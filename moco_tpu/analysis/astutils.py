@@ -29,7 +29,7 @@ from typing import Callable, Iterator, Optional
 
 def collect_imports(tree: ast.Module) -> dict[str, str]:
     """Local binding -> dotted origin, e.g. {'jnp': 'jax.numpy',
-    'lax': 'jax.lax', 'shard_map': 'moco_tpu.parallel.compat.shard_map'}."""
+    'lax': 'jax.lax', 'shard_map': 'jax.shard_map'}."""
     imports: dict[str, str] = {}
     for node in ast.walk(tree):
         if isinstance(node, ast.Import):

@@ -12,17 +12,23 @@ Samples the C++ decoders can't handle (webp/bmp/ppm, CMYK JPEGs) are
 retried per-slot through the PIL path — same output geometry — so
 results are host-independent rather than silently zero-filled.
 
-The library auto-builds via `make` on first use, serialized across
-processes with an fcntl lock (multi-host training, pytest-xdist); if the
-toolchain or libjpeg is missing the import fails gracefully and callers
-fall back to the PIL path (`native_available()` to probe).
+The library is git-ignored and built by `make` on first use, keyed on
+the CONTENT of `native/loader.cc` and `native/Makefile` (a stamp file
+beside the .so): a library left in a working tree by an older checkout
+is rebuilt, never loaded. Builds are serialized across processes with
+an fcntl lock (multi-host training, pytest-xdist). If the toolchain or
+libjpeg is missing, `native_available()` is False and says why once;
+callers then use the PIL path.
 """
 
 from __future__ import annotations
 
 import ctypes
+import functools
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional
 
@@ -32,28 +38,52 @@ from moco_tpu.utils import retry
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(__file__))), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libmoco_loader.so")
+_STAMP_PATH = os.path.join(_NATIVE_DIR, ".build.stamp")
 _build_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 ABI_VERSION = 4
 
 
-def _build_locked() -> None:
-    """Cross-process-safe build: exclusive fcntl lock + re-check, so only
-    one process runs make and nobody dlopens a half-written .so."""
+def _source_key() -> str:
+    """Content hash of what the library is built from."""
+    h = hashlib.sha256()
+    for name in ("loader.cc", "Makefile"):
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _built_from(key: str) -> bool:
+    if not os.path.exists(_LIB_PATH):
+        return False
+    try:
+        with open(_STAMP_PATH) as f:
+            return f.read().strip() == key
+    except FileNotFoundError:
+        return False
+
+
+def _ensure_built() -> None:
+    """Build the library unless it was built from the current sources.
+    Cross-process-safe: the check runs under an exclusive fcntl lock, so
+    only one process runs make and nobody dlopens a half-written .so.
+    `-B`: the decision to rebuild is made on content, not make's mtimes."""
     import fcntl
 
-    os.makedirs(_NATIVE_DIR, exist_ok=True)
+    key = _source_key()
     lock_path = os.path.join(_NATIVE_DIR, ".build.lock")
     with open(lock_path, "w") as lockf:
         fcntl.flock(lockf, fcntl.LOCK_EX)
         try:
-            if not os.path.exists(_LIB_PATH):
+            if not _built_from(key):
                 subprocess.run(
-                    ["make", "-C", _NATIVE_DIR],
+                    ["make", "-B", "-C", _NATIVE_DIR],
                     check=True,
                     capture_output=True,
                     text=True,
                 )
+                with open(_STAMP_PATH, "w") as f:
+                    f.write(key + "\n")
         finally:
             fcntl.flock(lockf, fcntl.LOCK_UN)
 
@@ -115,30 +145,36 @@ def _load_lib() -> ctypes.CDLL:
     with _build_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH):
-            _build_locked()
+        _ensure_built()
         lib = ctypes.CDLL(_LIB_PATH)
-        # version check BEFORE declaring ABI-current symbols: a stale .so
-        # lacks them and the dlsym failure would shadow this rebuild path
+        # version check BEFORE declaring ABI-current symbols: a library
+        # that disagrees lacks them, and the dlsym failure would hide why
         lib.mtl_version.restype = ctypes.c_int
         if lib.mtl_version() != ABI_VERSION:
-            # stale .so from an older checkout: rebuild once
-            os.remove(_LIB_PATH)
-            _build_locked()
-            lib = ctypes.CDLL(_LIB_PATH)
-            lib.mtl_version.restype = ctypes.c_int
-            if lib.mtl_version() != ABI_VERSION:
-                raise RuntimeError("native loader ABI mismatch after rebuild")
+            raise RuntimeError(
+                f"native loader built from the current sources reports ABI "
+                f"{lib.mtl_version()}, bindings expect {ABI_VERSION}"
+            )
         _declare_bindings(lib)
         _lib = lib
         return lib
 
 
+@functools.cache
 def native_available() -> bool:
+    """Whether the C++ loader builds and loads here, decided once per
+    process. A False costs the run its decode pool (callers use PIL
+    threads instead), so the reason is printed."""
     try:
         _load_lib()
         return True
-    except Exception:
+    except (OSError, subprocess.CalledProcessError, RuntimeError) as e:
+        detail = (getattr(e, "stderr", None) or str(e)).strip().splitlines()
+        print(
+            "native loader unavailable — image decode uses the PIL thread "
+            f"pool instead: {type(e).__name__}: {detail[-1] if detail else e}",
+            file=sys.stderr, flush=True,
+        )
         return False
 
 

@@ -29,14 +29,14 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from flax import struct
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from moco_tpu.core.moco import MocoState, build_encoder, create_state
 from moco_tpu.data.pipeline import EvalPipeline, LabeledPipeline
 from moco_tpu.models import LinearClassifier
 from moco_tpu.ops.losses import cross_entropy, topk_accuracy
-from moco_tpu.parallel import create_mesh, shard_map
+from moco_tpu.parallel import create_mesh
 from moco_tpu.parallel.mesh import DATA_AXIS
 from moco_tpu.utils.checkpoint import (
     CheckpointManager,

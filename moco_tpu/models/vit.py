@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from moco_tpu.parallel.compat import axis_size
+from moco_tpu.utils.platform import pallas_interpret
 
 
 def flash_attention_fn(query, key, value, **kwargs):
@@ -46,7 +46,7 @@ def flash_attention_fn(query, key, value, **kwargs):
     q = query.transpose(0, 2, 1, 3)
     k = key.transpose(0, 2, 1, 3)
     v = value.transpose(0, 2, 1, 3)
-    out = flash_attention(q, k, v, interpret=jax.default_backend() != "tpu")
+    out = flash_attention(q, k, v, interpret=pallas_interpret())
     return out.transpose(0, 2, 1, 3)
 
 
@@ -63,9 +63,7 @@ def ring_attention_fn(axis_name: str):
         q = query.transpose(0, 2, 1, 3)
         k = key.transpose(0, 2, 1, 3)
         v = value.transpose(0, 2, 1, 3)
-        out = ring_attention(
-            q, k, v, axis_name, interpret=jax.default_backend() != "tpu"
-        )
+        out = ring_attention(q, k, v, axis_name, interpret=pallas_interpret())
         return out.transpose(0, 2, 1, 3)
 
     return fn
@@ -258,7 +256,7 @@ class VisionTransformer(nn.Module):
         if self.sequence_axis is not None:
             try:
                 sp_rank = lax.axis_index(self.sequence_axis)
-                sp_n = axis_size(self.sequence_axis)
+                sp_n = lax.axis_size(self.sequence_axis)
             except NameError:
                 sp_rank = None
         if sp_rank is not None:

@@ -122,8 +122,22 @@ def _forward(q, k, queue, temperature, block_k, interpret):
     )(q.astype(jnp.float32), k.astype(jnp.float32), queue.astype(jnp.float32))
 
 
+def check_tiling(num_keys: int, block_k: int) -> None:
+    """The kernel streams whole (block_k, C) tiles; nothing here gives
+    way to the dense path. `make_train_step` calls this at build time
+    for an explicit `fused_infonce=True`, and its auto rule only selects
+    the fused loss when K tiles evenly; a direct call with a bad pair is
+    refused here, where materializing (B, 1+K) unasked would be the
+    wrong answer."""
+    if block_k <= 0 or num_keys <= 0 or num_keys % block_k:
+        raise ValueError(
+            f"fused InfoNCE needs a positive block that divides K: "
+            f"K={num_keys}, block_k={block_k}"
+        )
+
+
 def _reference(q, k, queue, temperature):
-    """Dense jnp oracle (and CPU fallback): same outputs."""
+    """Dense jnp oracle for the tests: same outputs."""
     pos = jnp.sum(q * k, axis=-1) / temperature
     # k/queue are detached by construction: infonce_stats' custom_vjp
     # returns no cotangent for them (_vjp_bwd yields dq only)
@@ -144,8 +158,7 @@ def infonce_stats(
     interpret: bool = False,
 ):
     """(pos, lse, n_above) per example, without materializing (B, 1+K)."""
-    if queue.shape[0] % block_k or queue.shape[0] == 0:
-        return _reference(q, k, queue, temperature)
+    check_tiling(queue.shape[0], block_k)
     return _forward(q, k, queue, temperature, block_k, interpret)
 
 
@@ -166,25 +179,21 @@ def _vjp_bwd(temperature, block_k, interpret, res, cots):
         g_lse = jnp.zeros((b,), jnp.float32)
     if g_pos is None:
         g_pos = jnp.zeros((b,), jnp.float32)
-    if kk % block_k or kk == 0:
-        p_neg = jnp.exp(q @ queue.T * inv_t - lse[:, None])
-        dq_neg = (p_neg * g_lse[:, None]) @ queue * inv_t
-    else:
-        kernel = functools.partial(_bwd_kernel, inv_t=inv_t)
-        dq_neg = pl.pallas_call(
-            kernel,
-            grid=(kk // block_k,),
-            in_specs=[
-                pl.BlockSpec((b, c), lambda i: (0, 0)),
-                pl.BlockSpec((block_k, c), lambda i: (i, 0)),
-                pl.BlockSpec((b,), lambda i: (0,)),
-                pl.BlockSpec((b,), lambda i: (0,)),
-            ],
-            out_specs=pl.BlockSpec((b, c), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((b, c), jnp.float32)],
-            interpret=interpret,
-        )(q.astype(jnp.float32), queue.astype(jnp.float32), lse, g_lse)
+    kernel = functools.partial(_bwd_kernel, inv_t=inv_t)
+    dq_neg = pl.pallas_call(
+        kernel,
+        grid=(kk // block_k,),
+        in_specs=[
+            pl.BlockSpec((b, c), lambda i: (0, 0)),
+            pl.BlockSpec((block_k, c), lambda i: (i, 0)),
+            pl.BlockSpec((b,), lambda i: (0,)),
+            pl.BlockSpec((b,), lambda i: (0,)),
+        ],
+        out_specs=pl.BlockSpec((b, c), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((b, c), jnp.float32)],
+        interpret=interpret,
+    )(q.astype(jnp.float32), queue.astype(jnp.float32), lse, g_lse)
     # pos-logit path: through both the pos output and the lse
     pos = jnp.sum(q * k, axis=-1) * inv_t
     p_pos = jnp.exp(pos - lse)

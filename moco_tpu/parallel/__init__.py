@@ -1,4 +1,3 @@
-from moco_tpu.parallel.compat import shard_map
 from moco_tpu.parallel.dist import (
     ProcessDataPartition,
     device_row_ranges,
@@ -41,5 +40,4 @@ __all__ = [
     "shuffle_gather",
     "unshuffle_gather",
     "ring_attention",
-    "shard_map",
 ]

@@ -1,13 +1,15 @@
-"""Version-compat shims for jax APIs that moved between releases.
+"""A forward-only `lax.optimization_barrier`.
 
-The codebase targets the modern `jax.shard_map` surface (top-level
-export, `check_vma=` kwarg). Older jax lines (< 0.6) ship the same
-transform as `jax.experimental.shard_map.shard_map` with the flag
-spelled `check_rep=`. Every call site routes through :func:`shard_map`
-here so ONE module gates the difference — on an old jax the alternative
-is an `AttributeError` at trace time in every shard_map consumer (the
-whole train step, the probe, the shuffle tests), which reads like a
-training bug rather than what it is: a missing-API environment.
+Deliberate, not a version shim: jax 0.9.0's own barrier is
+differentiable, but its transpose rule instantiates every zero
+cotangent and wraps the cotangents in a second barrier. For the
+layer-granular ZeRO `_tie` (core/moco.py) — which barriers the next
+group's shards together with an activation-sized anchor whose barrier
+output is then dropped — that would materialize an activation-sized
+zero in the backward pass and tie the shard cotangents to it. The
+barrier here constrains forward scheduling only; cotangents pass
+through untouched, so the backward pass sees the gradients it would
+see without any barrier.
 """
 
 from __future__ import annotations
@@ -15,26 +17,8 @@ from __future__ import annotations
 import jax
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """`jax.shard_map` where available, else the experimental spelling
-    with `check_vma` mapped onto its older `check_rep` name."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
-
-
 @jax.custom_vjp
 def optimization_barrier(x):
-    """`lax.optimization_barrier` with a gradient on every jax: older
-    releases ship the primitive without a differentiation rule, so the
-    barrier (an identity for values) carries an identity VJP — the
-    backward pass sees the same gradients either way."""
     return jax.lax.optimization_barrier(x)
 
 
@@ -47,12 +31,3 @@ def _barrier_bwd(_, g):
 
 
 optimization_barrier.defvjp(_barrier_fwd, _barrier_bwd)
-
-
-def axis_size(axis_name) -> int:
-    """`jax.lax.axis_size` where available; on older jax `psum(1, axis)`
-    — which under shard_map is a static Python int, so shape arithmetic
-    downstream (reshape by the axis size) keeps working."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)

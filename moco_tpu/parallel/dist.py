@@ -124,8 +124,7 @@ def maybe_initialize_multihost() -> bool:
     """
     import os
 
-    already = getattr(jax.distributed, "is_initialized", None)
-    if callable(already) and already():
+    if jax.distributed.is_initialized():
         return False
     env = os.environ
     wants = (

@@ -36,7 +36,6 @@ from jax import lax
 
 from moco_tpu.obs import comms
 from moco_tpu.ops.flash_attention import flash_attention_with_lse
-from moco_tpu.parallel.compat import axis_size
 
 NEG_INF = -1e30
 
@@ -55,7 +54,7 @@ def ring_attention(
 
     Returns this device's (B, H, S_local, D) output slice.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     b, h, s_local, d = q.shape
     perm = [(j, (j + 1) % n) for j in range(n)]

@@ -41,14 +41,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from moco_tpu.obs import comms
-from moco_tpu.parallel.compat import axis_size
 
 
 def _merge_gather(x: jax.Array, axis_name: str, site: str) -> jax.Array:
     """all_gather with the device dim folded into the batch dim:
     (N_global, ...). `site` names the collective in the comms ledger +
     HLO metadata (obs/comms.py)."""
-    with comms.tag(site, "all_gather", x, axis_size(axis_name)):
+    with comms.tag(site, "all_gather", x, lax.axis_size(axis_name)):
         g = lax.all_gather(x, axis_name)  # (n_dev, B_local, ...)
     return g.reshape((-1,) + g.shape[2:])
 
@@ -106,7 +105,7 @@ def balanced_shuffle(rng: jax.Array, x: jax.Array, axis_name: str) -> jax.Array:
 
     local-perm → tiled all_to_all (device d's chunk j → device j) →
     local-perm. Requires local batch divisible by the axis size."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     b = x.shape[0]
     if b % n:
         raise ValueError(f"a2a shuffle needs local batch {b} divisible by axis size {n}")
@@ -120,7 +119,7 @@ def balanced_shuffle(rng: jax.Array, x: jax.Array, axis_name: str) -> jax.Array:
 def balanced_unshuffle(rng: jax.Array, y: jax.Array, axis_name: str) -> jax.Array:
     """Exact inverse of `balanced_shuffle` with the same rng (the tiled
     chunk exchange is an involution; the local perms invert via argsort)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     b = y.shape[0]
     pre, post = _local_perms(rng, b, axis_name)
     y = jnp.take(y, jnp.argsort(post), axis=0)

@@ -77,7 +77,6 @@ from jax import lax
 
 from moco_tpu import obs
 from moco_tpu.obs import comms
-from moco_tpu.parallel.compat import axis_size
 from moco_tpu.parallel.mesh import DATA_AXIS
 from moco_tpu.utils import faults
 
@@ -99,7 +98,7 @@ def scatter_mean(x: jax.Array, axis_name: str = DATA_AXIS) -> jax.Array:
     """Mean-reduce a full local grad leaf across the axis AND keep only
     this replica's (m,) shard — one psum_scatter, the fused collective
     that makes sharded weight update cost no extra communication."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     m = padded_cols(x.size, n)
     flat = jnp.pad(x.reshape(-1), (0, n * m - x.size))
     return lax.psum_scatter(flat, axis_name, scatter_dimension=0, tiled=True) / n
@@ -107,7 +106,7 @@ def scatter_mean(x: jax.Array, axis_name: str = DATA_AXIS) -> jax.Array:
 
 def local_shard(x: jax.Array, axis_name: str = DATA_AXIS) -> jax.Array:
     """This replica's (m,) rows of a replicated full leaf."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     r = lax.axis_index(axis_name)
     m = padded_cols(x.size, n)
     flat = jnp.pad(x.reshape(-1), (0, n * m - x.size))
@@ -135,7 +134,7 @@ def sharded_update(tx, grads, opt_state, trainable, axis_name: str = DATA_AXIS):
     new_opt_state_local_expanded). Call inside shard_map; `grads` are the
     LOCAL (pre-reduction) gradients, `trainable` the replicated params,
     `opt_state` the local (1, m)/scalar view of the sharded state."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     with comms.tag("zero.grad_reduce_scatter", "psum_scatter", grads, n):
         grad_sh = jax.tree.map(lambda g: scatter_mean(g, axis_name), grads)
     param_sh = jax.tree.map(lambda p: local_shard(p, axis_name), trainable)

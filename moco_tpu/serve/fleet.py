@@ -51,6 +51,7 @@ import numpy as np
 
 from moco_tpu.analysis import tsan
 from moco_tpu.utils import faults, retry
+from moco_tpu.utils.platform import cpu_pinned
 
 WARM_INGEST_BLOCK = 512  # rows per /ingest POST during a warm replay
 
@@ -176,6 +177,30 @@ class ReplicaSupervisor:
         self._events: list = []
         self._stop = threading.Event()
         self._monitor: Optional[threading.Thread] = None
+        if not self._custom_argv:
+            self._check_accelerator_placement()
+
+    def _check_accelerator_placement(self) -> None:
+        """One process per chip host: a `replica_main` child whose
+        environment does not pin `JAX_PLATFORMS` to the CPU initialises
+        every chip it can see, and only one live process can hold them.
+        There is no per-replica device assignment yet (ROADMAP W1), so
+        a fleet that would start more than one such child is refused
+        here, before any of them hangs waiting for the chip."""
+        unpinned = [
+            c.index
+            for c in self._children
+            if not cpu_pinned(self._child_env(c.index, scrub_kills=False))
+        ]
+        if len(unpinned) > 1:
+            raise RuntimeError(
+                f"ReplicaSupervisor: replicas {unpinned} would each initialise "
+                "the accelerator (JAX_PLATFORMS is not pinned to cpu in their "
+                "environment), but a replica process takes every chip on the "
+                "host and only one process can hold them. Per-replica device "
+                "assignment does not exist yet (ROADMAP W1): run one replica "
+                "per host, or pin the fleet to the CPU with JAX_PLATFORMS=cpu."
+            )
 
     # -- topology ---------------------------------------------------------
 

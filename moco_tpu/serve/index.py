@@ -70,6 +70,7 @@ import numpy as np
 from moco_tpu.ops.losses import l2_normalize
 from moco_tpu.parallel.mesh import DATA_AXIS
 from moco_tpu.utils import faults
+from moco_tpu.utils.platform import pallas_interpret
 
 DEFAULT_KMEANS_ITERS = 10
 # modes query()/prepare() understand; "*_i8" score in int8 (enable_int8),
@@ -276,9 +277,9 @@ def _fused_cell_scores_kernel(probes_ref, q_ref, cell_rows_ref, out_ref):
     `probes` pick the block), so the kernel is a single (1, d) ×
     (cell_cap, d)^T dot — the cell is scored straight out of its DMA
     tile, and the (m, nprobe·cell_cap, d) gather never exists in HBM."""
-    out_ref[0] = jax.lax.dot_general(
+    out_ref[...] = jax.lax.dot_general(
         q_ref[...],
-        cell_rows_ref[0],
+        cell_rows_ref[...],
         (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
@@ -298,21 +299,33 @@ def _fused_cell_scores_pallas(queries, cell_rows, probes, interpret=False):
     m, d = queries.shape
     nlist, cell_cap, _ = cell_rows.shape
     nprobe = probes.shape[1]
+    # Mosaic wants a block's last two extents (8, 128)-aligned or equal
+    # to the array's, and a one-query (1, d) block of an (m, d) array
+    # is neither ("The Pallas TPU lowering currently requires that the
+    # last two dimensions of your block shape are divisible by 8 and
+    # 128 respectively, or be equal to the respective dimensions of the
+    # overall array", v5e, jax 0.9.0). So queries ride as (m, 1, d) and
+    # scores as (m, nprobe, 1, cell_cap): the per-step (1, d) and
+    # (1, cell_cap) tiles are then full trailing extents, and the
+    # leading axes are squeezed (None) out of the kernel's view.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m, nprobe),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, j, p: (i, 0)),
-            pl.BlockSpec((1, cell_cap, d), lambda i, j, p: (p[i, j], 0, 0)),
+            pl.BlockSpec((None, 1, d), lambda i, j, p: (i, 0, 0)),
+            pl.BlockSpec((None, cell_cap, d), lambda i, j, p: (p[i, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, cell_cap), lambda i, j, p: (i, j, 0)),
+        out_specs=pl.BlockSpec(
+            (None, None, 1, cell_cap), lambda i, j, p: (i, j, 0, 0)
+        ),
     )
-    return pl.pallas_call(
+    scores = pl.pallas_call(
         _fused_cell_scores_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, nprobe, cell_cap), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((m, nprobe, 1, cell_cap), jnp.float32),
         interpret=interpret,
-    )(probes, queries.astype(jnp.float32), cell_rows)
+    )(probes, queries.astype(jnp.float32).reshape(m, 1, d), cell_rows)
+    return scores.reshape(m, nprobe, cell_cap)
 
 
 def _ivf_topk_fused_pallas(
@@ -361,8 +374,8 @@ def _exact_topk_int8(queries, rows_i8, row_scale, valid_count, k: int):
 
 def _pallas_fused_default() -> tuple[bool, bool]:
     """(use_pallas, interpret) for the fused scan: the Pallas cell-DMA
-    kernel runs on real TPUs by default (the capability probe is the
-    backend itself — Mosaic has no CPU lowering); `MOCO_IVF_PALLAS`
+    kernel runs on a TPU backend by default (compiled there and at the
+    serving shapes by tests/test_tpu_kernels.py); `MOCO_IVF_PALLAS`
     overrides: `0` forces the portable lax fori_loop variant on a chip,
     `1` forces Pallas, `interpret` runs the kernel in interpret mode on
     any backend (the CPU equivalence tests)."""
@@ -373,7 +386,7 @@ def _pallas_fused_default() -> tuple[bool, bool]:
         return True, True
     if env in ("1", "on", "true"):
         return True, False
-    return jax.default_backend() == "tpu", False
+    return not pallas_interpret(), False
 
 
 class IndexRecompileError(RuntimeError):

@@ -50,10 +50,18 @@ def build_argparser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
-    from moco_tpu.utils.platform import pin_platform_from_env
+    from moco_tpu.utils.platform import (
+        enable_persistent_compilation_cache,
+        log_devices,
+        pin_platform_from_env,
+    )
 
-    pin_platform_from_env()
     args = build_argparser().parse_args(argv)
+    pin_platform_from_env()
+    # every boot AOT-compiles one encoder program per bucket: share them
+    # with the next boot (restart, rollout, the neighbouring replica)
+    enable_persistent_compilation_cache()
+    log_devices(f"replica {args.replica_index}")
 
     import os
 

@@ -26,8 +26,8 @@ Endpoints:
   params digest of the encoder answering on this replica, and the last
   ingest's source checkpoint step (encoder/index skew at a glance).
 - `GET /stats` — the live `serve/*` gauge snapshot as JSON.
-- `GET /healthz` — `{"ok": true, "warm": ..., "draining": false}` once
-  the AOT warmup ran; `ok` flips false while draining so a fleet router
+- `GET /healthz` — `{"ok": true, "warm": ..., "draining": false,
+  "platform": "tpu"}` once the AOT warmup ran; `ok` flips false while draining so a fleet router
   stops dispatching here before the batcher's intake actually shuts.
 - `POST /admin/drain` — graceful shutdown of THIS replica: healthz goes
   not-ok, the batcher flushes every accepted request (`drain()`, zero
@@ -85,6 +85,7 @@ import threading
 import time
 from collections import deque
 
+import jax
 import numpy as np
 
 from moco_tpu.obs import ctxprop
@@ -170,6 +171,10 @@ class ServeServer:
         self.recall_sample_every = int(recall_sample_every)
         self.workdir = workdir
         self.replica_index = int(replica_index)
+        # the backend this process resolved, on /healthz: a replica that
+        # lost its chip and came up on the CPU answers correctly and
+        # slowly, so whoever waits on /healthz can see it
+        self.platform = jax.default_backend()
         # served-model identity (obs/quality.py mints the digest): which
         # encoder answers on this replica — /stats and /admin/model
         # expose it so fleet version skew is a gauge, not an incident
@@ -283,6 +288,7 @@ class ServeServer:
                         "warm": server.engine.recompiles_after_warmup == 0,
                         "draining": draining,
                         "replica": server.replica_index,
+                        "platform": server.platform,
                     })
                 elif path == "/stats":
                     self._json(200, server.stats())

@@ -69,6 +69,7 @@ from moco_tpu.utils.metrics import (
     print0,
     profiler_trace,
 )
+from moco_tpu.utils.platform import log_devices
 from moco_tpu.utils.schedules import build_optimizer, make_lr_schedule
 from moco_tpu.utils.watchdog import StepWatchdog
 
@@ -112,6 +113,7 @@ def train(
     # below needs the process index, and reading it any earlier would
     # initialize a single-process backend.
     maybe_initialize_multihost()
+    log_devices("train")
     pidx = jax.process_index()
     # Telemetry (moco_tpu/obs): the span tracer is installed process-wide
     # for the run's duration, so the data pipeline's decode spans, the
@@ -228,6 +230,15 @@ def _train_impl(
     steps_per_epoch = config.steps_per_epoch or pipeline.steps_per_epoch
     if steps_per_epoch <= 0:
         raise ValueError("empty pipeline: fewer examples than one global batch")
+    if steps_per_epoch > pipeline.steps_per_epoch:
+        # the epoch loop stops when the pipeline runs dry, so a longer
+        # override would silently train fewer steps than the LR and
+        # momentum schedules were built for
+        raise ValueError(
+            f"steps_per_epoch={steps_per_epoch} exceeds the "
+            f"{pipeline.steps_per_epoch} full batches of "
+            f"{config.data.global_batch} the dataset yields per epoch"
+        )
 
     encoder = build_encoder(config.moco, num_data=num_data)
     predictor = build_predictor(config.moco, num_data=num_data)

@@ -123,8 +123,8 @@ class MocoConfig:
     # None = auto (on for TPU + replicated tile-divisible queue).
     fused_infonce: Optional[bool] = None
     # Queue tile size streamed through VMEM per grid step; 0 = the
-    # kernel's DEFAULT_BLOCK_K. Small values let tests drive the real
-    # kernel (not the dense fallback) at toy K.
+    # kernel's DEFAULT_BLOCK_K. Small values let tests drive the kernel
+    # at toy K (the block must divide K).
     fused_block_k: int = 0
     # Rematerialize the query-encoder forward in the backward pass
     # (jax.checkpoint): trades ~30% more FLOPs for O(depth) less
@@ -202,8 +202,9 @@ class ParallelConfig:
     # `comms/zero.gather.<group>` sites) and the rematerialized group
     # segments free them after their forward/backward contribution, so
     # transient model memory drops from full-tree to ~two adjacent
-    # groups — the per-chip-batch capacity unlock. Bit-identical loss
-    # trajectory to the whole-tree stages (tests assert it). Requires
+    # groups — the per-chip-batch capacity unlock. Same loss trajectory
+    # as the whole-tree stages up to f32 rounding across the separately
+    # compiled programs (tests/test_zero.py states the bound). Requires
     # zero_stage >= 2, num_model == 1, and an elementwise optimizer;
     # checkpoint layout is unchanged (the same (n, m) shards), so
     # resume round-trips freely across zero1/zero23/layer-granular.

@@ -2,7 +2,7 @@
 
     BENCH_TRACE_DIR=/tmp/trace python bench.py          # capture
     python scripts/analyze_trace.py /tmp/trace [--steps 20] \
-        [--flops 8.18e12 --bytes 100e9 --peak-tflops 197 --hbm-gbs 819]
+        [--flops 8.18e12 --bytes 100e9 --device-kind "TPU v5 lite"]
 
 Reads the newest `*.trace.json.gz` under the directory (the Perfetto
 JSON the profiler writes next to the xplane proto), aggregates X events
@@ -10,6 +10,10 @@ on the device track by fusion-name bucket, and — when the XLA
 cost-analysis numbers are passed — prints the compute/HBM rooflines the
 way PROFILE.md reports them. This is the exact analysis behind
 PROFILE.md, packaged so the next profiling pass is one command.
+
+A roofline needs the peaks of the chip that PRODUCED the trace: name it
+with --device-kind (as jax reports `device_kind`; must be in
+KNOWN_PEAKS) or pass --peak-tflops and --hbm-gbs. Nothing is assumed.
 """
 
 from __future__ import annotations
@@ -22,6 +26,26 @@ import json
 import os
 import re
 import sys
+
+
+# device_kind -> (peak bf16 TFLOP/s, HBM GB/s). Source: Google Cloud
+# documentation, "TPU v5e". The full table belongs to the benchmark
+# (ROADMAP S0); this script only refuses to guess.
+KNOWN_PEAKS = {"TPU v5 lite": (197.0, 819.0)}
+
+
+def resolve_peaks(device_kind, peak_tflops, hbm_gbs) -> tuple[float, float]:
+    """(peak TFLOP/s, HBM GB/s) from the two explicit numbers, else from
+    a device kind this script knows; otherwise exit."""
+    if peak_tflops is not None and hbm_gbs is not None:
+        return peak_tflops, hbm_gbs
+    if device_kind in KNOWN_PEAKS:
+        return KNOWN_PEAKS[device_kind]
+    sys.exit(
+        "roofline requested (--flops/--bytes) but the chip's peaks are unknown: "
+        "pass --peak-tflops AND --hbm-gbs, or a --device-kind in "
+        f"{sorted(KNOWN_PEAKS)} (got {device_kind!r})"
+    )
 
 
 def load_trace(path: str) -> dict:
@@ -55,9 +79,15 @@ def main() -> None:
     ap.add_argument("--top", type=int, default=15)
     ap.add_argument("--flops", type=float, default=None, help="per-step FLOPs (cost analysis)")
     ap.add_argument("--bytes", type=float, default=None, help="per-step bytes accessed")
-    ap.add_argument("--peak-tflops", type=float, default=197.0, help="chip peak (v5e bf16 default)")
-    ap.add_argument("--hbm-gbs", type=float, default=819.0, help="chip HBM GB/s (v5e default)")
+    ap.add_argument("--device-kind", default=None,
+                    help=f"jax device_kind of the traced chip, one of {sorted(KNOWN_PEAKS)}")
+    ap.add_argument("--peak-tflops", type=float, default=None, help="chip peak bf16 TFLOP/s")
+    ap.add_argument("--hbm-gbs", type=float, default=None, help="chip HBM GB/s")
     args = ap.parse_args()
+    if args.flops or args.bytes:
+        args.peak_tflops, args.hbm_gbs = resolve_peaks(
+            args.device_kind, args.peak_tflops, args.hbm_gbs
+        )
 
     trace = load_trace(args.trace)
     devs = device_pids(trace)

@@ -943,9 +943,13 @@ def run_smoke(workdir: str, contract_coverage: bool = False) -> dict:
 
 
 def main() -> int:
-    from moco_tpu.utils.platform import pin_platform_from_env
+    from moco_tpu.utils.platform import (
+        enable_persistent_compilation_cache,
+        pin_platform_from_env,
+    )
 
     pin_platform_from_env()
+    enable_persistent_compilation_cache()
     ap = argparse.ArgumentParser(description="serving-fleet router chaos smoke")
     ap.add_argument("--workdir", default=None)
     ap.add_argument(

@@ -63,7 +63,7 @@ def trace_schedule(process_index: int) -> "ScheduleRecorder":
 
     from moco_tpu.analysis.sanitizer import ScheduleRecorder, install_recorder
     from moco_tpu.obs import comms
-    from moco_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from moco_tpu.parallel.shuffle import (
         balanced_shuffle,
         balanced_unshuffle,
@@ -91,7 +91,7 @@ def trace_schedule(process_index: int) -> "ScheduleRecorder":
         fn = shard_map(
             step, mesh=mesh,
             in_specs=(P("data"), P()), out_specs=P("data"),
-            check_vma=False,  # nested-pjit rep inference trips on 0.4.x
+            check_vma=False,
         )
         x = jnp.arange(16 * n * 4, dtype=jnp.float32).reshape(16 * n, 4)
         rng = jax.random.PRNGKey(0)
@@ -115,7 +115,7 @@ def trace_zero_schedule(process_index: int) -> "ScheduleRecorder":
     from jax.sharding import Mesh, PartitionSpec as P
 
     from moco_tpu.analysis.sanitizer import ScheduleRecorder, install_recorder
-    from moco_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from moco_tpu.parallel.zero import BucketPlan, shard_tree
 
     recorder = ScheduleRecorder(process_index=process_index)

@@ -184,9 +184,13 @@ def poll_once(
 
 
 def main() -> int:
-    from moco_tpu.utils.platform import pin_platform_from_env
+    from moco_tpu.utils.platform import (
+        enable_persistent_compilation_cache,
+        pin_platform_from_env,
+    )
 
     pin_platform_from_env()
+    enable_persistent_compilation_cache()
     ap = argparse.ArgumentParser(description="tail a training checkpoint dir into a serving replica")
     ap.add_argument("--ckpt-dir", required=True, help="the training run's workdir")
     ap.add_argument("--server", required=True, help="replica base URL, e.g. http://127.0.0.1:8000")

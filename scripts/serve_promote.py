@@ -242,9 +242,13 @@ def promote_once(args, ledger) -> str:
 
 
 def main() -> int:
-    from moco_tpu.utils.platform import pin_platform_from_env
+    from moco_tpu.utils.platform import (
+        enable_persistent_compilation_cache,
+        pin_platform_from_env,
+    )
 
     pin_platform_from_env()
+    enable_persistent_compilation_cache()
     ap = argparse.ArgumentParser(
         description="gate + promote checkpoints into the serving fleet"
     )

@@ -900,9 +900,13 @@ def assert_serve_surface(workdir: str, summary: dict) -> None:
 
 
 def main() -> int:
-    from moco_tpu.utils.platform import pin_platform_from_env
+    from moco_tpu.utils.platform import (
+        enable_persistent_compilation_cache,
+        pin_platform_from_env,
+    )
 
-    pin_platform_from_env()  # honor JAX_PLATFORMS at the config level
+    pin_platform_from_env()
+    enable_persistent_compilation_cache()
     ap = argparse.ArgumentParser(description="embedding-service smoke")
     ap.add_argument("--workdir", default=None, help="default: a fresh temp dir")
     ap.add_argument(

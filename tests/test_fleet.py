@@ -32,7 +32,7 @@ from moco_tpu.obs.fleet import (
     reduce_stats,
 )
 from moco_tpu.parallel import create_mesh
-from moco_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 
 # -- fleet reduction (skew math on synthetic multi-host matrices) --------

@@ -75,9 +75,13 @@ def test_gradient_chains_through_normalization(data):
     )
 
 
-def test_fallback_on_indivisible_k(data):
+def test_indivisible_k_raises(data):
+    """The kernel entry never gives way to the dense path: a block that
+    does not tile K is an error, in the forward and under grad."""
     q, k, queue = data
-    pos, lse, above = infonce_stats(q, k, queue[:100], 0.2, block_k=64, interpret=True)
-    rpos, rlse, rabove = _reference(q, k, queue[:100], 0.2)
-    np.testing.assert_allclose(np.asarray(lse), np.asarray(rlse), rtol=1e-5)
-    np.testing.assert_array_equal(np.asarray(above), np.asarray(rabove))
+    with pytest.raises(ValueError, match="K=100, block_k=64"):
+        infonce_stats(q, k, queue[:100], 0.2, block_k=64, interpret=True)
+    with pytest.raises(ValueError, match="divides K"):
+        jax.grad(
+            lambda q: fused_infonce_loss(q, k, queue[:100], 0.2, block_k=64, interpret=True)[0]
+        )(q)

@@ -26,9 +26,9 @@ def _run_steps(fused: bool, n_steps: int = 2):
             cifar_stem=True,
             compute_dtype="float32",
             fused_infonce=fused,
-            # block_k=32 with K=64 → the REAL pallas kernel (interpret
-            # mode, 2-tile grid) runs inside the train step, not the
-            # dense fallback infonce_stats would take at K < block.
+            # block_k=32 with K=64 → the pallas kernel (interpret mode,
+            # 2-tile grid) inside the train step; the default 2048-row
+            # block does not tile K=64 and would be refused.
             fused_block_k=32,
         ),
         optim=OptimConfig(lr=0.05, epochs=2, cos=True),

@@ -19,20 +19,6 @@ import subprocess
 import sys
 import threading
 
-import jax
-import pytest
-
-# Cross-process collectives over the CPU backend need jaxlib's
-# multi-process CPU support (jax >= 0.5): older jaxlibs fail with
-# "Multiprocess computations aren't implemented on the CPU backend".
-# Gate on the capability rather than fail — the single-process mesh
-# tests (test_dist.py, test_train_step.py) still cover the collective
-# semantics on such environments.
-pytestmark = pytest.mark.skipif(
-    jax.__version_info__ < (0, 5),
-    reason="this jaxlib lacks multi-process CPU collectives",
-)
-
 WORKER = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_multihost_worker.py")
 NPROC = 2
 DEVICES_PER_PROC = 2

@@ -212,3 +212,21 @@ def test_decode_failures_counter(tmp_path):
     # the good slot decoded, the corrupt one zero-filled
     sums = imgs.reshape(len(ds), -1).sum(axis=1)
     assert (sums == 0).sum() == 1 and (sums > 0).sum() == 1
+
+
+def test_library_is_rebuilt_when_sources_change(monkeypatch):
+    """The git-ignored .so is keyed on the content of loader.cc and the
+    Makefile: one left behind by an older checkout (here: a stamp that
+    names other sources) is rebuilt, not loaded because it exists."""
+    from moco_tpu.data import native_loader as nl
+
+    key = nl._source_key()
+    assert nl._built_from(key)  # the module-level skipif already built it
+    with open(nl._STAMP_PATH, "w") as f:
+        f.write("0" * 64 + "\n")
+    assert not nl._built_from(key)
+    before = os.stat(nl._LIB_PATH).st_mtime_ns
+    monkeypatch.setattr(nl, "_lib", None)
+    nl._load_lib()
+    assert nl._built_from(key)
+    assert os.stat(nl._LIB_PATH).st_mtime_ns > before

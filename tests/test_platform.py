@@ -1,20 +1,27 @@
-"""Guards on moco_tpu.utils.platform.enable_persistent_compilation_cache.
+"""Guards on moco_tpu.utils.platform and the start-up rules around it.
 
-The persistent XLA compilation cache exists so TPU battery legs and the
-driver's end-of-round bench share one compile of the ~3.5-min r50/224
-step (PROFILE.md). It must stay OFF for CPU-resolved runs: XLA:CPU's
-AOT cache loader warns (and documents a SIGILL hazard) on
-machine-feature mismatches between writer and reader processes.
+The compile cache must be placeable from outside: with
+`JAX_COMPILATION_CACHE_DIR` set the program sets no directory in code;
+unset, it is one fixed git-ignored path inside the checkout — except on
+a CPU-pinned run, which skips it from the environment alone (XLA:CPU's
+AOT cache loader warns about machine-feature mismatches between writer
+and reader). Deciding must never initialise a backend: multi-host runs
+rendezvous first.
 """
 
 from __future__ import annotations
 
 import os
+import subprocess
+import sys
 
 import jax
 import pytest
 
+from moco_tpu.utils import platform
 from moco_tpu.utils.platform import enable_persistent_compilation_cache
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
@@ -24,38 +31,149 @@ def restore_cache_config():
     jax.config.update("jax_compilation_cache_dir", before)
 
 
-def test_cpu_backend_skips_cache(restore_cache_config, monkeypatch, tmp_path):
-    # conftest pins the CPU platform, so default_backend() == "cpu" here
-    monkeypatch.delenv("MOCO_COMPILE_CACHE_DIR", raising=False)
-    monkeypatch.delenv("MOCO_NO_COMPILE_CACHE", raising=False)
+def test_env_dir_means_nothing_is_set_in_code(restore_cache_config, monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: jax reads it itself, on any
+    platform — the function reports it and touches no config."""
     jax.config.update("jax_compilation_cache_dir", None)
-    enable_persistent_compilation_cache(str(tmp_path / "cache"))
-    assert jax.config.jax_compilation_cache_dir is None
-    assert not (tmp_path / "cache").exists()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+    for pinned in ("cpu", "tpu,cpu"):
+        monkeypatch.setenv("JAX_PLATFORMS", pinned)
+        assert enable_persistent_compilation_cache() == str(tmp_path / "outside")
+        assert jax.config.jax_compilation_cache_dir is None
+    assert not (tmp_path / "outside").exists()
 
 
-def test_explicit_dir_overrides_cpu_guard(restore_cache_config, monkeypatch, tmp_path):
-    target = tmp_path / "explicit"
-    monkeypatch.setenv("MOCO_COMPILE_CACHE_DIR", str(target))
-    monkeypatch.delenv("MOCO_NO_COMPILE_CACHE", raising=False)
-    enable_persistent_compilation_cache()
-    assert jax.config.jax_compilation_cache_dir == str(target)
-    assert target.is_dir()
-
-
-def test_opt_out_wins(restore_cache_config, monkeypatch, tmp_path):
-    monkeypatch.setenv("MOCO_NO_COMPILE_CACHE", "1")
-    monkeypatch.setenv("MOCO_COMPILE_CACHE_DIR", str(tmp_path / "never"))
+def test_cpu_pinned_run_skips_cache(restore_cache_config, monkeypatch):
     jax.config.update("jax_compilation_cache_dir", None)
-    enable_persistent_compilation_cache()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert enable_persistent_compilation_cache() is None
     assert jax.config.jax_compilation_cache_dir is None
-    assert not (tmp_path / "never").exists()
+
+
+def test_unset_env_uses_the_fixed_in_checkout_dir(restore_cache_config, monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    for unpinned in ("", "tpu,cpu"):
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_PLATFORMS", unpinned)
+        assert enable_persistent_compilation_cache() == platform.DEFAULT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == platform.DEFAULT_CACHE_DIR
+    assert platform.DEFAULT_CACHE_DIR == os.path.join(ROOT, ".jax_cache")
+    # git must never see it
+    ignored = subprocess.run(
+        ["git", "check-ignore", "-q", ".jax_cache/x"], cwd=ROOT
+    ).returncode
+    assert ignored == 0
+
+
+def test_cache_decision_initialises_no_backend():
+    """In a fresh interpreter, on the path that does set a directory."""
+    code = (  # the module by path: the package import would pull in flax/orbax
+        "import importlib.util as u\n"
+        "spec = u.spec_from_file_location('platform_', 'moco_tpu/utils/platform.py')\n"
+        "mod = u.module_from_spec(spec); spec.loader.exec_module(mod)\n"
+        "assert mod.enable_persistent_compilation_cache().endswith('.jax_cache')\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+    )
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = ""
+    subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env, check=True, timeout=120)
+
+
+def test_diagnostics_stay_off_stdout(capsys, monkeypatch):
+    """An entry point owns its stdout (bench.py's is one JSON record,
+    chip_smoke's last line is the result): what the library has to say
+    about the platform goes to stderr."""
+    from moco_tpu.data import native_loader
+
+    platform.pallas_interpret.__wrapped__()
+
+    def no_compiler():
+        raise OSError("no compiler here")
+
+    monkeypatch.setattr(native_loader, "_load_lib", no_compiler)
+    assert native_loader.native_available.__wrapped__() is False
+    platform.log_devices("test", file=sys.stderr)
+    out, err = capsys.readouterr()
+    assert out == ""
+    assert "pallas kernels: not compiled on backend 'cpu'" in err
+    assert "native loader unavailable" in err and "no compiler here" in err
+    assert "test: platform=cpu" in err
+
+
+@pytest.mark.slow  # 40 s; tier-1 has no room for it (test_diagnostics_stay_off_stdout is its tier-1 guard)
+def test_bench_stdout_is_one_json_record(tmp_path):
+    """ci.yml's perf gate pipes `JAX_PLATFORMS=cpu python bench.py` into
+    scripts/perf_ledger.py: stdout must be the record and nothing else.
+    The headline and the ann_ab leg run (the leg that reaches
+    `pallas_interpret()` through serve/index.py); the serving, ZeRO, data
+    and obs legs are skipped for wall time."""
+    from conftest import load_script
+
+    env = dict(
+        os.environ, JAX_PLATFORMS="cpu", BENCH_SKIP_DATA="1", BENCH_SKIP_OBS_OVERHEAD="1",
+        BENCH_SKIP_ZERO="1", BENCH_SKIP_SERVE="1",
+    )
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "bench.py")],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert len(proc.stdout.splitlines()) == 1, proc.stdout[:2000]
+    record = tmp_path / "bench_out.json"
+    record.write_text(proc.stdout)
+    rec = load_script("perf_ledger.py").load_bench_record(str(record))
+    assert rec["legs"]["ann_ab"]["ran"], rec["legs"]["ann_ab"]
+    assert "bench: platform=cpu" in proc.stderr
+
+
+def test_chip_smoke_fails_without_a_tpu(tmp_path):
+    """chip_smoke.py has no CPU mode: on a machine with no TPU it exits
+    non-zero, names the platform jax resolved, and prints no result."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+        env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode != 0
+    assert "no TPU" in proc.stderr and "platform 'cpu'" in proc.stderr
+    assert '"ok"' not in proc.stdout
+
+
+def test_chip_smoke_knows_where_the_cache_is(monkeypatch):
+    """chip_smoke's parent counts cache entries without importing jax,
+    so it carries the placement rule a second time: keep them equal."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(ROOT, "chip_smoke.py")
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert mod.cache_dir() == platform.DEFAULT_CACHE_DIR
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert mod.cache_dir() == "/some/dir"
+
+
+def test_analyze_trace_never_assumes_a_chip():
+    """A roofline needs the traced chip's own peaks: both numbers, or a
+    device_kind the script knows."""
+    from conftest import load_script
+
+    mod = load_script("analyze_trace.py")
+    assert mod.resolve_peaks("TPU v5 lite", None, None) == (197.0, 819.0)
+    assert mod.resolve_peaks(None, 100.0, 200.0) == (100.0, 200.0)
+    for kind, peak, hbm in ((None, None, None), ("TPU v9", None, None), (None, 197.0, None)):
+        with pytest.raises(SystemExit):
+            mod.resolve_peaks(kind, peak, hbm)
 
 
 def test_bn_compile_repro_grid_order():
     """The bisect harness must order each depth's cells baseline-first,
-    shipped-slice-suspects last (an abandoned pathological cell forfeits
-    the least information — scripts/bn_compile_repro.py docstring)."""
+    shipped-slice-suspects last (a run cut short forfeits the least
+    information — scripts/bn_compile_repro.py docstring)."""
     from conftest import load_script
 
     mod = load_script("bn_compile_repro.py")

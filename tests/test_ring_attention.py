@@ -11,7 +11,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from moco_tpu.ops.flash_attention import _attn_reference
 from moco_tpu.parallel.ring_attention import ring_attention
-from moco_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 B, H, D = 2, 2, 32
 SEQ_AXIS = "seq"

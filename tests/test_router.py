@@ -590,7 +590,10 @@ def test_server_admin_drain_flips_healthz_and_rejects_new_work():
         )
         with urllib.request.urlopen(req, timeout=30) as r:
             assert json.loads(r.read())["request_id"].startswith("r0-")
-        assert _get(url, "/healthz")["ok"] is True
+        h = _get(url, "/healthz")
+        # (the platform this process resolved: chip_smoke.py refuses a
+        # replica that came up anywhere but on the TPU)
+        assert h["ok"] is True and h["platform"] == "cpu"
         drain_req = urllib.request.Request(url + "/admin/drain?timeout=10", data=b"")
         with urllib.request.urlopen(drain_req, timeout=30) as r:
             body = json.loads(r.read())
@@ -660,6 +663,23 @@ def test_supervisor_child_env_scrubs_kill_rules():
         env={"MOCO_FAULTS": "kill@replica=0"},
     )
     assert "MOCO_FAULTS" not in sup2._child_env(0, scrub_kills=True)
+
+
+def test_supervisor_refuses_more_accelerator_replicas_than_it_can_place():
+    """One process per chip host: unpinned replica_main children would
+    each take every chip. No device assignment yet (ROADMAP W1), so the
+    supervisor refuses up front with a plain message."""
+    unpinned = {"PATH": os.environ.get("PATH", "")}
+    with pytest.raises(RuntimeError, match="only one process can hold them"):
+        ReplicaSupervisor(2, ckpt_dir="/nonexistent", env=unpinned)
+    # one unpinned replica is placeable; a CPU-pinned fleet of any size
+    # never touches the chip; per-replica pins count per replica
+    ReplicaSupervisor(1, ckpt_dir="/nonexistent", env=unpinned)
+    ReplicaSupervisor(3, ckpt_dir="/nonexistent", env={**unpinned, "JAX_PLATFORMS": "cpu"})
+    ReplicaSupervisor(
+        2, ckpt_dir="/nonexistent", env=unpinned,
+        extra_env={1: {"JAX_PLATFORMS": "cpu"}},
+    )
 
 
 # -- supervisor (real subprocesses, stdlib-only fake replica) ------------

@@ -197,6 +197,26 @@ def test_index_valid_count_masks_unfilled_rows():
     assert (scores_full[:, 4:] == -np.inf).all(), "unfilled rows not masked"
 
 
+def _assert_same_neighbours(s1, i1, s2, i2):
+    """What two separately compiled scans of the same rows guarantee on
+    jax 0.9.0. The sharded and the single-device program sum the
+    16-term dot products in different orders, so a score may differ in
+    its last bit (measured: at most 1 ULP, 6e-8 at these cosines); two
+    neighbours whose scores sit that close may therefore come back in
+    either order (measured: ids 90 and 51, one ULP apart in one program
+    and exactly tied in the other). So: scores equal within 1 ULP, and
+    ids equal as SETS over every run of scores tied within 2 ULP."""
+    ulp = float(np.spacing(np.float32(0.5)))  # cosines in (0.5, 1]: 2**-24
+    np.testing.assert_allclose(s1, s2, rtol=0, atol=ulp)
+    k = i1.shape[1]
+    for r in range(i1.shape[0]):
+        start = 0
+        for j in range(1, k + 1):
+            if j == k or s1[r, j - 1] - s1[r, j] > 2 * ulp:
+                assert set(i1[r, start:j]) == set(i2[r, start:j]), (r, i1[r], i2[r])
+                start = j
+
+
 def test_index_sharded_matches_single_device():
     from moco_tpu.parallel import create_mesh
 
@@ -210,8 +230,7 @@ def test_index_sharded_matches_single_device():
     assert sharded.capacity % mesh.shape["data"] == 0
     s1, i1 = plain.query(queries, 5)
     s2, i2 = sharded.query(queries, 5)
-    np.testing.assert_array_equal(i1, i2)
-    np.testing.assert_allclose(s1, s2, rtol=1e-6, atol=1e-6)
+    _assert_same_neighbours(s1, i1, s2, i2)
 
 
 def test_index_frozen_rejects_unprepared_shape():

@@ -17,7 +17,7 @@ from moco_tpu.parallel import (
     shuffle_gather,
     unshuffle_gather,
 )
-from moco_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 
 def _mesh():
@@ -124,7 +124,8 @@ def test_leak_control_cheat_arm_trains_and_probes(tmp_path):
     via allow_leaky_bn) must train on `synthetic_leak_control`, and the
     leak probe must resolve the virtual grouping from the checkpoint by
     default and produce finite aligned/shuffled accuracies. Guards the
-    single-chip path scripts/tpu_chains_r4.sh runs at full budget."""
+    single-chip path scripts/ablate_shuffle.py + scripts/leak_probe.py
+    run at full budget."""
     import numpy as np
 
     from moco_tpu.data.datasets import build_dataset

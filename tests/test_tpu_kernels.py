@@ -24,16 +24,24 @@ def _rand(shape, key, dtype=jnp.float32):
     return jax.random.normal(jax.random.PRNGKey(key), shape, dtype)
 
 
+# The main path's own shapes (imagenet_v2: C=128, K=65536, block 2048):
+# B=256 is the global batch on one chip, B=64 its per-chip share on four.
+MAIN_PATH_SHAPES = pytest.mark.parametrize(
+    "b,kk", [(64, 8192), (64, 65536), (256, 65536)], ids=["b64-k8k", "b64-k64k", "b256-k64k"]
+)
+
+
 class TestFusedInfoNCE:
-    B, C, K = 64, 128, 8192
+    C = 128
     BLOCK = 2048
 
-    def test_stats_match_dense_oracle(self):
+    @MAIN_PATH_SHAPES
+    def test_stats_match_dense_oracle(self, b, kk):
         from moco_tpu.ops.fused_infonce import _reference, infonce_stats
 
-        q = _rand((self.B, self.C), 0)
-        k = _rand((self.B, self.C), 1)
-        queue = _rand((self.K, self.C), 2)
+        q = _rand((b, self.C), 0)
+        k = _rand((b, self.C), 1)
+        queue = _rand((kk, self.C), 2)
         q = q / jnp.linalg.norm(q, axis=-1, keepdims=True)
         k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
         queue = queue / jnp.linalg.norm(queue, axis=-1, keepdims=True)
@@ -46,13 +54,14 @@ class TestFusedInfoNCE:
         np.testing.assert_allclose(np.asarray(lse), np.asarray(rlse), rtol=1e-4, atol=1e-4)
         np.testing.assert_array_equal(np.asarray(above), np.asarray(rabove))
 
-    def test_loss_grads_match_dense(self):
+    @MAIN_PATH_SHAPES
+    def test_loss_grads_match_dense(self, b, kk):
         from moco_tpu.ops.fused_infonce import fused_infonce_loss
         from moco_tpu.ops.losses import cross_entropy, infonce_logits
 
-        q = _rand((self.B, self.C), 3)
-        k = _rand((self.B, self.C), 4)
-        queue = _rand((self.K, self.C), 5)
+        q = _rand((b, self.C), 3)
+        k = _rand((b, self.C), 4)
+        queue = _rand((kk, self.C), 5)
         k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
         queue = queue / jnp.linalg.norm(queue, axis=-1, keepdims=True)
 
@@ -90,6 +99,23 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out), rtol=2e-2, atol=5e-3)
         np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), rtol=2e-2, atol=5e-3)
 
+    def test_forward_bf16_at_vit_b16_shape(self):
+        """ViT-B/16 as the v3 step feeds it: 12 heads of 64, 197 tokens,
+        bf16 operands (the dots run in the input dtype)."""
+        from moco_tpu.ops.flash_attention import _attn_reference, flash_attention_with_lse
+
+        q, k, v = (_rand((2, 12, 197, 64), 40 + i, jnp.bfloat16) for i in range(3))
+        out, lse = jax.jit(
+            lambda q, k, v: flash_attention_with_lse(q, k, v, None, 128, 128, False)
+        )(q, k, v)
+        ref_out, ref_lse = _attn_reference(
+            *(x.astype(jnp.float32) for x in (q, k, v)), 64**-0.5
+        )
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref_out), rtol=5e-2, atol=2e-2
+        )
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), rtol=5e-2, atol=2e-2)
+
     @pytest.mark.parametrize("seq", [256, 197], ids=["block-divisible", "padded"])
     def test_grads_match_dense(self, seq):
         from moco_tpu.ops.flash_attention import _attn_reference, flash_attention
@@ -122,3 +148,51 @@ class TestFlashAttention:
         np.testing.assert_allclose(
             np.asarray(out_flash), np.asarray(out_dense), rtol=2e-2, atol=2e-2
         )
+
+
+class TestFusedIVFScan:
+    """The fused IVF cell scan (serve/index.py) compiled by Mosaic: the
+    kernel alone at the serving dictionary's geometry, then through
+    `EmbeddingIndex` where a TPU backend selects it by default."""
+
+    def test_cell_scores_match_dense(self):
+        from moco_tpu.serve.index import _fused_cell_scores_pallas
+
+        # K=65536, d=128 -> train_ivf's defaults: nlist=256, cell_cap=512
+        m, d, nlist, cell_cap, nprobe = 8, 128, 256, 512, 8
+        # unit rows, like the dictionary's: scores are cosines
+        unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+        queries = unit(_rand((m, d), 30))
+        cell_rows = unit(_rand((nlist, cell_cap, d), 31))
+        probes = jax.random.randint(jax.random.PRNGKey(32), (m, nprobe), 0, nlist)
+        got = jax.jit(_fused_cell_scores_pallas)(queries, cell_rows, probes)
+        want = jnp.einsum(
+            "md,mpcd->mpc", queries, cell_rows[probes],
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        # the kernel's f32 dot runs as bf16 MXU passes by default
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-3)
+
+    def test_index_fused_matches_composed(self):
+        from moco_tpu.ops.losses import l2_normalize
+        from moco_tpu.serve.index import EmbeddingIndex
+
+        rng = np.random.default_rng(0)
+        centers = rng.normal(size=(64, 128)).astype(np.float32)
+        rows = np.repeat(centers, 128, axis=0) + 0.2 * rng.normal(
+            size=(8192, 128)
+        ).astype(np.float32)
+        rows = np.asarray(l2_normalize(jnp.asarray(rows)))[rng.permutation(8192)]
+        q = np.asarray(l2_normalize(jnp.asarray(
+            rows[:32] + 0.05 * rng.normal(size=(32, 128)).astype(np.float32)
+        )))
+        idx = EmbeddingIndex(8192, 128)
+        assert idx._fused_pallas and not idx._fused_interpret
+        idx.snapshot(rows)
+        idx.train_ivf(nprobe=8)
+        sc, ic = idx.query(q, 5, mode="ivf")
+        sf, i_f = idx.query(q, 5, mode="ivf_fused")
+        np.testing.assert_allclose(sf, sc, rtol=2e-2, atol=2e-2)
+        # bf16-pass scores may reorder near-ties: compare neighbour SETS
+        overlap = np.mean([len(set(a) & set(b)) / 5 for a, b in zip(ic, i_f)])
+        assert overlap >= 0.9, overlap

@@ -22,7 +22,7 @@ from moco_tpu.models import create_vit, sincos_2d_posembed
 from moco_tpu.parallel import create_mesh, shard_batch
 from moco_tpu.utils.config import DataConfig, MocoConfig, OptimConfig, TrainConfig
 from moco_tpu.utils.schedules import build_optimizer
-from moco_tpu.parallel.compat import shard_map
+from jax import shard_map
 
 IMG = 16  # 4x4 grid of 4px patches
 

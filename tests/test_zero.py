@@ -1,10 +1,13 @@
 """Sharded weight update (ZeRO over the data axis — parallel/zero.py,
-after arXiv:2004.13336): the sharded step must produce EXACTLY the same
+after arXiv:2004.13336): the sharded step must produce the same
 training trajectory as the replicated update, with opt state held as
 (n, m) shards — and, at stage 2/3, the params themselves persisting as
-shards with bucketed collectives, BIT-identical to stage 1."""
+shards with bucketed collectives, equal to stage 1 up to what
+separately compiled programs guarantee (stated above
+`_assert_losses_within_ulps`)."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -167,29 +170,82 @@ def test_zero_rejects_lars(stage):
 # ---------------------------------------------------------------------------
 
 
-def test_zero23_update_bit_identical_to_zero1():
+# What two SEPARATELY COMPILED step programs guarantee on jax 0.9.0.
+# The bucket transforms preserve per-leaf partitioning, so the stages
+# compute the same reductions — but XLA fuses and orders each program's
+# f32 arithmetic on its own, and the bits are not the same: the very
+# first forward (same params, same batch, before any update) already
+# reports 1.9157708883 under stage 1 and the layer-granular step and
+# 1.9157705307 under whole-tree stage 2/3, 3 ULP apart, and two SGD
+# steps through 2-rows-per-device BatchNorm amplify that. Measured after
+# two steps (this mesh, this config, both pairs the tests compare; the
+# figures are reproducible to every digit, whatever the core count):
+# losses 3 and 2 ULP apart; per leaf, ||a-b||/||a|| at most 4.97e-4 on
+# params_q, 9.78e-4 on the momentum buffers, 4.35e-6 on params_k,
+# 1.70e-6 on the BN statistics. The params_q figure belongs to the
+# 64-element BN biases, which start at zero and have next to no norm to
+# be relative to; every leaf of 10k elements or more is within 1.65e-5.
+# The bounds are those figures with ~1.2x room. A schedule bug is far
+# outside them: a lost or mis-scaled bucket (512 elements here) moves
+# its momentum leaf by a relative 0.1 or more. Bit equality held on the
+# older jax these tests were written on; it was a property of that
+# compiler, not of the schedule.
+FIRST_LOSS_ULPS = 3  # the first forward: no update has amplified anything yet
+LOSS_ULPS = 4
+REL_L2 = {"params_q": 6e-4, "opt_state": 1.2e-3, "params_k": 5.2e-6, "batch_stats": 2.1e-6}
+LARGE_LEAF, REL_L2_LARGE_PARAMS_Q = 10_000, 2e-5
+
+
+def _assert_losses_within_ulps(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    ulps = np.full(a.shape, LOSS_ULPS)
+    ulps[0] = FIRST_LOSS_ULPS
+    bound = ulps * np.spacing(np.maximum(np.abs(a), np.abs(b)))
+    assert (np.abs(a - b) <= bound).all(), f"loss trajectories diverged: {a} vs {b}"
+
+
+def _assert_leaves_close(xs, ys, rel_l2, what, rel_l2_large=None):
+    xs, ys = jax.tree.leaves(xs), jax.tree.leaves(ys)
+    assert len(xs) == len(ys), what
+    for i, (x, y) in enumerate(zip(xs, ys)):
+        x, y = np.asarray(x, np.float64), np.asarray(y, np.float64)
+        err, ref = np.linalg.norm(x - y), np.linalg.norm(x)
+        bound = rel_l2_large if rel_l2_large and x.size >= LARGE_LEAF else rel_l2
+        assert err <= bound * ref, (
+            f"{what} leaf {i} {x.shape}: ||a-b||/||a|| = {err / max(ref, 1e-300):.3e} "
+            f"> {bound:g}"
+        )
+
+
+def _assert_params_q_close(xs, ys):
+    _assert_leaves_close(
+        xs, ys, REL_L2["params_q"], "params_q", rel_l2_large=REL_L2_LARGE_PARAMS_Q
+    )
+
+
+@functools.cache
+def _zero23_two_steps():
+    """The whole-tree stage-2/3 reference run, compiled once for the two
+    tests that compare against it (tier-1 wall time)."""
+    return _run_steps(_config(zero=True, stage=3), n_steps=2, return_step=True)
+
+
+def test_zero23_update_matches_zero1_to_compile_noise():
     """The stage-2/3 step (persistent shards, bucketed collectives,
-    gather-at-step-start, shard-local EMA) must be BIT-identical to the
-    validated stage-1 sharded update: the bucket transforms preserve
-    per-leaf partitioning, so every reduction runs in the same order.
-    (Stage 1 itself matches the replicated update to float tolerance —
-    test_zero_matches_replicated_update — psum vs psum_scatter reduce
-    in different orders, so bitwise equality across THAT boundary is
-    not expected.)"""
+    gather-at-step-start, shard-local EMA) must reproduce the validated
+    stage-1 sharded update — to what separately compiled programs
+    guarantee (the bounds and their reason are stated above)."""
     s1, l1 = _run_steps(_config(zero=True), n_steps=2)
-    s23, l23 = _run_steps(_config(zero=True, stage=3), n_steps=2)
-    assert l1 == l23, f"loss trajectories diverged: {l1} vs {l23}"
+    s23, l23, _ = _zero23_two_steps()
+    _assert_losses_within_ulps(l1, l23)
     cfg = _config(zero=True, stage=3)
     shapes = full_param_shapes(cfg, build_encoder(cfg.moco, num_data=8))
     q_full = unshard_tree_host(s23.params_q, shapes["enc"])
     k_full = unshard_tree_host(s23.params_k, shapes["enc"])
-    for a, b in zip(jax.tree.leaves(s1.params_q), jax.tree.leaves(q_full)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for a, b in zip(jax.tree.leaves(s1.params_k), jax.tree.leaves(k_full)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # opt state shares the (n, m) layout across stages: directly bitwise
-    for a, b in zip(jax.tree.leaves(s1.opt_state), jax.tree.leaves(s23.opt_state)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _assert_params_q_close(s1.params_q, q_full)
+    _assert_leaves_close(s1.params_k, k_full, REL_L2["params_k"], "params_k")
+    # opt state shares the (n, m) layout across stages: compared directly
+    _assert_leaves_close(s1.opt_state, s23.opt_state, REL_L2["opt_state"], "opt_state")
     # ... and the stage-2/3 params PERSIST as (8, m), one row per device,
     # shrinking the at-rest per-device state footprint (same runs reused
     # so the suite pays no extra compiles for the layout assertions)
@@ -202,33 +258,33 @@ def test_zero23_update_bit_identical_to_zero1():
     assert tree_shard_bytes(s23) < 0.5 * tree_shard_bytes(s1)
 
 
-def test_zero_layer_granular_bit_identical_and_peak():
+def test_zero_layer_granular_matches_zero23_and_peak():
     """Tentpole invariant (ISSUE 20): the layer-granular schedule —
     per-group just-in-time gathers inside rematerialized segments, one
     group prefetched ahead, AD-transpose psum_scatter landing summed
     cotangents on the shards — reproduces the whole-tree stage-2/3 step
-    BIT-identically on ResNet (losses, params, opt state, both stats
-    collections), while the analytic peak model bytes drop >= 2x below
-    the whole-tree gather's."""
-    s23, l23, st23 = _run_steps(_config(zero=True, stage=3), return_step=True)
+    on ResNet (losses, params, opt state, both stats collections) to
+    what separately compiled programs guarantee (bounds and reason
+    above `_assert_losses_within_ulps`), while the
+    analytic peak model bytes drop >= 2x below the whole-tree gather's."""
+    s23, l23, st23 = _zero23_two_steps()
     sl, ll, stl = _run_steps(
         _config(zero=True, stage=3, layer=True), return_step=True
     )
-    assert l23 == ll, f"loss trajectories diverged: {l23} vs {ll}"
+    _assert_losses_within_ulps(l23, ll)
     cfg = _config(zero=True, stage=3)
     shapes = full_param_shapes(cfg, build_encoder(cfg.moco, num_data=8))
-    for name in ("params_q", "params_k"):
-        a = unshard_tree_host(getattr(s23, name), shapes["enc"])
-        b = unshard_tree_host(getattr(sl, name), shapes["enc"])
-        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-    for x, y in zip(jax.tree.leaves(s23.opt_state), jax.tree.leaves(sl.opt_state)):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    q23, k23, ql, kl = (
+        unshard_tree_host(p, shapes["enc"])
+        for p in (s23.params_q, s23.params_k, sl.params_q, sl.params_k)
+    )
+    _assert_params_q_close(q23, ql)
+    _assert_leaves_close(k23, kl, REL_L2["params_k"], "params_k")
+    _assert_leaves_close(s23.opt_state, sl.opt_state, REL_L2["opt_state"], "opt_state")
     for coll in ("batch_stats_q", "batch_stats_k"):
-        for x, y in zip(
-            jax.tree.leaves(getattr(s23, coll)), jax.tree.leaves(getattr(sl, coll))
-        ):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        _assert_leaves_close(
+            getattr(s23, coll), getattr(sl, coll), REL_L2["batch_stats"], coll
+        )
     # the memory claim, analytically: shards + one live group pair vs
     # shards + the whole gathered tree
     assert stl.layer_granular and not st23.layer_granular
@@ -375,7 +431,7 @@ def test_group_plan_gather_matches_whole_tree_gather():
     leaves as one whole-tree BucketPlan gather (and the source values):
     the element->chunk assignment invariant extends across the group
     partition, so the layer schedule changes memory, not bits."""
-    from moco_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from moco_tpu.parallel.zero import GroupPlan
 
     P = jax.sharding.PartitionSpec
