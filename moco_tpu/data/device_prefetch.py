@@ -24,7 +24,8 @@ reuses its memory for the normalized output instead of allocating a
 fresh batch-sized buffer.
 
 Observability contract (wired end-to-end, see ISSUE 5): every transfer
-runs under a `transfer` span on the ring thread's trace track, the ring
+runs under a `transfer` span on the ring thread's trace track and the
+wait for a free slot after it under `ring_blocked`, the ring
 keeps per-batch `t_transfer`/`transfer_bytes` plus a live-depth gauge
 (`stats_payload()` feeds the driver's metrics lines and the fleet
 straggler vector), and the wire registers an `input.h2d` entry in the
@@ -88,7 +89,11 @@ def _ring_loop(
                 faults.maybe_delay(H2D_SITE)
                 batch, nbytes = transfer(item)
             seconds = time.perf_counter() - t0
-            if not _responsive_put(q, stop, (batch, seconds, nbytes)):
+            # the wait for a free slot is the input side's slack: near
+            # zero, the ring (or the decode behind it) sets the pace
+            with obs_span("ring_blocked", seq=seq):
+                placed = _responsive_put(q, stop, (batch, seconds, nbytes))
+            if not placed:
                 return
             seq += 1
         _responsive_put(q, stop, _END)

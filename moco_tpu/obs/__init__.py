@@ -27,6 +27,7 @@ from moco_tpu.obs.trace import (  # stdlib-only, eager
     counter,
     get_tracer,
     instant,
+    set_annotator,
     set_tracer,
     span,
     spans_to_chrome_events,
@@ -78,6 +79,7 @@ __all__ = [
     "Tracer",
     "counter",
     "get_tracer",
+    "set_annotator",
     "set_tracer",
     "span",
     "instant",

@@ -8,8 +8,10 @@ this module is the machine-checkable one — the golden schema test
 Line kinds (all carry `step` int + `time` float):
 
 - *training lines*: `loss` present -> require `epoch`/`lr`/`acc1`/
-  `acc5`; optionally the step-time breakdown (`t_data`/`t_step`, and
-  `t_dispatch`/`t_device` on probe-sampled lines), the input-wire
+  `acc5`; optionally the step-time breakdown (`t_data`/`t_step`, the
+  `phase/*` account of every step since the last line, and
+  `t_dispatch`/`t_device`/`t_probe_step` once a step after the first
+  has been probe-sampled), the input-wire
   gauges (`t_transfer`/`transfer_bytes`/`prefetch_depth_live` when the
   device prefetch ring is on), device-memory gauges
   (`hbm_live_bytes`/`hbm_peak_bytes`, number or null), health gauges
@@ -52,6 +54,9 @@ EVENT_KINDS = frozenset(
      # rescale line carries the rescale/* family below — old/new mesh
      # shape, old/new global batch, and the re-derived hyperparameters
      "preempt", "rescale",
+     # once a run, when the first step's outputs are ready: what each
+     # part of set-up cost (the setup/<part>_s family below)
+     "setup",
      # checkpoint-promotion audit lines (serve/promote.py
      # PromotionLedger): verdict + per-gate evidence in the promotion/*
      # family below
@@ -128,6 +133,9 @@ FIELD_VALIDATORS = {
     "t_step": _num,
     "t_dispatch": _num_or_null,
     "t_device": _num,
+    # the step the pair above was sampled on (a line repeats the pair
+    # until the next sample; the process's first step is never sampled)
+    "t_probe_step": _int_like,
     # input wire (data/device_prefetch.py — present when the device
     # prefetch ring is on): last batch's host→device transfer seconds,
     # its uint8 wire bytes, and how many staged batches were resident
@@ -292,6 +300,13 @@ FIELD_VALIDATORS = {
 # slo_violations, slo_ms, bucket_<b> histogram counts)
 PREFIX_VALIDATORS = {
     "ema_drift/": _num_or_null,
+    # the host's phase account (obs/stepstats.py phase_account): per-step
+    # mean seconds under each driver and ring span since the last line,
+    # and phase/steps, the steps it covers. Measured on every step, so
+    # never null.
+    "phase/": _num,
+    # the `setup` event line's parts (obs/stepstats.py setup_account)
+    "setup/": _num,
     # elastic rescale event fields (kappa, derived lr/momentum, ...);
     # the explicit entries above (dead_hosts list, int mesh shapes) win
     "rescale/": _num_or_null,

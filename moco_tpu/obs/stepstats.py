@@ -28,7 +28,6 @@ run always has the numbers; chip_smoke.py fails without them.
 
 from __future__ import annotations
 
-import time
 from typing import Optional
 
 import jax
@@ -42,14 +41,19 @@ class StepTimeProbe:
         probe.data_wait(seconds)        # host blocked on input
         probe.dispatched(seconds)       # step_fn call returned (async)
         if probe.should_sample(step):
-            t0 = time.perf_counter()
-            jax.block_until_ready(outputs)
-            probe.device_block(time.perf_counter() - t0)
+            with obs.span("device_wait") as wait:
+                jax.block_until_ready(outputs)
+            probe.device_block(wait.seconds, steps_done)
         probe.step_done(total_seconds)
 
-    `payload()` returns the fields for the metrics line: always
-    `t_data`/`t_step`; `t_dispatch`/`t_device` from the most recent
-    sampled step (absent until one happened).
+    The seconds come from the driver's spans (`_SpanCM.seconds`), one
+    clock read an edge. `payload()` returns the fields for the metrics
+    line: always `t_data`/`t_step`; `t_dispatch`/`t_device` from the
+    most recent sampled step with `t_probe_step`, the step they were
+    sampled on. The driver never feeds it the process's first step,
+    which compiles (or loads) the program and is timed as
+    `setup/first_step` instead: the three stay off the line until a
+    later step has been sampled.
 
     Under the software-pipelined driver loop (ISSUE 5) the log-step
     fetch is deferred one dispatch, so `step_done` receives the
@@ -66,6 +70,7 @@ class StepTimeProbe:
         self._last_dispatch: Optional[float] = None
         self._t_dispatch: Optional[float] = None
         self._t_device: Optional[float] = None
+        self._probe_step: Optional[int] = None
 
     def should_sample(self, step: int) -> bool:
         return self.every > 0 and step % self.every == 0
@@ -76,11 +81,13 @@ class StepTimeProbe:
     def dispatched(self, seconds: float) -> None:
         self._last_dispatch = seconds
 
-    def device_block(self, seconds: float) -> None:
+    def device_block(self, seconds: float, step: int) -> None:
         # a sampled step: the dispatch measured this iteration becomes
-        # the published pair (dispatch, device)
+        # the published pair (dispatch, device). `step` is the global
+        # step with this one done, as a log line's `step` counts.
         self._t_dispatch = self._last_dispatch
         self._t_device = seconds
+        self._probe_step = int(step)
 
     def step_done(self, seconds: float) -> None:
         self.t_step = seconds
@@ -96,7 +103,45 @@ class StepTimeProbe:
         if self._t_device is not None:
             out["t_dispatch"] = self._t_dispatch
             out["t_device"] = self._t_device
+            out["t_probe_step"] = self._probe_step
         return out
+
+
+# The host phases a log line accounts for, by span name: the driver
+# thread's (always on the line) and the prefetch ring thread's (on the
+# line once the ring has run). `log_flush` contains `metrics_fetch` and
+# `lr_fetch`, its two waits for the device; `device_wait` is the probe's
+# drain, on one step in `obs_probe_every`.
+DRIVER_PHASES = (
+    "data_wait", "step", "device_wait", "throttle_wait", "log_flush", "metrics_fetch", "lr_fetch",
+)
+RING_PHASES = ("transfer", "augment_dispatch", "ring_blocked")
+SETUP_PARTS = ("backend", "state_init", "checkpoint", "pipeline_start", "first_step")
+
+
+def _seconds_between(now: dict, before: dict, name: str) -> float:
+    return now.get(name, (0, 0.0))[1] - before.get(name, (0, 0.0))[1]
+
+
+def phase_account(now: dict, before: dict, steps: int) -> dict:
+    """The `phase/*` fields of a log line: per-step mean seconds of each
+    host phase between two `Tracer.totals()` snapshots `steps` steps
+    apart. Every step counts, where the probe samples one in
+    `obs_probe_every`. `phase/log_flush_host` is the flush less the fetch
+    of the step's metrics: the log path's host work, and whatever else
+    in it waits for the device (`phase/lr_fetch`)."""
+    steps = max(int(steps), 1)
+    names = DRIVER_PHASES + tuple(n for n in RING_PHASES if n in now)
+    out = {f"phase/{n}": _seconds_between(now, before, n) / steps for n in names}
+    out["phase/log_flush_host"] = out["phase/log_flush"] - out["phase/metrics_fetch"]
+    out["phase/steps"] = steps
+    return out
+
+
+def setup_account(now: dict, before: dict) -> dict:
+    """The `setup/<part>_s` fields of the `setup` event line: seconds
+    spent under each set-up span between two `Tracer.totals()` snapshots."""
+    return {f"setup/{p}_s": _seconds_between(now, before, f"setup/{p}") for p in SETUP_PARTS}
 
 
 def device_memory_stats(device=None) -> Optional[dict]:
@@ -161,6 +206,8 @@ def tree_shard_bytes(tree) -> int:
 
 __all__ = [
     "StepTimeProbe",
+    "phase_account",
+    "setup_account",
     "device_memory_stats",
     "memory_payload",
     "tree_shard_bytes",

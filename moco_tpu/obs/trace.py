@@ -13,7 +13,13 @@ answers that question with nested spans:
             state, metrics = step_fn(state, batch, rng)
 
 Spans are recorded per-thread (the prefetch producer's `host_decode`
-spans land on their own track) and written in two forms:
+spans land on their own track). One span has three readers besides the
+in-memory list: per-name totals (`Tracer.totals()`: count and seconds
+of every completed span, which the train driver differences between log
+lines into the `phase/*` account), the profiler (with an annotator
+installed, `set_annotator(jax.profiler.TraceAnnotation)`, entering a
+span also enters `moco/<name>` on the profiler's own clock, so a device
+trace shows what the host was doing in every idle gap), and two files:
 
 - a streaming JSONL file (one object per completed span, flushed as
   written — a SIGKILL loses at most the span being formatted), and
@@ -60,9 +66,11 @@ _NULL_SPAN = _NullSpan()
 
 class _SpanCM:
     """Context manager for one live span: records ts on enter, emits the
-    completed event on exit (even when the body raises)."""
+    completed event on exit (even when the body raises). `seconds` is
+    the span's duration once it has closed, so a caller that needs the
+    time of a statement reads it here and not off a clock of its own."""
 
-    __slots__ = ("tracer", "name", "args", "t0")
+    __slots__ = ("tracer", "name", "args", "t0", "seconds", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self.tracer = tracer
@@ -70,15 +78,21 @@ class _SpanCM:
         self.args = args
 
     def __enter__(self):
+        self._ann = _annotation(self.name, self.args)
+        if self._ann is not None:
+            self._ann.__enter__()
         self.tracer._stack().append(self.name)
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        self.seconds = t1 - self.t0
         stack = self.tracer._stack()
         stack.pop()
         self.tracer._emit(self.name, self.t0, t1, len(stack), self.args, exc_type)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -101,6 +115,7 @@ class Tracer:
         self._lock = tsan.make_lock("obs.trace")
         self._local = threading.local()
         self._spans: list[dict] = []
+        self._totals: dict[str, list] = {}
         self._dropped = 0
         self.max_spans = max_spans
         # multi-process runs tag every span with the process index so
@@ -129,6 +144,23 @@ class Tracer:
     def span(self, name: str, **args) -> _SpanCM:
         return _SpanCM(self, name, args)
 
+    def _record(self, rec: dict) -> None:
+        """Keep and stream one record; the caller holds the lock."""
+        if len(self._spans) < self.max_spans:
+            self._spans.append(rec)
+        else:
+            self._dropped += 1
+        if self._f is not None and not self._f.closed:
+            self._f.write(json.dumps(rec) + "\n")
+
+    def totals(self) -> dict:
+        """{name: (count, seconds)} over every span completed so far on
+        any thread. Two snapshots differenced give what each phase cost
+        between them; a child (`metrics_fetch` under `log_flush`) is
+        told from its parent by its name, and its time is in both."""
+        with self._lock:
+            return {name: (n, sec) for name, (n, sec) in self._totals.items()}
+
     def _emit(self, name, t0, t1, depth, args, exc_type) -> None:
         rec = {
             "name": name,
@@ -144,12 +176,13 @@ class Tracer:
         if exc_type is not None:
             rec["error"] = exc_type.__name__
         with self._lock:
-            if len(self._spans) < self.max_spans:
-                self._spans.append(rec)
-            else:
-                self._dropped += 1
-            if self._f is not None and not self._f.closed:
-                self._f.write(json.dumps(rec) + "\n")
+            if "instant" not in args:
+                total = self._totals.get(name)
+                if total is None:
+                    total = self._totals[name] = [0, 0.0]
+                total[0] += 1
+                total[1] += t1 - t0
+            self._record(rec)
 
     def emit_span(
         self,
@@ -178,12 +211,7 @@ class Tracer:
         if args:
             rec["args"] = args
         with self._lock:
-            if len(self._spans) < self.max_spans:
-                self._spans.append(rec)
-            else:
-                self._dropped += 1
-            if self._f is not None and not self._f.closed:
-                self._f.write(json.dumps(rec) + "\n")
+            self._record(rec)
 
     def instant(self, name: str, **args) -> None:
         """Zero-duration marker event (checkpoint committed, fault
@@ -205,12 +233,7 @@ class Tracer:
             "counter": {k: float(v) for k, v in values.items()},
         }
         with self._lock:
-            if len(self._spans) < self.max_spans:
-                self._spans.append(rec)
-            else:
-                self._dropped += 1
-            if self._f is not None and not self._f.closed:
-                self._f.write(json.dumps(rec) + "\n")
+            self._record(rec)
 
     # -- export ----------------------------------------------------------
 
@@ -315,6 +338,26 @@ def spans_to_chrome_events(
 # (one attribute read + one call — cheap enough for per-batch sites).
 
 _tracer: Optional[Tracer] = None
+# `factory(name, **args)` -> context manager that puts the span on the
+# profiler's timeline; this module stays stdlib-only, so whoever imports
+# jax installs `jax.profiler.TraceAnnotation` (train driver, replica)
+_annotator = None
+
+
+def set_annotator(factory):
+    """Install (or clear, with None) the profiler annotation factory;
+    returns the previous one so callers can restore it. Spans given
+    explicit stamps (`emit_span`) are rendered after the fact and are
+    never annotated."""
+    global _annotator
+    prev = _annotator
+    _annotator = factory
+    return prev
+
+
+def _annotation(name: str, args: dict):
+    factory = _annotator
+    return factory(f"moco/{name}", **args) if factory is not None else None
 
 
 def set_tracer(tracer: Optional[Tracer]) -> Optional[Tracer]:
@@ -332,7 +375,9 @@ def get_tracer() -> Optional[Tracer]:
 
 def span(name: str, **args):
     t = _tracer
-    return t.span(name, **args) if t is not None else _NULL_SPAN
+    if t is not None:
+        return t.span(name, **args)
+    return _annotation(name, args) or _NULL_SPAN
 
 
 def instant(name: str, **args) -> None:

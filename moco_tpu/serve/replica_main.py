@@ -65,9 +65,12 @@ def main(argv=None) -> int:
 
     import os
 
+    import jax
+
     from moco_tpu.analysis import contracts as contract_cov
     from moco_tpu.obs import quality
     from moco_tpu.obs.sinks import JsonlSink
+    from moco_tpu.obs.trace import set_annotator
     from moco_tpu.serve.engine import InferenceEngine, load_serving_encoder
     from moco_tpu.serve.index import EmbeddingIndex
     from moco_tpu.serve.server import ServeServer
@@ -75,6 +78,10 @@ def main(argv=None) -> int:
     from moco_tpu.utils.checkpoint import CheckpointManager
 
     faults.install_from_env()
+    # the engine's spans (`serve_aot_compile`, `serve_embed`,
+    # `serve_query`) as `moco/<name>` on the profiler's clock: a device
+    # trace of this replica then says what the host was doing
+    prev_annotator = set_annotator(jax.profiler.TraceAnnotation)
     # contract-coverage arm: MOCO_CONTRACT_COVERAGE=1 (planted by a
     # smoke script before the supervisor spawns us) installs a recorder;
     # the snapshot dumps on graceful exit below. A killed replica never
@@ -135,6 +142,7 @@ def main(argv=None) -> int:
     # then the ordinary close (final metrics flush included)
     drained = server.drain(timeout=args.drain_timeout_s)
     server.close()
+    set_annotator(prev_annotator)
     if sink is not None:
         sink.close()
     if recorder is not None and args.workdir:

@@ -15,6 +15,7 @@ Library entry: `train(config) -> final metrics`. CLI: repo-root
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import os
@@ -43,7 +44,13 @@ from moco_tpu.obs import comms
 from moco_tpu.obs.alerts import AlertEngine, FatalAlertError, parse_rules
 from moco_tpu.obs.fleet import FleetAggregator, Heartbeat
 from moco_tpu.obs.sinks import build_sinks, per_process_filename
-from moco_tpu.obs.stepstats import StepTimeProbe, memory_payload, tree_shard_bytes
+from moco_tpu.obs.stepstats import (
+    StepTimeProbe,
+    memory_payload,
+    phase_account,
+    setup_account,
+    tree_shard_bytes,
+)
 from moco_tpu.parallel.elastic import (
     RESCALE_EXIT_CODE,
     ElasticCoordinator,
@@ -121,7 +128,10 @@ def train(
     # Spans stream to trace_events.jsonl (crash-safe tail; per-process
     # filenames when processes share a workdir — scripts/trace_merge.py
     # stitches them into one Perfetto file with a track per host) and
-    # export as a Chrome trace on exit.
+    # export as a Chrome trace on exit. Every span is also entered as
+    # `moco/<name>` on jax's profiler, so a device trace taken over this
+    # run (--profile-steps, or anyone's start_trace) carries the host's
+    # phases on the device's clock.
     tracer = obs.Tracer(
         os.path.join(
             config.workdir, per_process_filename("trace_events.jsonl", pidx)
@@ -129,6 +139,7 @@ def train(
         process_index=pidx,
     )
     prev_tracer = obs.set_tracer(tracer)
+    prev_annotator = obs.set_annotator(jax.profiler.TraceAnnotation)
     try:
         # Elastic outer loop (parallel/elastic.py): each _train_impl
         # attempt runs on one mesh shape; an ElasticRescale (heartbeat
@@ -177,6 +188,7 @@ def train(
             )
         except Exception as e:  # telemetry must never mask the real error
             print(f"WARNING: chrome trace export failed: {e!r}", flush=True)
+        obs.set_annotator(prev_annotator)
         obs.set_tracer(prev_tracer)
         tracer.close()
 
@@ -189,9 +201,20 @@ def _train_impl(
     profile_steps: Optional[tuple],
     dead_hosts: frozenset = frozenset(),
 ) -> dict:
+    # Set-up is timed in five spans (`setup/*`); what they had cost when
+    # the first step's outputs are ready goes out once as the `setup`
+    # event line. The baseline makes an elastic re-entry count its own.
+    tracer = obs.get_tracer()  # train() installed it
+    setup_base = tracer.totals()
+    setup_open = True
+
+    def setup_span(name: str):
+        """The process's first step is still set-up: its data wait and
+        its dispatch are timed under a set-up name as well."""
+        return obs.span(name) if setup_open else contextlib.nullcontext()
+
     # (the multi-host rendezvous already ran in train(), before the
-    # tracer needed the process index; this is a no-op then, and keeps
-    # direct _train_impl callers working)
+    # tracer needed the process index; this is a no-op then)
     maybe_initialize_multihost()
     # Auto-scale (utils/config.py): `config` arrives carrying REFERENCE
     # hyperparameters; the live lr / EMA momentum are derived here from
@@ -208,25 +231,27 @@ def _train_impl(
         )
     if config.elastic and config.parallel.num_model > 1:
         raise ValueError("elastic=True supports num_model=1 meshes only")
-    if dead_hosts:
-        # post-rescale attempt: the mesh covers the SURVIVING devices
-        # only (the agreed width; feasibility was decided by the plan)
-        mesh = create_mesh(
-            num_data=config.parallel.num_data,
-            num_model=config.parallel.num_model,
-            devices=surviving_devices(dead_hosts),
-        )
-    elif config.parallel.num_data is None:
-        # slice-aware layout: on multi-slice deployments the data axis
-        # orders ICI-adjacent chips together so grad psum rides ICI first
-        mesh = create_multislice_mesh(num_model=config.parallel.num_model)
-    else:
-        mesh = create_mesh(
-            num_data=config.parallel.num_data, num_model=config.parallel.num_model
-        )
+    with obs.span("setup/backend"):
+        if dead_hosts:
+            # post-rescale attempt: the mesh covers the SURVIVING devices
+            # only (the agreed width; feasibility was decided by the plan)
+            mesh = create_mesh(
+                num_data=config.parallel.num_data,
+                num_model=config.parallel.num_model,
+                devices=surviving_devices(dead_hosts),
+            )
+        elif config.parallel.num_data is None:
+            # slice-aware layout: on multi-slice deployments the data axis
+            # orders ICI-adjacent chips together so grad psum rides ICI first
+            mesh = create_multislice_mesh(num_model=config.parallel.num_model)
+        else:
+            mesh = create_mesh(
+                num_data=config.parallel.num_data, num_model=config.parallel.num_model
+            )
     num_data = mesh.shape["data"]
 
-    pipeline = TwoCropPipeline(config.data, mesh, seed=config.seed, dataset=dataset)
+    with obs.span("setup/pipeline_start"):
+        pipeline = TwoCropPipeline(config.data, mesh, seed=config.seed, dataset=dataset)
     steps_per_epoch = config.steps_per_epoch or pipeline.steps_per_epoch
     if steps_per_epoch <= 0:
         raise ValueError("empty pipeline: fewer examples than one global batch")
@@ -250,18 +275,21 @@ def _train_impl(
     sample = jnp.zeros((1, config.data.image_size, config.data.image_size, 3), jnp.float32)
     zero = config.parallel.shard_weight_update
     zero23 = zero_stage23(config)
-    state = create_state(
-        init_rng, config, encoder, tx, sample, predictor=predictor,
-        zero_num_data=num_data if zero else None,
-    )
+    with obs.span("setup/state_init"):
+        state = create_state(
+            init_rng, config, encoder, tx, sample, predictor=predictor,
+            zero_num_data=num_data if zero else None,
+        )
 
     # Checkpoint ids are the GLOBAL STEP (unique and monotonic even for
     # mid-epoch preemption saves); the epoch lives in extras. Save
     # frequency is gated here in the driver, not by Orbax's policy.
-    ckpt = CheckpointManager(
-        config.workdir, keep=config.checkpoint_keep, save_interval=1,
-        async_save=config.checkpoint_async,
-    )
+    with obs.span("setup/checkpoint"):
+        ckpt = CheckpointManager(
+            config.workdir, keep=config.checkpoint_keep, save_interval=1,
+            async_save=config.checkpoint_async,
+        )
+        resuming = ckpt.latest_step() is not None
 
     def emergency_save(s, completed_epoch: int, reason: str, extra_fields=None) -> None:
         """The shared save-first-die-second path: the watchdog stall,
@@ -289,7 +317,7 @@ def _train_impl(
         ckpt.wait()
 
     start_epoch = 0
-    if ckpt.latest_step() is not None:  # --resume semantics, automatic
+    if resuming:  # --resume semantics, automatic
 
         def _check_compat(extra: dict) -> None:
             # fail fast with a readable diff BEFORE the state restore: a
@@ -334,20 +362,23 @@ def _train_impl(
                     zero_stage=saved_stage,
                 ),
             )
-            saved_template = create_state(  # mocolint: disable=JX003  (restore TEMPLATE: values are overwritten by the checkpoint read, only shapes matter — key reuse is deliberate)
-                init_rng, saved_cfg, encoder, tx, sample, predictor=predictor,
-                zero_num_data=saved_n if saved_zero else None,
-            )
-            restored, extra = ckpt.restore(saved_template, validate_extra=_check_compat)
+            with obs.span("setup/state_init"):
+                saved_template = create_state(  # mocolint: disable=JX003  (restore TEMPLATE: values are overwritten by the checkpoint read, only shapes matter — key reuse is deliberate)
+                    init_rng, saved_cfg, encoder, tx, sample, predictor=predictor,
+                    zero_num_data=saved_n if saved_zero else None,
+                )
+            with obs.span("setup/checkpoint"):
+                restored, extra = ckpt.restore(saved_template, validate_extra=_check_compat)
             full_cfg = dataclasses.replace(
                 config,
                 parallel=dataclasses.replace(
                     config.parallel, shard_weight_update=False
                 ),
             )
-            full_template = create_state(  # mocolint: disable=JX003  (shape-only template for reshard_state — deliberate key reuse, values never train)
-                init_rng, full_cfg, encoder, tx, sample, predictor=predictor
-            )
+            with obs.span("setup/state_init"):
+                full_template = create_state(  # mocolint: disable=JX003  (shape-only template for reshard_state — deliberate key reuse, values never train)
+                    init_rng, full_cfg, encoder, tx, sample, predictor=predictor
+                )
             state = reshard_state(restored, state, full_template)
             print0(
                 "resume reshard: checkpoint ZeRO layout "
@@ -356,7 +387,8 @@ def _train_impl(
         else:
             # a corrupt newest checkpoint is quarantined and the next-older
             # step restores instead (fault-tolerance layer)
-            state, extra = ckpt.restore(state, validate_extra=_check_compat)
+            with obs.span("setup/checkpoint"):
+                state, extra = ckpt.restore(state, validate_extra=_check_compat)
         start_epoch = int(extra.get("epoch", 0)) + 1
         print0(f"resumed from epoch {start_epoch - 1} (step {int(state.step)})")
 
@@ -779,6 +811,7 @@ def _train_impl(
                 # pipelined loop is the meaningful number (per-iteration
                 # host wall is just dispatch, ~ms)
                 flush_anchor = {"wall": time.perf_counter(), "gstep": gstep_host}
+                phase_anchor = {"totals": tracer.totals(), "gstep": gstep_host}
                 stop_now = False
                 pending: Optional[dict] = None
                 inflight: deque = deque()
@@ -792,7 +825,12 @@ def _train_impl(
                     recompile guard, fleet gather, heartbeat."""
                     nonlocal state
                     i, gstep = p["i"], p["gstep"]
-                    fetched = jax.device_get(p["metrics"])
+                    # taken before this flush does anything: the account
+                    # on this line then holds whole spans only, the last
+                    # flush (with its own fetch) and every step since it
+                    phase_now = tracer.totals()
+                    with obs.span("metrics_fetch", step=gstep):
+                        fetched = jax.device_get(p["metrics"])
                     m = {
                         k: (float(v) if getattr(v, "ndim", 1) == 0 else v)
                         for k, v in fetched.items()
@@ -872,9 +910,14 @@ def _train_impl(
                     probe.step_done(t_step)
                     progress.display(i)
                     wire = ring_stats() if ring_stats is not None else {}
+                    # the schedule is jnp: a handful of tiny device programs
+                    # that queue behind the steps in flight, and `float`
+                    # waits for them
+                    with obs.span("lr_fetch", step=gstep):
+                        lr_now = float(lr_schedule(gstep - 1))
                     payload = {
                         "epoch": epoch,
-                        "lr": float(lr_schedule(gstep - 1)),
+                        "lr": lr_now,
                         **m,
                         # step-time breakdown + device memory
                         # (obs): t_data/t_step always; dispatch/
@@ -882,6 +925,13 @@ def _train_impl(
                         # step; hbm gauges null where the backend
                         # lacks memory_stats (CPU hosts)
                         **probe.payload(),
+                        # the host's phase account: per-step mean seconds
+                        # of every driver and ring span since the last
+                        # line, every step counted
+                        **phase_account(
+                            phase_now, phase_anchor["totals"],
+                            gstep - phase_anchor["gstep"],
+                        ),
                         **memory_payload(),
                         # at-rest state footprint (analytic, per device)
                         "hbm_state_bytes": hbm_state_bytes,
@@ -907,6 +957,7 @@ def _train_impl(
                             else {}
                         ),
                     }
+                    phase_anchor["totals"], phase_anchor["gstep"] = phase_now, gstep
                     # fault-tolerance observability: only present
                     # when nonzero, so clean runs keep clean lines
                     if guard["nan_steps"]:
@@ -989,64 +1040,83 @@ def _train_impl(
                     for i in range(steps_per_epoch):
                         if profile_window is not None:
                             profile_window.on_step(gstep_host)
-                        fetch0 = time.perf_counter()
-                        with obs.span("data_wait", step=gstep_host):
-                            batch = next(it, None)
-                        if batch is None:
-                            break
-                        t_data = time.perf_counter() - fetch0
-                        data_time.update(t_data)
-                        probe.data_wait(t_data)
-                        t_disp0 = time.perf_counter()
-                        with obs.span("step", step=gstep_host):
-                            if gatherer is not None:
-                                # the gather for THIS step was issued one
-                                # iteration ago and ran under the previous
-                                # step; take() blocks only for what didn't
-                                # fit under it (the overlap/zero gauge)
-                                gathered = gatherer.take()
-                                state, metrics = step_fn.step(
-                                    state, gathered, batch, root_rng
-                                )
-                                gatherer.submit(state, gstep_host + 1)
-                            else:
-                                state, metrics = step_fn(state, batch, root_rng)
-                        probe.dispatched(time.perf_counter() - t_disp0)
-                        if probe.should_sample(gstep_host):
-                            # drain the device queue ON SAMPLED STEPS ONLY,
-                            # splitting host dispatch from device compute —
-                            # every other step stays sync-free
-                            with obs.span("device_wait", step=gstep_host):
-                                t_dev0 = time.perf_counter()
-                                jax.block_until_ready((state, metrics))
-                            probe.device_block(time.perf_counter() - t_dev0)
-                        gstep_host += 1
-                        # bounded in-flight window: wait on the OLDEST
-                        # dispatched step only — `pipeline_depth` newer
-                        # steps stay queued on the device
-                        inflight.append(metrics)
-                        if len(inflight) > pipeline_depth:
-                            jax.block_until_ready(inflight.popleft())
-                        if wd is not None:
-                            wd.beat()  # a timestamp assignment — no device sync
-                        if pending is not None:
-                            # the previous log step's metrics, fetched
-                            # with this step already queued behind them
-                            flush_log(pending)
-                            pending = None
-                        if preempted["count"]:
-                            stop_now = True
-                            break
-                        if i % config.log_every == 0 or i == steps_per_epoch - 1:
-                            pending = {
-                                "i": i, "gstep": gstep_host,
-                                "metrics": metrics, "state": state,
-                                "t_data": t_data,
-                            }
+                        # one number for the host spans and the device
+                        # programs of this step in a profiler trace
+                        step_scope = jax.profiler.StepTraceAnnotation(
+                            "moco/train_step", step_num=gstep_host
+                        )
+                        with step_scope:
+                            with setup_span("setup/pipeline_start"):
+                                with obs.span("data_wait", step=gstep_host) as waited:
+                                    batch = next(it, None)
+                            if batch is None:
+                                break
+                            t_data = waited.seconds
+                            data_time.update(t_data)
+                            probe.data_wait(t_data)
+                            with setup_span("setup/first_step"):
+                                with obs.span("step", step=gstep_host) as dispatched:
+                                    if gatherer is not None:
+                                        # the gather for THIS step was issued one
+                                        # iteration ago and ran under the previous
+                                        # step; take() blocks only for what didn't
+                                        # fit under it (the overlap/zero gauge)
+                                        gathered = gatherer.take()
+                                        state, metrics = step_fn.step(
+                                            state, gathered, batch, root_rng
+                                        )
+                                        gatherer.submit(state, gstep_host + 1)
+                                    else:
+                                        state, metrics = step_fn(state, batch, root_rng)
+                                probe.dispatched(dispatched.seconds)
+                                if setup_open or probe.should_sample(gstep_host):
+                                    # drain the device queue ON SAMPLED STEPS ONLY,
+                                    # splitting host dispatch from device compute —
+                                    # every other step stays sync-free. The process's
+                                    # first step compiles or loads the program: set-up
+                                    # ends when its outputs are ready, and the probe
+                                    # is not fed a compile as a step time.
+                                    with obs.span("device_wait", step=gstep_host) as drained:
+                                        jax.block_until_ready((state, metrics))
+                                    if not setup_open:
+                                        probe.device_block(drained.seconds, gstep_host + 1)
+                            gstep_host += 1
+                            if setup_open:
+                                setup_open = False
+                                writer.write(gstep_host, {
+                                    "epoch": epoch,
+                                    "event": "setup",
+                                    **setup_account(tracer.totals(), setup_base),
+                                })
+                            # bounded in-flight window: wait on the OLDEST
+                            # dispatched step only — `pipeline_depth` newer
+                            # steps stay queued on the device
+                            inflight.append(metrics)
+                            if len(inflight) > pipeline_depth:
+                                with obs.span("throttle_wait", step=gstep_host):
+                                    jax.block_until_ready(inflight.popleft())
+                            if wd is not None:
+                                wd.beat()  # a timestamp assignment — no device sync
+                            if pending is not None:
+                                # the previous log step's metrics, fetched
+                                # with this step already queued behind them
+                                with obs.span("log_flush", step=pending["gstep"]):
+                                    flush_log(pending)
+                                pending = None
+                            if preempted["count"]:
+                                stop_now = True
+                                break
+                            if i % config.log_every == 0 or i == steps_per_epoch - 1:
+                                pending = {
+                                    "i": i, "gstep": gstep_host,
+                                    "metrics": metrics, "state": state,
+                                    "t_data": t_data,
+                                }
                     if pending is not None and not stop_now:
                         # the epoch's final log step has no successor
                         # iteration — flush it here
-                        flush_log(pending)
+                        with obs.span("log_flush", step=pending["gstep"]):
+                            flush_log(pending)
                         pending = None
                 finally:
                     # epoch teardown / preemption exit: release the

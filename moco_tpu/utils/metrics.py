@@ -111,6 +111,18 @@ class MetricWriter(JsonlSink):
 _profiler_state = {"active": False}
 
 
+def _profile_options():
+    """Device events and the host's annotated spans (`moco/<name>`, what
+    `obs.span` enters), no Python tracer: with `start_trace`'s defaults
+    every Python call is recorded and a traced ResNet-50 step took 1.9 s
+    where an untraced one takes 0.177 s (PERF.md section 6, PR 24), so
+    the trace stood for nothing."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return opts
+
+
 def _start_profiler(logdir: str) -> bool:
     """Start a trace; returns True when THIS call owns the stop. A
     dangling trace from a previous failed region is stopped and the
@@ -118,7 +130,7 @@ def _start_profiler(logdir: str) -> bool:
     if _profiler_state["active"]:
         return False  # reentrant region: outer owns the trace
     try:
-        jax.profiler.start_trace(logdir)
+        jax.profiler.start_trace(logdir, profiler_options=_profile_options())
     except Exception:
         # a trace someone else started and never stopped — clear it and
         # retry once; a second failure is a real error and propagates
@@ -126,7 +138,7 @@ def _start_profiler(logdir: str) -> bool:
             jax.profiler.stop_trace()
         except Exception:
             pass
-        jax.profiler.start_trace(logdir)
+        jax.profiler.start_trace(logdir, profiler_options=_profile_options())
     _profiler_state["active"] = True
     return True
 

@@ -316,6 +316,37 @@ class TestRingUnit:
         with pytest.raises(ValueError, match="depth"):
             DevicePrefetchRing(iter([]), lambda x: (x, 0), depth=0)
 
+    @pytest.mark.parametrize("consumer_delay", [0.0, 0.03])
+    def test_ring_blocked_is_the_input_sides_slack(self, consumer_delay):
+        """The wait for a free slot is timed as `ring_blocked`: next to
+        nothing while the consumer keeps up, and about the consumer's
+        extra time a batch once it is slowed (through the `delay@site`
+        hook), with the `transfer` spans unchanged."""
+        from moco_tpu import obs
+
+        n = 8
+        tracer = obs.Tracer()
+        prev = obs.set_tracer(tracer)
+        try:
+            if consumer_delay:
+                faults.install(f"delay@site=test.consumer:seconds={consumer_delay}")
+            ring = DevicePrefetchRing(iter(range(n)), lambda x: (x, 1), depth=1)
+            for _ in ring:
+                faults.maybe_delay("test.consumer")
+            ring.close()
+        finally:
+            obs.set_tracer(prev)
+        totals = tracer.totals()
+        assert totals["transfer"][0] == n and totals["ring_blocked"][0] == n
+        blocked = totals["ring_blocked"][1]
+        if consumer_delay:
+            # depth 1: one batch waits in the slot, so the ring sits out
+            # all of the consumer's delays but the first two
+            assert blocked >= (n - 2) * consumer_delay * 0.9
+        else:
+            assert blocked < 0.05
+        assert totals["transfer"][1] < 0.05
+
     def test_empty_payload_before_first_batch(self):
         ring = DevicePrefetchRing(iter([]), lambda x: (x, 0), depth=1)
         assert list(ring) == []

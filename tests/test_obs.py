@@ -97,6 +97,112 @@ def test_set_tracer_install_and_restore():
     assert obs.get_tracer() is prev
 
 
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: counts enters and
+    leaves per name and keeps the keyword arguments it was made with."""
+
+    log: list = []
+
+    def __init__(self, name, **args):
+        self.name, self.args = name, args
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.args))
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.log.append(("exit", self.name, exc_type))
+        return False
+
+
+@pytest.fixture
+def annotations():
+    _FakeAnnotation.log = []
+    prev = obs.set_annotator(_FakeAnnotation)
+    yield _FakeAnnotation.log
+    assert obs.set_annotator(prev) is _FakeAnnotation
+
+
+def test_annotator_entered_and_left_once_per_span(annotations):
+    t = Tracer()
+    with t.span("epoch", epoch=3):
+        with t.span("step", step=7):
+            pass
+    # the profiler sees `moco/<name>` with the span's own arguments,
+    # entered outermost first and left innermost first
+    assert annotations == [
+        ("enter", "moco/epoch", {"epoch": 3}),
+        ("enter", "moco/step", {"step": 7}),
+        ("exit", "moco/step", None),
+        ("exit", "moco/epoch", None),
+    ]
+
+
+def test_annotator_left_when_the_body_raises(annotations):
+    t = Tracer()
+    with pytest.raises(RuntimeError):
+        with t.span("boom"):
+            raise RuntimeError("x")
+    assert annotations == [("enter", "moco/boom", {}), ("exit", "moco/boom", RuntimeError)]
+    assert t.snapshot()[0]["error"] == "RuntimeError"  # and the span was still recorded
+
+
+def test_annotator_never_sees_stamped_spans_instants_or_counters(annotations):
+    t = Tracer()
+    t.emit_span("serve_request", 1.0, 2.0, request="r1")  # rendered after the fact
+    t.instant("checkpoint_committed")
+    t.counter("prefetch_depth_live", depth=2)
+    assert annotations == []
+    assert t.totals() == {}  # none of them is a phase with a duration of its own
+
+
+def test_module_level_span_with_annotator_and_no_tracer(annotations):
+    assert obs.get_tracer() is None
+    with obs.span("serve_embed", bucket=8) as cm:
+        assert isinstance(cm, _FakeAnnotation)  # the annotation alone
+    assert [a[:2] for a in annotations] == [
+        ("enter", "moco/serve_embed"), ("exit", "moco/serve_embed"),
+    ]
+
+
+def test_span_seconds_and_totals_give_per_step_means():
+    """A fake driver loop: the phase account of a log line is the
+    difference of two `totals()` snapshots over the steps between them,
+    and a span's own `seconds` is what the totals add up."""
+    from moco_tpu.obs.stepstats import DRIVER_PHASES, phase_account
+
+    t = Tracer()
+    before = t.totals()
+    waited = 0.0
+    for step in range(4):
+        with t.span("data_wait", step=step) as w:
+            pass
+        waited += w.seconds
+        with t.span("step", step=step):
+            pass
+    with t.span("log_flush"):
+        with t.span("metrics_fetch") as fetch:
+            pass
+    now = t.totals()
+    assert now["data_wait"][0] == 4 and now["step"][0] == 4 and now["log_flush"][0] == 1
+    assert now["data_wait"][1] == pytest.approx(waited)
+    pay = phase_account(now, before, steps=4)
+    assert pay["phase/steps"] == 4
+    assert pay["phase/data_wait"] == pytest.approx(waited / 4)
+    assert pay["phase/throttle_wait"] == 0.0  # a driver phase is on the line even at zero
+    assert pay["phase/metrics_fetch"] == pytest.approx(fetch.seconds / 4)
+    # a child is told from its parent by name; the parent's time holds it
+    assert pay["phase/log_flush_host"] == pytest.approx(
+        pay["phase/log_flush"] - pay["phase/metrics_fetch"]
+    ) and pay["phase/log_flush_host"] >= 0.0
+    assert {f"phase/{n}" for n in DRIVER_PHASES} <= set(pay)
+    assert "phase/ring_blocked" not in pay  # no ring ran: its phases stay off the line
+    # the next line starts from this snapshot and sees nothing of the old window
+    again = phase_account(t.totals(), now, steps=2)
+    assert again["phase/data_wait"] == 0.0 and again["phase/steps"] == 2
+    assert schema.validate_line({"step": 4, "time": 1.0, **pay}) == []
+
+
 def test_tracer_bounds_memory_not_stream(tmp_path):
     t = Tracer(jsonl_path=str(tmp_path / "s.jsonl"), max_spans=2)
     for i in range(5):
@@ -311,11 +417,15 @@ class _FakeProfiler:
     """Stands in for jax.profiler: records start/stop calls and can be
     armed to raise on start (the dangling-trace failure mode)."""
 
+    ProfileOptions = jax.profiler.ProfileOptions
+
     def __init__(self):
         self.calls = []
+        self.options = []
         self.active = False
 
-    def start_trace(self, logdir):
+    def start_trace(self, logdir, profiler_options=None):
+        self.options.append(profiler_options)
         if self.active:
             self.calls.append(("start_fail", logdir))
             raise RuntimeError("profiler already active")
@@ -352,6 +462,22 @@ def test_profiler_trace_recovers_from_dangling_trace(fake_profiler):
     # the dangler was stopped, then start retried and succeeded
     assert ("start_fail", "/tmp/prof") in fake_profiler.calls
     assert fake_profiler.calls[-2:] == [("start", "/tmp/prof"), ("stop",)]
+
+
+def test_profiler_starts_without_the_python_tracer(fake_profiler):
+    """`--profile-steps` / `--profile-dir` must give a trace whose steps
+    take what untraced steps take: device events and the host's annotated
+    spans, never the Python tracer (`start_trace`'s default)."""
+    from moco_tpu.utils.metrics import ProfilerWindow, profiler_trace
+
+    with profiler_trace("/tmp/prof"):
+        pass
+    w = ProfilerWindow("/tmp/w", 0, 1)
+    w.on_step(0)
+    w.close()
+    assert len(fake_profiler.options) == 2
+    for opts in fake_profiler.options:
+        assert opts.python_tracer_level == 0 and opts.host_tracer_level == 1
 
 
 def test_profiler_trace_reentrant_inner_is_noop(fake_profiler):
@@ -411,9 +537,10 @@ def test_step_probe_sampling_schedule_and_payload():
     p.step_done(0.5)
     pay = p.payload()
     assert pay == {"t_data": 0.25, "t_step": 0.5}  # no sample yet
-    p.device_block(0.4)
+    p.device_block(0.4, step=4)
     pay = p.payload()
     assert pay["t_dispatch"] == 0.03 and pay["t_device"] == 0.4
+    assert pay["t_probe_step"] == 4  # which step the repeated pair was sampled on
     disabled = StepTimeProbe(every=0)
     assert not any(disabled.should_sample(s) for s in range(10))
 
@@ -569,6 +696,21 @@ def test_schema_rejects_bad_lines():
     bad2 = _good_train_line()
     bad2["ema_drift/backbone"] = "high"
     assert any("ema_drift/backbone" in e for e in schema.validate_line(bad2))
+
+
+def test_schema_knows_the_phase_account_and_the_setup_line():
+    line = _good_train_line()
+    line.update({"phase/data_wait": 0.004, "phase/log_flush_host": 0.0007, "phase/steps": 10,
+                 "t_dispatch": 0.05, "t_device": 0.1, "t_probe_step": 51})
+    assert schema.validate_line(line) == []
+    setup = {"step": 1, "time": 1.0, "epoch": 0, "event": "setup", "setup/backend_s": 0.01,
+             "setup/state_init_s": 14.0, "setup/checkpoint_s": 0.2,
+             "setup/pipeline_start_s": 8.0, "setup/first_step_s": 12.5}
+    assert schema.validate_line(setup) == []
+    for key, bad in (("phase/throttle_wait", "long"), ("phase/steps", None),
+                     ("t_probe_step", 2.5), ("setup/first_step_s", "12 s")):
+        broken = dict(line if not key.startswith("setup/") else setup, **{key: bad})
+        assert any(key in e for e in schema.validate_line(broken)), key
 
 
 def test_schema_rejects_nonfinite_literals():

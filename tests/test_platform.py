@@ -157,19 +157,6 @@ def test_chip_smoke_knows_where_the_cache_is(monkeypatch):
     assert mod.cache_dir() == "/some/dir"
 
 
-def test_analyze_trace_never_assumes_a_chip():
-    """A roofline needs the traced chip's own peaks: both numbers, or a
-    device_kind the script knows."""
-    from conftest import load_script
-
-    mod = load_script("analyze_trace.py")
-    assert mod.resolve_peaks("TPU v5 lite", None, None) == (197.0, 819.0)
-    assert mod.resolve_peaks(None, 100.0, 200.0) == (100.0, 200.0)
-    for kind, peak, hbm in ((None, None, None), ("TPU v9", None, None), (None, 197.0, None)):
-        with pytest.raises(SystemExit):
-            mod.resolve_peaks(kind, peak, hbm)
-
-
 def test_bn_compile_repro_grid_order():
     """The bisect harness must order each depth's cells baseline-first,
     shipped-slice-suspects last (a run cut short forfeits the least
