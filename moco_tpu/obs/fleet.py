@@ -10,7 +10,8 @@ view at log-step cadence:
   per-host stats vector (`FLEET_FIELDS`: data wait, step wall, wire
   transfer time, dispatch lag, io retries, decode failures, live HBM);
   a jitted `all_gather` +
-  reduction over a one-device-per-host mesh returns per-field
+  reduction over a one-device-per-host mesh (on one process: the same
+  reduction on the host, no device program) returns per-field
   min/mean/max/argmax plus a `straggler_skew` gauge — `(max(t_step) -
   mean(t_step)) / mean(t_step)`, the fraction of every step the fleet
   spends waiting for its slowest host. Process 0 merges the result into
@@ -42,12 +43,15 @@ import json
 import os
 import socket
 import time
+import warnings
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from moco_tpu.obs.trace import span
 
 FLEET_FIELDS = (
     "t_data",
@@ -64,25 +68,27 @@ FLEET_FIELDS = (
 )
 
 
-def reduce_stats(stats: jax.Array, t_step_index: int) -> dict:
+def reduce_stats(stats, t_step_index: int, xp=jnp) -> dict:
     """Pure per-field reduction over an (n_hosts, n_fields) stats matrix.
 
     NaN-aware: a host that can't report a field contributes NaN, and a
     field nobody reports reduces to NaN (-> null in the line). Returns
     {'min','mean','max' (F,), 'argmax' (F,) int32, 'straggler_skew' ()}.
     Jit-compatible; shared by the live aggregator and the skew tests.
+    `xp` is the array namespace: `jnp` for the jitted collective,
+    `numpy` where one process reduces its own row on the host.
     """
-    s = stats.astype(jnp.float32)
-    mins = jnp.nanmin(s, axis=0)
-    means = jnp.nanmean(s, axis=0)
-    maxs = jnp.nanmax(s, axis=0)
+    s = stats.astype(xp.float32)
+    mins = xp.nanmin(s, axis=0)
+    means = xp.nanmean(s, axis=0)
+    maxs = xp.nanmax(s, axis=0)
     # argmax over NaN-padded columns: NaN -> -inf so a reporting host
     # always wins; an all-NaN column degrades to host 0 (meaningless
     # alongside a null max, which readers key on).
-    argmax = jnp.argmax(jnp.where(jnp.isnan(s), -jnp.inf, s), axis=0).astype(jnp.int32)
+    argmax = xp.argmax(xp.where(xp.isnan(s), -xp.inf, s), axis=0).astype(xp.int32)
     t = s[:, t_step_index]
-    t_mean = jnp.nanmean(t)
-    skew = (jnp.nanmax(t) - t_mean) / jnp.maximum(t_mean, 1e-12)
+    t_mean = xp.nanmean(t)
+    skew = (xp.nanmax(t) - t_mean) / xp.maximum(t_mean, 1e-12)
     return {
         "min": mins,
         "mean": means,
@@ -98,9 +104,10 @@ class FleetAggregator:
     Builds a 1-D `hosts` mesh with ONE representative device per
     process; each process's vector becomes its row of a (n_hosts, F)
     array sharded over that mesh, and the jitted reduce (replicated
-    output) is the per-step all_gather. On a single process this
-    degenerates to a trivial one-row reduce — the same code path runs
-    everywhere, so every CI test exercises it.
+    output) is the per-step all_gather, under a `fleet_gather` span. A
+    single process has one row and no one to gather from: `gather`
+    reduces it on the host with the same `reduce_stats` and dispatches
+    nothing to the device.
     """
 
     def __init__(self, fields: Sequence[str] = FLEET_FIELDS):
@@ -135,11 +142,27 @@ class FleetAggregator:
         return out
 
     def gather(self, host_vector: np.ndarray) -> dict:
-        """The per-step collective: contribute this host's vector, get
+        """The per-step reduction: contribute this host's vector, get
         the fleet reduction back (host numpy values, replicated — every
         process sees the same result). ALL processes must call this at
-        the same step."""
+        the same step.
+
+        With one process there is nothing to gather and the reduction
+        runs on the host: the caller is the driver's log flush, where a
+        device program would queue behind the step in flight and reading
+        it back would drain the device's queue."""
         row = np.asarray(host_vector, np.float32).reshape(1, len(self.fields))
+        if self.num_hosts == 1:
+            with warnings.catch_warnings():
+                # a field nobody reports is an all-NaN column: NaN, as jnp gives
+                warnings.simplefilter("ignore", RuntimeWarning)
+                return reduce_stats(row, self._t_idx, xp=np)
+        with span("fleet_gather"):
+            return self._collective(row)
+
+    def _collective(self, row: np.ndarray) -> dict:
+        """The cross-process path: this host's row onto its representative
+        device, the jitted all_gather + reduce, the result back."""
         local = jax.device_put(row, self.rep_devices[self.process_index])
         stats = jax.make_array_from_single_device_arrays(
             (self.num_hosts, len(self.fields)), self._row_sharding, [local]

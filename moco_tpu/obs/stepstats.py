@@ -109,11 +109,13 @@ class StepTimeProbe:
 
 # The host phases a log line accounts for, by span name: the driver
 # thread's (always on the line) and the prefetch ring thread's (on the
-# line once the ring has run). `log_flush` contains `metrics_fetch` and
-# `lr_fetch`, its two waits for the device; `device_wait` is the probe's
+# line once the ring has run). `log_flush` contains its waits for the
+# device: `metrics_fetch`, the read of a step dispatched before the newest
+# one, and `fleet_gather`, the cross-process collective (zero on one
+# process, which reduces on the host). `device_wait` is the probe's
 # drain, on one step in `obs_probe_every`.
 DRIVER_PHASES = (
-    "data_wait", "step", "device_wait", "throttle_wait", "log_flush", "metrics_fetch", "lr_fetch",
+    "data_wait", "step", "device_wait", "throttle_wait", "log_flush", "metrics_fetch", "fleet_gather",
 )
 RING_PHASES = ("transfer", "augment_dispatch", "ring_blocked")
 SETUP_PARTS = ("backend", "state_init", "checkpoint", "pipeline_start", "first_step")
@@ -129,7 +131,8 @@ def phase_account(now: dict, before: dict, steps: int) -> dict:
     apart. Every step counts, where the probe samples one in
     `obs_probe_every`. `phase/log_flush_host` is the flush less the fetch
     of the step's metrics: the log path's host work, and whatever else
-    in it waits for the device (`phase/lr_fetch`)."""
+    in it waits for the device (`phase/fleet_gather` with more than one
+    process; nothing with one)."""
     steps = max(int(steps), 1)
     names = DRIVER_PHASES + tuple(n for n in RING_PHASES if n in now)
     out = {f"phase/{n}": _seconds_between(now, before, n) / steps for n in names}

@@ -268,7 +268,8 @@ def _train_impl(
     encoder = build_encoder(config.moco, num_data=num_data)
     predictor = build_predictor(config.moco, num_data=num_data)
     tx = build_optimizer(config.optim, steps_per_epoch=steps_per_epoch)
-    lr_schedule = make_lr_schedule(config.optim, steps_per_epoch)
+    # the optimizer's schedule evaluated on the host, for the log line
+    lr_schedule = make_lr_schedule(config.optim, steps_per_epoch, xp=np)
 
     rng = jax.random.PRNGKey(config.seed)
     init_rng, shuffle_rng = jax.random.split(rng)
@@ -822,7 +823,15 @@ def _train_impl(
                     per-field float() forced a blocking transfer per
                     metric), then every runtime guard piggybacks on the
                     fetch — NaN guard, chaos hooks, alert engine,
-                    recompile guard, fleet gather, heartbeat."""
+                    recompile guard, fleet gather, heartbeat.
+
+                    The rule: in here the host waits only for device
+                    work dispatched BEFORE the newest step — that fetch,
+                    with the next step already queued behind it. Nothing
+                    here dispatches a device program and then reads it:
+                    the read would wait for the steps in flight and hand
+                    the next dispatch an empty queue (the learning rate
+                    and the one-process fleet reduce are host numpy)."""
                     nonlocal state
                     i, gstep = p["i"], p["gstep"]
                     # taken before this flush does anything: the account
@@ -910,14 +919,9 @@ def _train_impl(
                     probe.step_done(t_step)
                     progress.display(i)
                     wire = ring_stats() if ring_stats is not None else {}
-                    # the schedule is jnp: a handful of tiny device programs
-                    # that queue behind the steps in flight, and `float`
-                    # waits for them
-                    with obs.span("lr_fetch", step=gstep):
-                        lr_now = float(lr_schedule(gstep - 1))
                     payload = {
                         "epoch": epoch,
-                        "lr": lr_now,
+                        "lr": float(lr_schedule(gstep - 1)),
                         **m,
                         # step-time breakdown + device memory
                         # (obs): t_data/t_step always; dispatch/

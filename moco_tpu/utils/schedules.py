@@ -26,23 +26,29 @@ import optax
 from moco_tpu.utils.config import OptimConfig
 
 
-def make_lr_schedule(cfg: OptimConfig, steps_per_epoch: int) -> Callable:
+def make_lr_schedule(cfg: OptimConfig, steps_per_epoch: int, xp=jnp) -> Callable:
     """Per-epoch-granular schedule over the global step, matching
-    `adjust_learning_rate` exactly (with optional linear warmup)."""
+    `adjust_learning_rate` exactly (with optional linear warmup).
+
+    `xp` is the array namespace the one formula is evaluated in: `jnp`
+    inside the optimizer (traced into the step program), `numpy` for the
+    driver's log line, where a `jnp` evaluation would be a handful of
+    device programs queued behind the step in flight. The epoch and the
+    cosine stay float32 in both, so the two agree to float32 rounding."""
     total_epochs = cfg.epochs
 
     def schedule(step):
-        epoch = jnp.floor_divide(step, steps_per_epoch).astype(jnp.float32)
+        epoch = xp.floor_divide(step, steps_per_epoch).astype(xp.float32)
         if cfg.cos:
-            factor = 0.5 * (1.0 + jnp.cos(math.pi * epoch / total_epochs))
+            factor = 0.5 * (1.0 + xp.cos(math.pi * epoch / total_epochs))
         else:
-            milestones = jnp.asarray(cfg.schedule, jnp.float32)
-            factor = 0.1 ** jnp.sum(epoch[None] >= milestones)
+            milestones = xp.asarray(cfg.schedule, xp.float32)
+            factor = 0.1 ** xp.sum(epoch[None] >= milestones)
         lr = cfg.lr * factor
         if cfg.warmup_epochs > 0:
             warm_steps = cfg.warmup_epochs * steps_per_epoch
             warm = cfg.lr * (step + 1) / warm_steps
-            lr = jnp.where(step < warm_steps, warm, lr)
+            lr = xp.where(step < warm_steps, warm, lr)
         return lr
 
     return schedule

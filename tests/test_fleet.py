@@ -24,6 +24,7 @@ from jax.sharding import PartitionSpec as P
 from moco_tpu.obs import alerts as alerts_mod
 from moco_tpu.obs import comms, schema, sinks
 from moco_tpu.obs.alerts import AlertEngine, parse_rules
+from moco_tpu.obs.trace import Tracer, set_tracer
 from moco_tpu.obs.fleet import (
     FLEET_FIELDS,
     FleetAggregator,
@@ -38,10 +39,25 @@ from jax import shard_map
 # -- fleet reduction (skew math on synthetic multi-host matrices) --------
 
 
-def test_reduce_stats_min_mean_max_argmax():
+# `reduce_stats` has two evaluations of one formula: jitted `jnp` (the
+# cross-process collective) and numpy (one process reduces its own row
+# on the host, PR 27). Every case runs under both.
+def _reduce(backend, m, t_idx):
+    m = np.asarray(m, np.float32)
+    if backend == "numpy":
+        with np.testing.suppress_warnings() as sup:
+            sup.filter(RuntimeWarning)  # all-NaN columns
+            return reduce_stats(m, t_idx, xp=np)
+    return jax.jit(lambda s: reduce_stats(s, t_idx))(jnp.asarray(m))
+
+
+BACKENDS = pytest.mark.parametrize("backend", ["jit", "numpy"])
+
+
+@BACKENDS
+def test_reduce_stats_min_mean_max_argmax(backend):
     # 3 hosts x 2 fields; t_step is column 1
-    m = jnp.asarray([[1.0, 2.0], [3.0, 4.0], [2.0, 6.0]], jnp.float32)
-    out = jax.jit(lambda s: reduce_stats(s, 1))(m)
+    out = _reduce(backend, [[1.0, 2.0], [3.0, 4.0], [2.0, 6.0]], 1)
     np.testing.assert_allclose(np.asarray(out["min"]), [1.0, 2.0])
     np.testing.assert_allclose(np.asarray(out["mean"]), [2.0, 4.0])
     np.testing.assert_allclose(np.asarray(out["max"]), [3.0, 6.0])
@@ -50,33 +66,57 @@ def test_reduce_stats_min_mean_max_argmax():
     np.testing.assert_allclose(float(out["straggler_skew"]), 0.5, rtol=1e-6)
 
 
-def test_reduce_stats_uniform_fleet_has_zero_skew():
-    m = jnp.full((4, 3), 2.5, jnp.float32)
-    out = reduce_stats(m, 0)
+@BACKENDS
+def test_reduce_stats_uniform_fleet_has_zero_skew(backend):
+    out = _reduce(backend, np.full((4, 3), 2.5), 0)
     np.testing.assert_allclose(float(out["straggler_skew"]), 0.0, atol=1e-6)
 
 
-def test_reduce_stats_nan_aware():
+@BACKENDS
+def test_reduce_stats_nan_aware(backend):
     """A host that can't report a field (NaN) must not poison the fleet
     stats; a field NO host reports stays NaN (-> null in the line)."""
-    m = jnp.asarray(
-        [[1.0, np.nan, np.nan], [np.nan, 4.0, np.nan]], jnp.float32
-    )
-    out = reduce_stats(m, 0)
+    out = _reduce(backend, [[1.0, np.nan, np.nan], [np.nan, 4.0, np.nan]], 0)
     assert float(out["min"][0]) == 1.0 and float(out["max"][1]) == 4.0
     assert np.isnan(float(out["mean"][2]))  # nobody reported column 2
     # skew over a column with one reporter: max == mean -> 0
     np.testing.assert_allclose(float(out["straggler_skew"]), 0.0, atol=1e-6)
 
 
-def test_fleet_aggregator_roundtrip_and_payload():
-    f = FleetAggregator()
-    assert f.num_hosts == 1  # single process, however many devices
-    vec = f.host_vector(
+@pytest.mark.parametrize(
+    "matrix,t_idx",
+    [
+        # a straggler, a half-reported column, an all-NaN column
+        ([[0.1, 0.5, np.nan, 3.0], [0.4, 0.9, np.nan, np.nan], [0.2, 0.6, np.nan, 1.0]], 1),
+        # the driver's own case: one row, hbm unknown
+        ([[0.004, 0.177, 0.02, 0.05, 0.0, 0.0, np.nan]], 1),
+        # t_step itself unknown on one host
+        ([[1.0, np.nan], [2.0, 0.25], [0.5, 0.75]], 1),
+    ],
+    ids=["straggler_and_nan_columns", "one_row", "nan_in_t_step"],
+)
+def test_reduce_stats_numpy_equals_jitted(matrix, t_idx):
+    """Same dtypes, same values, NaNs in the same places: the line's
+    fields are what they were when the reduce ran on the device."""
+    host, dev = _reduce("numpy", matrix, t_idx), jax.device_get(_reduce("jit", matrix, t_idx))
+    assert set(host) == set(dev)
+    for k in dev:
+        assert np.asarray(host[k]).dtype == dev[k].dtype, k
+        assert np.asarray(host[k]).shape == dev[k].shape, k
+        np.testing.assert_allclose(np.asarray(host[k]), dev[k], rtol=1e-6, err_msg=k)
+
+
+def _fleet_vector(f):
+    return f.host_vector(
         t_data=0.1, t_step=0.5, dispatch_lag=0.02,
         io_retries=3, decode_failures=0, hbm_live=None,
     )
-    stats = f.gather(vec)
+
+
+def test_fleet_aggregator_roundtrip_and_payload():
+    f = FleetAggregator()
+    assert f.num_hosts == 1  # single process, however many devices
+    stats = f.gather(_fleet_vector(f))
     pay = f.payload(stats)
     assert pay["fleet_hosts"] == 1
     assert pay["straggler_skew"] == pytest.approx(0.0)
@@ -88,6 +128,56 @@ def test_fleet_aggregator_roundtrip_and_payload():
     assert np.isnan(pay["fleet/hbm_live_max"])
     rec = sinks.sanitize(pay)
     assert rec["fleet/hbm_live_max"] is None
+
+
+@pytest.fixture
+def tracer():
+    """A tracer installed process-wide for the test, as `train()` does."""
+    t = Tracer()
+    prev = set_tracer(t)
+    try:
+        yield t
+    finally:
+        set_tracer(prev)
+
+
+def test_one_process_gather_runs_no_device_program(monkeypatch, recwarn, tracer):
+    """One process has nothing to gather: the payload is what the
+    collective path gives for the one row, and getting it moves nothing
+    to the device and runs nothing there (the caller is the driver's log
+    flush, where a read of a fresh device program drains the queue)."""
+    f = FleetAggregator()
+    vec = _fleet_vector(f)
+    before = f.payload(f._collective(vec.reshape(1, -1)))  # the path every gather took
+
+    def refuse(*a, **k):
+        raise AssertionError("a one-process gather touched the device")
+
+    monkeypatch.setattr(f, "_reduce", refuse)
+    monkeypatch.setattr(f, "_collective", refuse)
+    with jax.transfer_guard("disallow_explicit"):  # a `device_put` too
+        stats = f.gather(vec)
+    assert not any(isinstance(v, jax.Array) for v in stats.values())
+    assert "fleet_gather" not in tracer.totals()  # the span is the collective's
+    after = sinks.sanitize(f.payload(stats))
+    assert after == sinks.sanitize(before)
+    assert not [w for w in recwarn.list if issubclass(w.category, RuntimeWarning)]
+
+
+def test_collective_gather_runs_under_its_own_span(monkeypatch, tracer):
+    """With more than one process the collective stays, as a child span
+    of the flush, so a multi-host line shows the wait that is left."""
+    f = FleetAggregator()
+    vec = _fleet_vector(f)
+    monkeypatch.setattr(f, "num_hosts", 2)  # take the multi-process branch
+    called = []
+    monkeypatch.setattr(f, "_collective", lambda row: called.append(row) or {"ok": True})
+    with tracer.span("log_flush"):
+        assert f.gather(vec) == {"ok": True}
+    assert len(called) == 1 and called[0].shape == (1, len(FLEET_FIELDS))
+    assert tracer.totals()["fleet_gather"][0] == 1
+    by_name = {s["name"]: s for s in tracer.snapshot()}
+    assert by_name["fleet_gather"]["depth"] == by_name["log_flush"]["depth"] + 1
 
 
 def test_host_vector_rejects_unknown_field():

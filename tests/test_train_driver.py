@@ -238,3 +238,86 @@ def test_cli_preset_with_override(tmp_path):
     cfg = cli.config_from_args(args)
     assert cfg.moco.arch == "resnet18" and cfg.moco.cifar_stem
     assert cfg.optim.epochs == 1  # override wins over preset
+
+
+# -- the log flush's rule (PR 27) -----------------------------------------
+
+
+def test_log_flush_reads_only_the_step_metrics_and_dispatches_nothing(tmp_path, monkeypatch):
+    """Inside `flush_log` the host waits only for device work dispatched
+    before the newest step: the one `device_get` of the logged step's
+    metrics. Anything else the flush dispatched and read would wait for
+    the steps in flight and leave the device's queue empty (PR 26 measured
+    it: `float(lr_schedule(...))` and, behind it, the one-process fleet
+    reduce). Held here by refusing EVERY host-to-device transfer while a
+    `log_flush` span is open on the driver thread (a `jnp` op on a Python
+    number and a `device_put` both need one; on the CPU backend the
+    device-to-host direction cannot be guarded), and by counting the
+    explicit reads. One device, a CIFAR-stem ResNet-18 at 16 px, 7 steps."""
+    import threading
+
+    import jax
+
+    from moco_tpu.obs.fleet import FLEET_FIELDS
+    from moco_tpu.train import train
+    from moco_tpu.utils.schedules import make_lr_schedule
+
+    local = threading.local()  # the ring thread transfers batches all the while
+    flushes = []  # one entry a flush: the trees it read back
+
+    class FlushGuard:
+        """Stands in for `jax.profiler.TraceAnnotation`, which `train()`
+        installs as the annotator of every `obs.span`."""
+
+        def __init__(self, name, **kwargs):
+            self.flush = name == "moco/log_flush"
+
+        def __enter__(self):
+            if self.flush:
+                local.reads = []
+                self.guard = jax.transfer_guard_host_to_device("disallow_explicit")
+                self.guard.__enter__()
+            return self
+
+        def __exit__(self, *exc):
+            if self.flush:
+                self.guard.__exit__(*exc)
+                flushes.append(local.reads)
+                local.reads = None
+            return False
+
+    device_get = jax.device_get
+
+    def counting_device_get(tree):
+        if getattr(local, "reads", None) is not None:
+            local.reads.append(tree)
+        return device_get(tree)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", FlushGuard)
+    monkeypatch.setattr(jax, "device_get", counting_device_get)
+
+    steps = 7
+    config = dataclasses.replace(
+        _tiny_config(tmp_path, epochs=1, shuffle="none"),
+        parallel=ParallelConfig(num_data=1), knn_every_epochs=0,
+    )
+    assert config.fleet_metrics and config.log_every == 2
+    train(config, dataset=SyntheticDataset(num_examples=16 * steps, image_size=16))
+
+    lines = [json.loads(l) for l in open(os.path.join(config.workdir, "metrics.jsonl"))]
+    logged = [l for l in lines if "loss" in l]
+    assert [l["step"] for l in logged] == [1, 3, 5, 7]
+    # one read a flush, and it is the logged step's metrics tree
+    assert len(flushes) == len(logged)
+    for reads in flushes:
+        assert len(reads) == 1 and "loss" in reads[0], [sorted(r) for r in reads]
+    on_device = make_lr_schedule(config.optim, steps)
+    for l in logged:
+        assert l["lr"] == pytest.approx(float(on_device(l["step"] - 1)), rel=1e-6)
+        assert l["fleet_hosts"] == 1 and l["straggler_skew"] == pytest.approx(0.0)
+        for name in FLEET_FIELDS:
+            assert {f"fleet/{name}_{r}" for r in ("min", "mean", "max", "argmax")} <= set(l)
+        assert l["fleet/t_step_mean"] == pytest.approx(l["t_step"], rel=1e-6)
+        assert l["phase/log_flush_host"] >= 0.0
+        assert l["phase/fleet_gather"] == 0.0  # one process: no collective ran
+        assert "phase/lr_fetch" not in l
