@@ -4,9 +4,14 @@ Everything that belongs to one configuration, one traffic mix or one
 per-layer metric lives in a file of its own, found by the name in the
 manifest: `configs/<config>.json`, `traffic/<traffic>.json`,
 `layer_metrics/<metric>.json`, and a reader `readers/<reader>.py` named
-by the layer-metric file. A later PR adds files and manifest entries and
-edits none. `root` is injectable so the tests can prove that with a
-throw-away cell in a temporary directory.
+by the layer-metric file. What belongs to one encoder family, one kind
+of input or one kernel is a module found by name in the same way:
+`reference/<name>.py` (named by the configuration file), the
+`inputs/<name>.py` the reference names as its `INPUT`, and the
+`required/<name>.py` a kernel metric's file names. The harness's own
+code names no family, no modality and no kernel. A later PR adds files
+and manifest entries and edits none. `root` is injectable so the tests
+can prove that with a throw-away family in a temporary directory.
 """
 
 from __future__ import annotations
@@ -27,6 +32,21 @@ class ManifestError(ValueError):
     pass
 
 
+def load_module(bench_dir: str, kind: str, name: str):
+    """The module `<bench_dir>/<kind>/<name>.py` (`kind`: readers,
+    reference, inputs or required), loaded by path so that one added in a
+    temporary directory is found like one in the repo."""
+    if not NAME_RE.match(name):
+        raise ManifestError(f"bad module name {kind}/{name!r}")
+    path = os.path.join(bench_dir, kind, f"{name}.py")
+    if not os.path.exists(path):
+        raise ManifestError(f"missing benchmark module {path}")
+    spec = importlib.util.spec_from_file_location(f"_bench_{kind}_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def _load_json(path: str) -> dict:
     try:
         with open(path) as f:
@@ -39,7 +59,7 @@ class Manifest:
     """The parsed manifest plus look-ups by name. `repo_root` holds
     BENCHMARK.json (or `manifest_path` names a manifest of candidate
     cells); its first `paths` entry holds configs/, traffic/,
-    layer_metrics/ and readers/."""
+    layer_metrics/, readers/, reference/, inputs/ and required/."""
 
     def __init__(self, repo_root: str = REPO_ROOT, manifest_path: str | None = None):
         self.repo_root = repo_root
@@ -75,17 +95,14 @@ class Manifest:
         ]
 
     def reader(self, name: str):
-        """The module `readers/<name>.py`, loaded by path so a reader
-        added in a temporary directory is found like one in the repo."""
-        if not NAME_RE.match(name):
-            raise ManifestError(f"bad reader name {name!r}")
-        path = os.path.join(self.bench_dir, "readers", f"{name}.py")
-        if not os.path.exists(path):
-            raise ManifestError(f"missing reader {path}")
-        spec = importlib.util.spec_from_file_location(f"_bench_reader_{name}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
+        return load_module(self.bench_dir, "readers", name)
+
+    def family(self, cfg_file: dict) -> tuple:
+        """(reference module, input module) of a configuration: the plain
+        reference its file names, and the input module that reference
+        names as its `INPUT`."""
+        ref = load_module(self.bench_dir, "reference", cfg_file["reference"])
+        return ref, load_module(self.bench_dir, "inputs", ref.INPUT)
 
 
 def read_layer_metrics(manifest: Manifest, cell: str, ctx: dict) -> dict:

@@ -38,12 +38,13 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def make_checkpoint(config, seed: int, ckpt_dir: str):
-    """Weights from the seed, saved as the train driver saves them."""
+def make_checkpoint(config, seed: int, ckpt_dir: str, inputs):
+    """Weights from the seed, saved as the train driver saves them.
+    `inputs`: the family's input module (the sample row's shape)."""
     from moco_tpu.utils.checkpoint import CheckpointManager
     from moco_tpu.utils.config import config_to_dict
 
-    state, _, _ = correct.seeded_state(config, seed)
+    state, _, _ = correct.seeded_state(config, seed, inputs)
     shutil.rmtree(ckpt_dir, ignore_errors=True)
     mgr = CheckpointManager(ckpt_dir, keep=1)
     mgr.save(0, state, extra={"epoch": 0, "config": config_to_dict(config), "num_data": 1},
@@ -93,6 +94,7 @@ def run(manifest, cell: dict, args, t_start: float) -> dict:
 
     rehearse = args.rehearse
     cfg_file = manifest.config_file(cell["config"])
+    ref, inputs = manifest.family(cfg_file)
     traffic_file = manifest.traffic_file(cell["traffic"])
     traffic = common.merged(traffic_file, rehearse)
     serve_cfg = common.merged(cfg_file["serve"], rehearse)
@@ -102,7 +104,7 @@ def run(manifest, cell: dict, args, t_start: float) -> dict:
     os.makedirs(workdir)
     ckpt_dir = os.path.join(common.OUT_DIR, "cache", f"ckpt-{cell['name']}")
     config = common.build_train_config(cfg_file, traffic_file, args.seed, ckpt_dir, rehearse)
-    state = make_checkpoint(config, args.seed, ckpt_dir)
+    state = make_checkpoint(config, args.seed, ckpt_dir, inputs)
     log("checkpoint made from the seed")
 
     port = _free_port()
@@ -169,21 +171,24 @@ def run(manifest, cell: dict, args, t_start: float) -> dict:
     ]
     summary = summarise(win, serve_cfg["slo_ms"], traffic["client_timeout_s"])
     check = correct.check_serve(
-        state, config, cfg_file["reference"], args.seed, gen["sample"], serve_cfg["neighbors_k"]
+        state, config, ref, inputs, args.seed, gen["sample"], serve_cfg["neighbors_k"]
     )
     log(f"correct: {check}")
     log(f"window: {summary}")
     recompiles = serve_lines[-1].get("serve/recompiles_after_warmup") if serve_lines else None
-    ok = bool(
-        check["ok"] and recompiles == 0 and summary["failed"] == 0
-        and summary["late_p95_ms"] <= float(traffic["late_p95_cap_ms"])
-    )
+    compared = {
+        **correct.compared(check, ref),
+        "serve_recompiles": {"value": recompiles, "at_most": 0},
+        "failed_requests": {"value": summary["failed"], "at_most": 0},
+        "gen_late_p95_ms": {"value": summary["late_p95_ms"], "at_most": float(traffic["late_p95_cap_ms"])},
+    }
     result = {
-        "correct": ok,
+        "correct": bool(check["ok"]) and all(correct.holds(c) for c in compared.values()),
         "attempted": summary["attempted"],
         "failed": summary["failed"],
         "metrics": {},
         "device": {**device, "memory_peak_bytes": peak},
+        "compared": compared,
     }
     detail = {
         "cell": cell["name"], "seed": args.seed, "trace": args.trace, "summary": summary,

@@ -90,13 +90,13 @@ def _parse(line: str):
 def run(manifest, cell: dict, args, t_start: float) -> dict:
     import jax
 
-    from benchmarks.data.pool import PoolDataset
     from benchmarks.trace_reduce import cut_fixture, load_events, ops_inside, reduce_trace
     from moco_tpu.train import train
     from moco_tpu.utils.config import config_to_dict
 
     rehearse = args.rehearse
     cfg_file = manifest.config_file(cell["config"])
+    ref, inputs = manifest.family(cfg_file)
     traffic_file = manifest.traffic_file(cell["traffic"])
     traffic = common.merged(traffic_file, rehearse)
     device = common.require_devices(cell["chips"], rehearse)
@@ -109,10 +109,8 @@ def run(manifest, cell: dict, args, t_start: float) -> dict:
     if not rehearse and chips != cell["chips"]:
         raise SystemExit(f"cell asks {cell['chips']} chips, configuration resolves {chips}")
     batch = config.data.global_batch
-    pool = PoolDataset(
-        args.seed, pool_size=traffic["pool_images"], image_size=config.data.image_size
-    )
-    log(f"pool of {traffic['pool_images']} images ready")
+    dataset = inputs.dataset(args.seed, traffic, config)
+    log(f"dataset ready ({ref.INPUT})")
 
     warmup = int(traffic["warmup_steps"])
     profile_dir = os.path.join(workdir, "profile")
@@ -124,7 +122,7 @@ def run(manifest, cell: dict, args, t_start: float) -> dict:
     )
     watcher.start()
     try:
-        train(config, dataset=pool)
+        train(config, dataset=dataset)
     finally:
         watcher.done.set()
         watcher.join(timeout=5.0)
@@ -155,20 +153,23 @@ def run(manifest, cell: dict, args, t_start: float) -> dict:
 
     check = correct.check_train(
         dataclasses.replace(config, parallel=dataclasses.replace(config.parallel, num_data=1)),
-        cfg_file["reference"], args.seed,
+        ref, inputs, args.seed,
         sample_n=int(traffic["correct_sample"]), gradient=bool(traffic.get("correct_gradient")),
     )
     log(f"correct: {check}")
-    ok = bool(
-        check["ok"] and finite and not nonfinite and compiled_in_window == 0
-        and len(lines) >= 2
-    )
+    compared = {
+        **correct.compared(check, ref),
+        "nonfinite_losses": {"value": len(nonfinite) + (0 if finite else 1), "at_most": 0},
+        "compiled_in_window": {"value": compiled_in_window, "at_most": 0},
+        "window_log_lines": {"value": len(lines), "at_least": 2},
+    }
     result = {
-        "correct": ok,
+        "correct": all(correct.holds(c) for c in compared.values()),
         "attempted": int(steps),
         "failed": len(nonfinite),
         "metrics": {},
         "device": {**device, "memory_peak_bytes": peak},
+        "compared": compared,
     }
     detail = {
         "cell": cell["name"], "seed": args.seed, "trace": args.trace,
@@ -200,7 +201,7 @@ def run(manifest, cell: dict, args, t_start: float) -> dict:
             "peaks": peaks_for(device["kind"]),
             "chips": chips,
             "train_config": config_to_dict(config),
-            "step_flops": _step_flops(config),
+            "step_flops": _step_flops(config, ref, inputs),
         }
         common.add_traced(result, detail, manifest, cell["name"], ctx, loaded)
         if args.dump_trace_events:  # whole steps, compressed: the stuff of a test fixture
@@ -212,19 +213,20 @@ def run(manifest, cell: dict, args, t_start: float) -> dict:
     return result
 
 
-def _step_flops(config) -> float:
+def _step_flops(config, ref, inputs) -> float:
     """Operations one step needs, from the parameter shapes alone
-    (`jax.eval_shape`: nothing is allocated)."""
+    (`jax.eval_shape`: nothing is allocated but one sample row). What a
+    row costs forward is the family's count (`ref.forward_flops`); what
+    a step makes of it is momentum contrast's (`flops.train_step_flops`)."""
     import jax
     import jax.numpy as jnp
 
     from moco_tpu.core import build_encoder, build_predictor
 
-    size = config.data.image_size
     enc, pred = build_encoder(config.moco), build_predictor(config.moco)
+    sample = inputs.sample_input(config)
     shapes = jax.eval_shape(
-        lambda r: enc.init(r, jnp.zeros((1, size, size, 3), jnp.float32), train=False),
-        jax.random.PRNGKey(0),
+        lambda r: enc.init(r, sample, train=False), jax.random.PRNGKey(0)
     )["params"]
     pred_shapes = {}
     if pred is not None:
@@ -233,6 +235,6 @@ def _step_flops(config) -> float:
             jax.random.PRNGKey(0),
         )["params"]
     return flops.train_step_flops(
-        shapes, pred_shapes, size, config.data.global_batch,
+        ref.forward_flops(shapes, config), pred_shapes, config.data.global_batch,
         v3=config.moco.v3, dim=config.moco.dim, num_negatives=config.moco.num_negatives,
     )

@@ -4,11 +4,51 @@ runs in reduced precision), no kernels, no batching tricks."""
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 HI = lax.Precision.HIGHEST
+
+# The control of `correct` (harness/correct.py::check_train, `control=`):
+# the reference put in the program's place, with both operands of every
+# matmul and convolution rounded to a lower precision (per-tensor scaled,
+# as a deployment would scale them) and the arithmetic left in float32.
+# None everywhere else: the reference proper rounds nothing.
+_OPERAND_DTYPE = None
+
+
+@contextlib.contextmanager
+def operands_rounded_to(dtype):
+    """Trace (and so call a fresh `jax.jit` of) the reference inside this
+    to get the control; the setting is read while tracing."""
+    global _OPERAND_DTYPE
+    before, _OPERAND_DTYPE = _OPERAND_DTYPE, dtype
+    try:
+        yield
+    finally:
+        _OPERAND_DTYPE = before
+
+
+def operand(x):
+    """A matmul's or convolution's operand as float32: as it is, or, in
+    the control, rounded to the lower precision. An 8-bit type gets a
+    per-tensor scale that puts the largest magnitude at the type's top;
+    a 16-bit float is wide enough to take the values as they are."""
+    x = jnp.asarray(x, jnp.float32)
+    dtype = _OPERAND_DTYPE
+    if dtype is None:
+        return x
+    if jnp.dtype(dtype).itemsize > 1:
+        return x.astype(dtype).astype(jnp.float32)
+    integer = jnp.issubdtype(dtype, jnp.integer)
+    top = float(jnp.iinfo(dtype).max if integer else jnp.finfo(dtype).max)
+    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / top
+    y = jnp.round(x / scale) if integer else x / scale
+    return y.astype(dtype).astype(jnp.float32) * scale
+
 
 IMAGENET_MEAN = (0.485, 0.456, 0.406)
 IMAGENET_STD = (0.229, 0.224, 0.225)
@@ -30,7 +70,7 @@ def preprocess(images_uint8) -> jax.Array:
 
 
 def dense(x, p):
-    y = jnp.matmul(x, jnp.asarray(p["kernel"], jnp.float32), precision=HI)
+    y = jnp.matmul(operand(x), operand(p["kernel"]), precision=HI)
     return y + p["bias"] if "bias" in p else y
 
 

@@ -15,16 +15,51 @@ that the reference has to share to be comparable:
 
 It reads the program's parameter tree by its flax names and shares no
 code with it.
+
+The family's file: beside the forward (`loss_and_embeddings`, `embed`) it
+states what the harness needs to know of the family and finds here by
+name: `INPUT`, `TOLERANCES`, `forward_flops`.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.reference.common import HI, batch_norm, cross_entropy, dense, l2_normalize
+from benchmarks.harness.flops import dense_flops, shape
+from benchmarks.reference.common import (
+    HI, batch_norm, cross_entropy, dense, l2_normalize, operand,
+)
 
+# what the encoder reads: `benchmarks/inputs/images.py`
+INPUT = "images"
+
+# `correct`'s limits that are this family's own (the others are
+# `harness/correct.py`'s defaults, where each measure is explained).
+# emb_centred_rel: ||sys - ref||_F over ||ref - mean row of ref||_F of the
+# first view's normalised predictions, the bfloat16 system against this
+# float32 reference, ViT-B/16. 0.12 since PR 24. It lies between two
+# readings on the chip (PERF.md section 2): sound runs read 0.065-0.087
+# over 20 seeds (my chip runs, PR 28; 0.068-0.082 over 11 in PR 24), the
+# control (this reference with fp8 or int8 operands,
+# `benchmarks/control.py`) 0.212-0.409 over 6 seeds x 2 types.
+TOLERANCES = {"emb_centred_rel": 0.12}
+
+
+
+def trained_gradient(grads, moco_cfg):
+    """A gradient of the loss as the step trains on it: with
+    `freeze_patch_embed` (the v3 stability trick, arXiv:2104.02057
+    section 4.2) the step zeroes the patch projection's, so `correct`
+    compares the rest."""
+    if not moco_cfg.freeze_patch_embed:
+        return grads
+    backbone = grads["enc"]["backbone"]
+    frozen = jax.tree.map(jnp.zeros_like, backbone["patch_embed"])
+    return {**grads, "enc": {**grads["enc"], "backbone": {**backbone, "patch_embed": frozen}}}
 
 def _layer_norm(x, p, eps: float = 1e-6):
     mean = jnp.mean(x, axis=-1, keepdims=True)
@@ -51,26 +86,27 @@ def _sincos_2d(dim: int, grid: int, cls_token: bool) -> np.ndarray:
 
 def _attention(x, p):
     """Multi-head self-attention; kernels are (D, H, Dh) in, (H, Dh, D) out."""
-    proj = lambda n: jnp.einsum("bsd,dhe->bshe", x, jnp.asarray(p[n]["kernel"], jnp.float32),
+    x = operand(x)
+    proj = lambda n: jnp.einsum("bsd,dhe->bshe", x, operand(p[n]["kernel"]),
                                 precision=HI) + p[n]["bias"]
     q, k, v = proj("query"), proj("key"), proj("value")
-    scores = jnp.einsum("bshe,bthe->bhst", q, k, precision=HI) / np.sqrt(q.shape[-1])
+    scores = jnp.einsum("bshe,bthe->bhst", operand(q), operand(k), precision=HI) / np.sqrt(q.shape[-1])
     w = jax.nn.softmax(scores, axis=-1)
-    y = jnp.einsum("bhst,bthe->bshe", w, v, precision=HI)
-    return jnp.einsum("bshe,hed->bsd", y, jnp.asarray(p["out"]["kernel"], jnp.float32),
+    y = jnp.einsum("bhst,bthe->bshe", operand(w), operand(v), precision=HI)
+    return jnp.einsum("bshe,hed->bsd", operand(y), operand(p["out"]["kernel"]),
                       precision=HI) + p["out"]["bias"]
 
 
 def backbone(params: dict, x):
     """Final-norm class-token feature (N, D) of float32 NHWC images."""
-    w = jnp.asarray(params["patch_embed"]["kernel"], jnp.float32)  # (P, P, 3, D)
+    w = operand(params["patch_embed"]["kernel"])  # (P, P, 3, D)
     patch, dim = w.shape[0], w.shape[3]
     n, h, _, c = x.shape
     grid = h // patch
     # non-overlapping patches: a strided convolution is a matmul on them
     patches = x.reshape(n, grid, patch, grid, patch, c).transpose(0, 1, 3, 2, 4, 5)
     tokens = jnp.matmul(
-        patches.reshape(n, grid * grid, patch * patch * c), w.reshape(-1, dim), precision=HI
+        operand(patches.reshape(n, grid * grid, patch * patch * c)), w.reshape(-1, dim), precision=HI
     ) + params["patch_embed"]["bias"]
     cls = "cls_token" in params
     if cls:
@@ -128,6 +164,39 @@ def loss_and_embeddings(
     k1, k2 = jnp.split(keys, 2, axis=0)
     labels = jnp.arange(q1.shape[0], dtype=jnp.int32)
     ctr = lambda q, k: 2.0 * temperature * cross_entropy(
-        jnp.matmul(q, k.T, precision=HI) / temperature, labels
+        jnp.matmul(operand(q), operand(k).T, precision=HI) / temperature, labels
     )
     return ctr(q1, k2) + ctr(q2, k1), q1
+
+
+# -- operations, from shapes alone ------------------------------------------
+# The topology is the paper's (ViT: arXiv:2010.11929); the tree is the
+# program's own (flax names).
+
+
+def vit_forward_flops(backbone: dict, image_size: int) -> float:
+    """Forward operations of one image through the program's ViT tree:
+    patch projection, then per block QKV + scores + weighted sum +
+    output projection + the two MLP matmuls, over S = patches (+1 with a
+    class token) tokens."""
+    ph, pw, cin, dim = shape(backbone["patch_embed"]["kernel"])
+    patches = (image_size // ph) * (image_size // pw)
+    seq = patches + (1 if "cls_token" in backbone else 0)
+    total = 2.0 * ph * pw * cin * dim * patches
+    for name, blk in backbone.items():
+        if not name.startswith("block_"):
+            continue
+        total += dense_flops(blk["MlpBlock_0"], seq)
+        attn = blk["MultiHeadDotProductAttention_0"]
+        for proj in ("query", "key", "value", "out"):
+            k = shape(attn[proj]["kernel"])
+            total += 2.0 * math.prod(k) * seq
+        total += 2.0 * 2.0 * seq * seq * dim  # QK^T and softmax(.)V over all heads
+    return total
+
+
+def forward_flops(param_shapes: dict, config) -> float:
+    """One image forward through backbone + projection head, from the
+    encoder's parameter shapes and the configuration's input size."""
+    fwd = vit_forward_flops(param_shapes["backbone"], config.data.image_size)
+    return fwd + dense_flops(param_shapes.get("head", {}))

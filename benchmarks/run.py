@@ -7,9 +7,11 @@ per-layer metric files by name, and runs it through the program's normal
 entry point (`moco_tpu.train.train`, or a serving replica's `main`). The
 last line of standard output is one JSON object: `correct`, `attempted`,
 `failed`, `metrics` (the cell's end-to-end metrics with `--trace 0`, its
-per-layer metrics with `--trace 1`), `device`, and with `--trace 1`
-`breakdown`. Everything else (the window's log lines, the latency list,
-the reduced trace) goes to `benchmarks/out/<cell>-<seed>-<trace>.json`.
+per-layer metrics with `--trace 1`), `device`, with `--trace 1`
+`breakdown`, and last `compared`: every number `correct` rests on beside
+its limit, which are also the last lines of standard error. Everything
+else (the window's log lines, the latency list, the reduced trace) goes
+to `benchmarks/out/<cell>-<seed>-<trace>.json`.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits 3 and
 prints no result. `--rehearse` is for the CPU: a tiny preset, counts and
@@ -83,10 +85,19 @@ def main(argv=None) -> int:
     result = runner.run(manifest, cell, args, _T_START)
 
     detail = result.pop("_detail", {})
+    compared = result.pop("compared", None)
+    if compared is not None:
+        result["compared"] = compared  # last in the line
     os.makedirs(common.OUT_DIR, exist_ok=True)
     out_path = os.path.join(common.OUT_DIR, f"{cell['name']}-{args.seed}-{args.trace}.json")
     with open(out_path, "w") as f:
         json.dump({"result": result, **detail}, f)
+    # the driver's contract: each number compared beside its limit "as its last lines on
+    # standard error, and in the result's line too, under a key of its own that comes last
+    # there" (of a run that is not correct the driver keeps the end of each and nothing else)
+    for name, c in (compared or {}).items():
+        limits = " ".join(f"{k}={v}" for k, v in c.items() if k != "value")
+        print(f"compared {name}: value={c['value']} {limits}", file=sys.stderr)
     sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0

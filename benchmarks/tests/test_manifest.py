@@ -1,6 +1,7 @@
 """The manifest keeps to the contract, every name resolves to a file, and a
-configuration, a cell, a per-layer metric and a reader can each be added
-by new files and manifest entries alone."""
+configuration, a cell, a per-layer metric and a reader, and a whole encoder
+family with its own input, tolerance, operation count and kernel, can each
+be added by new files and manifest entries alone."""
 
 import json
 import os
@@ -9,7 +10,7 @@ import shutil
 import pytest
 
 from benchmarks.harness.manifest import (
-    BENCH_DIR, NAME_RE, REPO_ROOT, UNIT_RE, Manifest, read_layer_metrics,
+    BENCH_DIR, NAME_RE, REPO_ROOT, UNIT_RE, Manifest, ManifestError, load_module, read_layer_metrics,
 )
 
 
@@ -100,8 +101,14 @@ def test_files_found_by_name(manifest):
         cfg = manifest.config_file(w["config"])
         assert cfg["name"] == w["config"] and "preset" in cfg and "reference" in cfg
         assert sorted(cfg["reduced"]) == sorted(manifest.configs[w["config"]]["reduced"])
-        ref = os.path.join(BENCH_DIR, "reference", cfg["reference"] + ".py")
-        assert os.path.exists(ref)
+        # the family's file states its input, its tolerances and its operation count, and the
+        # input module has what the harness asks of a modality
+        ref, inputs = manifest.family(cfg)
+        assert NAME_RE.match(ref.INPUT) and callable(ref.forward_flops)
+        assert 0 < ref.TOLERANCES["emb_centred_rel"] < 1
+        assert all(callable(getattr(ref, f)) for f in ("loss_and_embeddings", "embed"))
+        assert all(callable(getattr(inputs, f))
+                   for f in ("dataset", "sample_input", "correct_views", "correct_rows"))
         traffic = manifest.traffic_file(w["traffic"])
         assert traffic["kind"] in ("train", "serve")
         if traffic["kind"] == "serve":
@@ -109,17 +116,24 @@ def test_files_found_by_name(manifest):
         for m in manifest.metrics_for(cell, "per_layer"):
             spec = manifest.layer_metric_file(m["name"])
             assert hasattr(manifest.reader(spec["reader"]), "read")
+            if "required" in spec:  # a kernel's required work is a module found by name
+                assert callable(load_module(manifest.bench_dir, "required", spec["required"]).required)
 
 
-def test_additions_need_new_files_only(tmp_path):
-    """A throw-away configuration, cell, per-layer metric and reader that
-    exist only in a temporary directory: nothing already there is edited."""
+def _throwaway_copy(tmp_path):
+    """(root of a copy of the benchmark in a temporary directory, its manifest as a dict,
+    every file of the copy with its bytes)."""
     root = tmp_path / "repo"
     shutil.copytree(BENCH_DIR, root / "benchmarks", ignore=shutil.ignore_patterns("out", "__pycache__"))
     raw = json.load(open(os.path.join(REPO_ROOT, "BENCHMARK.json")))
     before = {
         str(p.relative_to(root)): p.read_bytes() for p in (root / "benchmarks").rglob("*") if p.is_file()
     }
+    return root, raw, before
+
+
+def _a_cell(root, raw):
+    """A throw-away configuration, cell, per-layer metric and reader."""
     cfg = json.load(open(root / "benchmarks/configs/r50_v2.json"))
     cfg.update(name="r50_v2_b512", overrides={"data.global_batch": 512})
     (root / "benchmarks/configs/r50_v2_b512.json").write_text(json.dumps(cfg))
@@ -152,6 +166,143 @@ def test_additions_need_new_files_only(tmp_path):
     assert got == {"loss_last": {"value": 9.25, "unit": "nats"}}
     # a reader that finds nothing leaves its metric out of the line
     assert read_layer_metrics(m, "train_r50_b512", {}) == {}
+
+
+TOY_REFERENCE = '''"""A throw-away family: the ResNet reference's forward under another name,
+with its own input, tolerances and operation count."""
+from benchmarks.harness.flops import dense_flops
+from benchmarks.reference.resnet_moco_v2 import embed, loss_and_embeddings  # noqa: F401
+
+INPUT = "toy_rows"
+TOLERANCES = {"emb_centred_rel": 2e-3, "loss_abs": 5e-4}
+
+
+def forward_flops(param_shapes, config):
+    return 7.0 * dense_flops(param_shapes) + config.data.image_size
+'''
+
+TOY_INPUT = '''"""A throw-away input: the image module's rows from another seed, the views
+the other way round; `CALLS` records what the harness asked for."""
+from benchmarks.inputs import images
+
+CALLS = []
+
+
+def dataset(seed, traffic, config):
+    CALLS.append("dataset")
+    return images.dataset(seed + 1, {"pool_images": traffic["toy_pool"]}, config)
+
+
+def sample_input(config):
+    CALLS.append("sample_input")
+    return images.sample_input(config)
+
+
+def correct_rows(seed, n, config):
+    CALLS.append("correct_rows")
+    return images.correct_rows(seed + 1, n, config)
+
+
+def correct_views(seed, n, config):
+    CALLS.append("correct_views")
+    return images.correct_views(seed + 1, n, config)[::-1]
+'''
+
+TOY_REQUIRED = '''"""A throw-away kernel's required work: 8190 bytes a chip and step."""
+
+
+def required(ctx):
+    return {"flops": 2.0 * ctx["chips"], "bytes": 8190.0} if ctx.get("toy") else None
+'''
+
+
+def _a_family(root, raw):
+    """A throw-away encoder family: a reference module with its own tolerance, operation count
+    and input, that input module, a kernel's required work and the roofline metric that names it;
+    `check_train`, `_step_flops` and the `kernel` reader driven through them."""
+    from benchmarks.harness import correct, flops
+    from benchmarks.harness.common import build_train_config, merged
+    from benchmarks.harness.peaks import peaks_for
+    from benchmarks.harness.train_cell import _step_flops
+    from benchmarks.trace_reduce import ops_inside, reduce_trace
+
+    bench = root / "benchmarks"
+    (bench / "reference/toy_family.py").write_text(TOY_REFERENCE)
+    (bench / "inputs/toy_rows.py").write_text(TOY_INPUT)
+    (bench / "required/toy_copy.py").write_text(TOY_REQUIRED)
+    cfg = json.load(open(bench / "configs/r50_v2.json"))
+    cfg.update(name="toy", reference="toy_family")
+    (bench / "configs/toy.json").write_text(json.dumps(cfg))
+    traffic = json.load(open(bench / "traffic/job_loop.json"))
+    del traffic["pool_images"], traffic["rehearsal"]["pool_images"]  # the image module's key
+    traffic["toy_pool"] = 16  # this input module's own
+    (bench / "traffic/toy_loop.json").write_text(json.dumps(traffic))
+    for name, required in (("toy_copy_roofline", "toy_copy"), ("toy_lost_roofline", "nowhere")):
+        (bench / f"layer_metrics/{name}.json").write_text(json.dumps(
+            {"reader": "kernel", "pattern": "^copy", "what": "roofline", "required": required}
+        ))
+    raw["configs"].append({"name": "toy", "source": "x", "reduced": [], "why": "y",
+                           "file": "benchmarks/configs/toy.json"})
+    raw["workloads"].append({"name": "train_toy", "config": "toy", "traffic": "toy_loop",
+                             "chips": 1, "why": "z"})
+    raw["end_to_end"][0]["workloads"].append("train_toy")
+    raw["per_layer"].append({"name": "toy_copy_roofline", "unit": "%", "better": "higher",
+                             "source": "device_trace", "layer": "kernels",
+                             "moves": "train_img_per_s_chip", "workloads": ["train_toy"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(raw))
+
+    m = Manifest(repo_root=str(root))
+    cell = m.cell("train_toy")
+    cfg_file, traffic_file = m.config_file(cell["config"]), m.traffic_file(cell["traffic"])
+    ref, inputs = m.family(cfg_file)
+    assert ref.__file__.startswith(str(root)) and inputs.__file__.startswith(str(root))
+    config = build_train_config(cfg_file, traffic_file, 11, "/nonexistent", True)  # resnet18, 32 px
+    pool = inputs.dataset(11, merged(traffic_file, True), config)
+    assert pool.load(0)[0].shape == (32, 32, 3) and len(pool._pool) == 16
+
+    # `correct` through the family's own sample and held to its own limits
+    out = correct.check_train(config, ref, inputs, seed=11, sample_n=8, gradient=False)
+    assert out["ok"], out
+    assert inputs.CALLS == ["dataset", "sample_input", "correct_views"]
+    beside = correct.compared(out, ref)
+    assert beside["emb_centred_rel_error"]["at_most"] == 2e-3 and beside["loss_abs_diff"]["at_most"] == 5e-4
+    ref.TOLERANCES = {"loss_abs": 0.02}  # a family that states no embedding limit is refused
+    with pytest.raises(ValueError, match="emb_centred_rel"):
+        correct.tolerances(ref)
+
+    # the step's operations from the family's count (MoCo v2: 3 + 1 forwards a pair, and InfoNCE)
+    step = _step_flops(config, ref, inputs)
+    import jax
+    from moco_tpu.core import build_encoder
+    shapes = jax.eval_shape(
+        lambda r: build_encoder(config.moco).init(r, inputs.sample_input(config), train=False),
+        jax.random.PRNGKey(0),
+    )["params"]
+    fwd = 7.0 * flops.dense_flops(shapes) + 32
+    assert fwd > 32 and step == 32 * 4.0 * fwd + flops.infonce_flops(32, 128, 4096)
+
+    # the kernel reader finds the copy's required-work module by the name in the metric's file
+    ops = [("fusion.1", 0, 100), ("while.2", 100, 300), ("convolution.3", 120, 50),
+           ("all-reduce-done.4", 200, 100), ("copy.5", 500, 100)]
+    mods = [("jit_step_fn(1)", 0, 400), ("jit_step_fn(1)", 450, 150), ("jit__augment(2)", 405, 40)]
+    reduced = reduce_trace(ops, mods, "jit_step_fn")
+    ctx = {"trace": reduced, "trace_ops": ops_inside(ops, reduced), "chips": 1, "toy": True,
+           "peaks": peaks_for("TPU v5 lite")}
+    got = read_layer_metrics(m, "train_toy", ctx)
+    # 100 ns of `copy` over 2 steps; 8190 B at 819 GB/s is 10 ns: a fifth of 50 ns
+    assert got["toy_copy_roofline"] == {"value": pytest.approx(20.0), "unit": "%"}
+    assert "toy_copy_roofline" not in read_layer_metrics(m, "train_toy", {**ctx, "toy": False})
+    lost = m.layer_metric_file("toy_lost_roofline")
+    with pytest.raises(ManifestError, match="required/nowhere.py"):
+        m.reader(lost["reader"]).read(lost, ctx)
+
+
+@pytest.mark.parametrize("add", [_a_cell, _a_family], ids=["cell", "family"])
+def test_additions_need_new_files_only(tmp_path, add):
+    """What a later PR adds exists only in a temporary copy, as new files and manifest entries:
+    it works there, and nothing that was already there is edited."""
+    root, raw, before = _throwaway_copy(tmp_path)
+    add(root, raw)
     after = {k: (root / k).read_bytes() for k in before}
     assert after == before
 
