@@ -11,6 +11,7 @@ from moco_tpu.core.moco import (
     make_train_step,
     place_state,
     reshard_state,
+    sample_input,
     state_specs,
     zero_stage23,
 )
@@ -29,6 +30,7 @@ __all__ = [
     "make_train_step",
     "place_state",
     "reshard_state",
+    "sample_input",
     "state_specs",
     "zero_stage23",
     "check_queue_divisibility",

@@ -41,6 +41,7 @@ from moco_tpu.core.queue import check_queue_divisibility, enqueue, init_queue
 from moco_tpu.obs import comms
 from moco_tpu.obs import health as obs_health
 from moco_tpu.models import ProjectionHead, V3MLPHead, create_resnet
+from moco_tpu.models.joyai import create_joyai, is_token_arch, routing_metrics
 from moco_tpu.ops.losses import cross_entropy, infonce_logits, l2_normalize, topk_accuracy
 from moco_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from moco_tpu.parallel.shuffle import (
@@ -87,8 +88,21 @@ class MoCoEncoder(nn.Module):
 
 def create_backbone(cfg: MocoConfig, num_data: Optional[int] = None) -> nn.Module:
     """Backbone factory shared by pretraining and the linear probe:
-    ResNet family or ViT family from `cfg.arch`."""
+    ResNet family, ViT family or a decoder stack (token rows) from
+    `cfg.arch`."""
     dtype = jnp.dtype(cfg.compute_dtype)
+    if is_token_arch(cfg.arch):
+        if cfg.v3 or cfg.vit_sequence_parallel or cfg.shuffle != "none":
+            # a decoder stack has no BatchNorm to shuffle for, and only the
+            # queue path (v1/v2) has been taught to read token rows
+            raise ValueError(
+                f"{cfg.arch!r} is a token encoder: it trains on the v1/v2 step "
+                "with shuffle='none'"
+            )
+        return create_joyai(
+            cfg.arch, dtype=dtype, layers=cfg.lm_layers, vocab_rows=cfg.lm_vocab_rows,
+            expert_share=tuple(cfg.expert_share) or None, remat=cfg.remat,
+        )
     if cfg.vit_sequence_parallel and not cfg.arch.startswith("vit"):
         # must fail HERE, not just in the vit branch: v3_step keys its
         # backbone-grad psum on this flag, and a silently-ignored flag on
@@ -304,14 +318,21 @@ def _tree_shard_bytes_analytic(tree, n: int) -> int:
     )
 
 
+def sample_input(config: TrainConfig):
+    """One row as the encoder takes it, for `init` and `eval_shape`: only
+    its structure and types matter (no parameter's shape depends on how
+    long a token row is, so a token sample is a short one)."""
+    if config.data.input == "tokens":
+        return {"ids": jnp.zeros((1, 8), jnp.int32), "lengths": jnp.full((1,), 8, jnp.int32)}
+    return jnp.zeros((1, config.data.image_size, config.data.image_size, 3), jnp.float32)
+
+
 def full_param_shapes(config: TrainConfig, encoder: MoCoEncoder, predictor=None) -> dict:
     """Abstract (ShapeDtypeStruct) trees of the FULL trainable params —
     the shape source the ZeRO-2/3 bucket plans, eval-side gathers, and
     reshard templates all derive from (the persistent (n, m) layout
     does not carry the original leaf shapes)."""
-    sample = jnp.zeros(
-        (1, config.data.image_size, config.data.image_size, 3), jnp.float32
-    )
+    sample = sample_input(config)
     enc = jax.eval_shape(
         lambda r: encoder.init(r, sample, train=False), jax.random.PRNGKey(0)
     )["params"]
@@ -761,8 +782,11 @@ def make_train_step(
     # instead of keeping every activation live (SURVEY.md hard-part 6 /
     # the HBM-vs-FLOPs trade). Key-side forwards carry no gradient, so
     # only the grad-bearing query apply is wrapped.
+    # A backbone that recomputes block by block itself (`remat` of its own)
+    # is not wrapped a second time: that would run its forward three times.
+    whole_remat = cfg.remat and not getattr(encoder.backbone, "remat", False)
     grad_apply_encoder = (
-        jax.checkpoint(lambda p, s, x: apply_encoder(p, s, x)) if cfg.remat else apply_encoder
+        jax.checkpoint(lambda p, s, x: apply_encoder(p, s, x)) if whole_remat else apply_encoder
     )
 
     def apply_predictor(params, batch_stats, x, train=True):
@@ -1053,7 +1077,7 @@ def make_train_step(
         if cfg.v3:
             return v3_step(state, batch, gathered=gathered)
         im_q, im_k = batch["im_q"], batch["im_k"]
-        local_b = im_q.shape[0]
+        local_b = jax.tree.leaves(im_q)[0].shape[0]
         # Deterministic per-step randomness, identical on every device:
         # replaces the reference's `broadcast(idx_shuffle, src=0)`
         # (moco/builder.py:~L89).
@@ -1192,6 +1216,13 @@ def make_train_step(
         # Running BN stats: average across devices (strictly better than
         # the reference, which checkpoints rank 0's local stats).
         stats_q = lax.pmean(stats_q, DATA_AXIS)
+        # what the query encoder's expert layers left there this step
+        # ({} for an encoder without any): rides the log line's one fetch
+        metrics.update(routing_metrics(stats_q))
+        if isinstance(im_q, dict):  # token rows: the valid tokens of both views
+            metrics["tokens_per_step"] = lax.psum(
+                jnp.sum(im_q["lengths"]) + jnp.sum(im_k["lengths"]), DATA_AXIS
+            )
         if cfg.key_bn_running_stats:
             # the key's running statistics trail the query's on the
             # params' momentum schedule (EMAN); stats_q is already

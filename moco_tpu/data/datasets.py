@@ -123,6 +123,29 @@ class SyntheticDataset:
         return img, int(index % self.num_classes)
 
 
+class SyntheticTokenDataset:
+    """Fixed-seed random token documents: the token-input counterpart of
+    `SyntheticDataset`. The token protocol is `__len__` and
+    `load_tokens(index) -> 1-D int32 ids`; a document may be shorter or
+    longer than the pipeline's window."""
+
+    def __init__(
+        self, num_examples: int = 1024, vocab_size: int = 512,
+        min_len: int = 32, max_len: int = 256,
+    ):
+        self.num_examples = num_examples
+        self.vocab_size = vocab_size
+        self.min_len, self.max_len = min_len, max_len
+
+    def __len__(self) -> int:
+        return self.num_examples
+
+    def load_tokens(self, index: int) -> np.ndarray:
+        rng = np.random.default_rng(index)
+        n = int(rng.integers(self.min_len, self.max_len + 1))
+        return rng.integers(0, self.vocab_size, n, dtype=np.int32)
+
+
 class LearnableSyntheticDataset:
     """Deterministic synthetic dataset with real class structure — the
     learning-signal stand-in for ImageNet in this no-dataset environment
@@ -646,6 +669,13 @@ class ImageFolderDataset:
             pool = self._crop_pool
         self.decode_failures += sum(pool.map(one, range(bs)))
         return out, labels
+
+
+def build_token_dataset(name: str, seq_len: int):
+    """Datasets of the token protocol (`load_tokens`), by name."""
+    if name == "synthetic":
+        return SyntheticTokenDataset(min_len=max(seq_len // 2, 1), max_len=4 * seq_len)
+    raise ValueError(f"no token dataset named {name!r} (choose from: synthetic)")
 
 
 def build_dataset(

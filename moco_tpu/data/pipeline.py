@@ -15,6 +15,12 @@ Replaces the reference's `DataLoader(workers=32)` + `TwoCropTransform`
   blur/flip/normalize — plus the crop itself on the canvas path),
   batched and jitted (`moco_tpu.data.augment`).
 
+Token input (`DataConfig.input == "tokens"`) keeps the threads, the ring
+and the epoch modes and changes what a row is: the host cuts two
+independent `seq_len`-token windows from each document (span
+`host_crop`, where images have `host_decode`), int32 ids and int32
+lengths cross the wire, and no augmentation program is dispatched.
+
 Two epoch modes, bit-identical in output (same seeded order, same step
 rngs, same jitted augment):
 
@@ -59,7 +65,7 @@ from moco_tpu.data.augment import (
     get_recipe,
     two_crop_augment,
 )
-from moco_tpu.data.datasets import build_dataset
+from moco_tpu.data.datasets import build_dataset, build_token_dataset
 from moco_tpu.data.device_prefetch import DevicePrefetchRing
 from moco_tpu.obs import comms
 from moco_tpu.obs.trace import span as obs_span
@@ -189,6 +195,20 @@ class HostBatch(NamedTuple):
         return n
 
 
+class TokenBatch(NamedTuple):
+    """One step's host-side product on token input: this process's rows,
+    `ids` (B_local, 2, seq_len) int32 with zeros past a window's end and
+    `lengths` (B_local, 2) int32, one window a view."""
+
+    step: int
+    ids: np.ndarray
+    lengths: np.ndarray
+
+    @property
+    def wire_bytes(self) -> int:
+        return int(self.ids.nbytes) + int(self.lengths.nbytes)
+
+
 class _HostPipeline:
     """Shared host-side machinery: dataset build, batch/steps accounting,
     decode pool, mesh sharding, seeded per-epoch shuffling."""
@@ -205,6 +225,8 @@ class _HostPipeline:
         self.config = config
         self.mesh = mesh
         self.seed = seed
+        if dataset is None and config.input == "tokens":
+            dataset = build_token_dataset(config.dataset, config.seq_len)
         self.dataset = dataset or build_dataset(
             config.dataset,
             config.data_dir,
@@ -382,6 +404,11 @@ class TwoCropPipeline(_HostPipeline):
 
     def __init__(self, config: DataConfig, mesh: Mesh, seed: int = 0, dataset=None, train: bool = True):
         super().__init__(config, mesh, seed=seed, dataset=dataset, train=train, drop_last=True)
+        self.tokens = config.input == "tokens"
+        if self.tokens:
+            if config.seq_len <= 0:
+                raise ValueError("token input needs data.seq_len > 0")
+            return  # no recipe, no augmentation program
         self.recipe: AugRecipe = get_recipe(
             config.aug_plus, config.image_size, crops_only=config.crops_only
         )
@@ -436,6 +463,41 @@ class TwoCropPipeline(_HostPipeline):
                 out = aug(hb.rng, views[0])
         return out, hb.wire_bytes
 
+    def _token_gen(self, epoch: int):
+        """Two independent windows of `seq_len` tokens from each document
+        (a document shorter than the window is one whole view, padded).
+        The window starts are drawn once a step for the global batch and
+        sliced by global position, as the crop boxes are."""
+        order, seq = self._epoch_order(epoch), self.config.seq_len
+        positions = np.asarray(self._partition.local_positions, np.int64)
+        for step in range(self.steps_per_epoch):
+            idx = order[step * self.batch_size : (step + 1) * self.batch_size]
+            local_idx = self._partition.local_indices(idx)
+            u = np.random.default_rng((self.seed, epoch, step)).random((self.batch_size, 2))
+            ids = np.zeros((len(local_idx), 2, seq), np.int32)
+            lengths = np.zeros((len(local_idx), 2), np.int32)
+            with obs_span("host_crop", n=len(local_idx)):
+                for row, (index, pos) in enumerate(zip(local_idx, positions)):
+                    doc = np.asarray(self.dataset.load_tokens(int(index)), np.int32)
+                    span = min(len(doc), seq)
+                    for view in range(2):
+                        start = int(u[pos, view] * (len(doc) - span + 1))
+                        ids[row, view, :span] = doc[start : start + span]
+                        lengths[row, view] = span
+            yield TokenBatch(step, ids, lengths)
+
+    def _stage_tokens(self, tb: TokenBatch, donate: bool):
+        part = self._partition
+        with comms.tag("input.h2d", "device_put", (tb.ids, tb.lengths), axis_size=1):
+            out = {
+                name: {
+                    "ids": part.assemble(np.ascontiguousarray(tb.ids[:, view])),
+                    "lengths": part.assemble(np.ascontiguousarray(tb.lengths[:, view])),
+                }
+                for view, name in enumerate(("im_q", "im_k"))
+            }
+        return out, tb.wire_bytes
+
     def epoch(
         self,
         epoch: int,
@@ -443,6 +505,10 @@ class TwoCropPipeline(_HostPipeline):
         depth: Optional[int] = None,
         donate: bool = False,
     ) -> Iterator[dict]:
+        if self.tokens:
+            return self._epoch_iter(
+                self._token_gen(epoch), self._stage_tokens, device, depth, donate
+            )
         return self._epoch_iter(self._host_gen(epoch), self._stage, device, depth, donate)
 
 

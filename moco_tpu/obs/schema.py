@@ -155,6 +155,8 @@ FIELD_VALIDATORS = {
     # (obs/stepstats.py tree_shard_bytes) — backend-independent, so the
     # ZeRO-1 vs ZeRO-2/3 memory A/B works on CPU meshes too
     "hbm_state_bytes": _int_like,
+    # token input: the valid tokens of both views this step, over the mesh
+    "tokens_per_step": _num,
     # ZeRO-2/3 hoisted-gather overlap efficiency (parallel/zero.py
     # AsyncParamGather): 1 - wait/duration of the gather-side stall the
     # worker absorbed off the critical path (the synthetic
@@ -305,6 +307,9 @@ PREFIX_VALIDATORS = {
     # and phase/steps, the steps it covers. Measured on every step, so
     # never null.
     "phase/": _num,
+    # an expert layer's routing this step (models/joyai.py
+    # routing_metrics): moe/load_max_over_mean, moe/tokens_per_expert
+    "moe/": _num,
     # the `setup` event line's parts (obs/stepstats.py setup_account)
     "setup/": _num,
     # elastic rescale event fields (kappa, derived lr/momentum, ...);

@@ -26,9 +26,12 @@ returns the (out, logsumexp) pair that lets partial attention results
 from different devices be combined exactly, and the backward carries
 the lse cotangent that merge induces.
 
-Non-causal (ViT is bidirectional); fp32 accumulation regardless of
-input dtype; jnp reference implementation included for testing and as
-the CPU fallback.
+`flash_attention` is non-causal (ViT is bidirectional). The decoder
+stacks read `causal_flash_attention` at the end of this file: causal with
+the key blocks above the diagonal skipped, a query/key width that may
+differ from the value width (latent attention: 192 against 128), and a
+key length per row. fp32 accumulation regardless of input dtype; jnp
+reference implementations included for testing and as the CPU fallback.
 """
 
 from __future__ import annotations
@@ -39,6 +42,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -439,3 +443,306 @@ def flash_attention(
     """Attention output only; differentiable."""
     out, _ = flash_attention_with_lse(q, k, v, scale, block_q, block_k, interpret)
     return out
+
+
+# ------------------------------------------- causal, unequal widths, lengths
+#
+# What a decoder stack's attention needs and the kernels above lack: a
+# causal mask whose blocks above the diagonal cost nothing, q/k of one
+# width and v of another, and keys masked beyond each row's own length.
+# The grid is (batch*head, query block, key block) with the softmax state
+# in VMEM scratch across the last axis, so neither K/V nor Q is ever whole
+# in VMEM (8192 positions x 192 would be). A skipped key block maps to the
+# block before it: its DMA is elided and its body does not run.
+
+CAUSAL_BLOCK = 512
+# below this the (S, S) scores of one head are a few VMEM tiles and XLA's
+# fused attention is as good: the kernels engage on length, never on a flag
+CAUSAL_MIN_SEQ = 1024
+
+
+def _causal_mask(s_q: int, s_k: int, kv_lens: jax.Array) -> jax.Array:
+    """(B, 1, S_q, S_k): key at or before the query, and inside the row's length."""
+    rows = jnp.arange(s_q)[:, None]
+    cols = jnp.arange(s_k)[None, :]
+    return (cols <= rows)[None, None] & (cols[None, None] < kv_lens[:, None, None, None])
+
+
+def _causal_attn_reference(q, k, v, kv_lens, scale):
+    """Dense masked jnp attention, (B, H, S, Dqk) x (B, H, S, Dv) -> (B, H, S, Dv)."""
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
+    logits = jnp.where(_causal_mask(q.shape[2], k.shape[2], kv_lens), logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, v.astype(jnp.float32)).astype(v.dtype)
+
+
+def _scores(q, kb, scale, q_start, k_start, kv_len):
+    """Masked scaled scores of one (query block, key block) tile, fp32."""
+    s = jax.lax.dot_general(
+        q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale
+    rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where((cols <= rows) & (cols < kv_len), s, NEG_INF)
+
+
+def _causal_fwd_kernel(
+    lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc,
+    *, scale: float, block_q: int, block_k: int, heads: int,
+):
+    i, j = pl.program_id(1), pl.program_id(2)
+    kv_len = lens_ref[pl.program_id(0) // heads]
+    q_start, k_start = i * block_q, j * block_k
+
+    @pl.when(j == 0)
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+
+    @pl.when((k_start < q_start + block_q) & (k_start < kv_len))
+    def _():
+        s = _scores(q_ref[...], k_ref[...], scale, q_start, k_start, kv_len)
+        m_prev = m_sc[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        vb = v_ref[...]
+        acc[...] = acc[...] * corr + jax.lax.dot_general(
+            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_sc[...] = m_new
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        # a row of a length-0 batch entry has seen no key: l = 0; it is
+        # padding, its output is never read, but it must stay finite
+        l = jnp.maximum(l_sc[...], 1e-30)
+        o_ref[...] = (acc[...] / l).astype(o_ref.dtype)
+        lse_ref[...] = m_sc[...] + jnp.log(l)
+
+
+def _causal_dq_kernel(
+    lens_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref, acc,
+    *, scale: float, block_q: int, block_k: int, heads: int,
+):
+    i, j = pl.program_id(1), pl.program_id(2)
+    kv_len = lens_ref[pl.program_id(0) // heads]
+    q_start, k_start = i * block_q, j * block_k
+
+    @pl.when(j == 0)
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+
+    @pl.when((k_start < q_start + block_q) & (k_start < kv_len))
+    def _():
+        kb, vb, g = k_ref[...], v_ref[...], g_ref[...]
+        s = _scores(q_ref[...], kb, scale, q_start, k_start, kv_len)
+        p = jnp.exp(s - lse_ref[...])
+        dp = jax.lax.dot_general(
+            g, vb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        ds = (p * (dp - delta_ref[...])).astype(kb.dtype)
+        acc[...] += jax.lax.dot_general(
+            ds, kb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[...] = (acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _causal_dkv_kernel(
+    lens_ref, k_ref, v_ref, q_ref, g_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+    *, scale: float, block_q: int, block_k: int, heads: int,
+):
+    j, i = pl.program_id(1), pl.program_id(2)  # key block outside, query blocks stream
+    kv_len = lens_ref[pl.program_id(0) // heads]
+    q_start, k_start = i * block_q, j * block_k
+
+    @pl.when(i == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when((k_start < q_start + block_q) & (k_start < kv_len))
+    def _():
+        qb, g, vb = q_ref[...], g_ref[...], v_ref[...]
+        s = _scores(qb, k_ref[...], scale, q_start, k_start, kv_len)
+        p = jnp.exp(s - lse_ref[...])
+        dv_acc[...] += jax.lax.dot_general(
+            p.astype(g.dtype), g, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        dp = jax.lax.dot_general(
+            g, vb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        ds = (p * (dp - delta_ref[...])).astype(qb.dtype)
+        dk_acc[...] += jax.lax.dot_general(
+            ds, qb, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+    @pl.when(i == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _causal_specs(block_q: int, block_k: int, d_qk: int, d_v: int):
+    """Block specs over a (bh, query block, key block) grid. A key block
+    above the diagonal maps to the last one needed, so it is not fetched."""
+    last_k = lambda i: (i * block_q + block_q - 1) // block_k
+    q_map = lambda b, i, j, lens: (b, i, 0)
+    k_map = lambda b, i, j, lens: (b, jnp.minimum(j, last_k(i)), 0)
+    return {
+        "q": pl.BlockSpec((None, block_q, d_qk), q_map),
+        "g": pl.BlockSpec((None, block_q, d_v), q_map),
+        "row": pl.BlockSpec((None, block_q, 1), q_map),
+        "k": pl.BlockSpec((None, block_k, d_qk), k_map),
+        "v": pl.BlockSpec((None, block_k, d_v), k_map),
+    }
+
+
+_SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret):
+    b, h, s, d_qk = q.shape
+    d_v = v.shape[-1]
+    bh = b * h
+    sp = _causal_specs(block_q, block_k, d_qk, d_v)
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _causal_fwd_kernel, scale=scale, block_q=block_q, block_k=block_k, heads=h
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, s // block_q, s // block_k),
+            in_specs=[sp["q"], sp["k"], sp["v"]],
+            out_specs=[sp["g"], sp["row"]],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d_v), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, s, d_v), v.dtype),
+            jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
+        ],
+        compiler_params=_SEMANTICS,
+        interpret=interpret,
+        name="causal_attention_fwd",
+    )(kv_lens, q.reshape(bh, s, d_qk), k.reshape(bh, s, d_qk), v.reshape(bh, s, d_v))
+    return out.reshape(b, h, s, d_v), lse
+
+
+def _causal_backward(q, k, v, kv_lens, out, lse, g, scale, block_q, block_k, interpret):
+    b, h, s, d_qk = q.shape
+    d_v = v.shape[-1]
+    bh = b * h
+    delta = jnp.sum(
+        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True
+    ).reshape(bh, s, 1)
+    q3, k3 = q.reshape(bh, s, d_qk), k.reshape(bh, s, d_qk)
+    v3, g3 = v.reshape(bh, s, d_v), g.reshape(bh, s, d_v)
+    sp = _causal_specs(block_q, block_k, d_qk, d_v)
+    static = dict(scale=scale, block_q=block_q, block_k=block_k, heads=h)
+    dq = pl.pallas_call(
+        functools.partial(_causal_dq_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, s // block_q, s // block_k),
+            in_specs=[sp["q"], sp["g"], sp["row"], sp["row"], sp["k"], sp["v"]],
+            out_specs=sp["q"],
+            scratch_shapes=[pltpu.VMEM((block_q, d_qk), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((bh, s, d_qk), q.dtype),
+        compiler_params=_SEMANTICS,
+        interpret=interpret,
+        name="causal_attention_dq",
+    )(kv_lens, q3, g3, lse, delta, k3, v3)
+
+    # key block outside, query blocks stream: those before the diagonal
+    # map to the first one needed and are skipped
+    first_q = lambda j: (j * block_k) // block_q
+    kq_map = lambda b_, j, i, lens: (b_, jnp.maximum(i, first_q(j)), 0)
+    kk_map = lambda b_, j, i, lens: (b_, j, 0)
+    dk, dv = pl.pallas_call(
+        functools.partial(_causal_dkv_kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, s // block_k, s // block_q),
+            in_specs=[
+                pl.BlockSpec((None, block_k, d_qk), kk_map),
+                pl.BlockSpec((None, block_k, d_v), kk_map),
+                pl.BlockSpec((None, block_q, d_qk), kq_map),
+                pl.BlockSpec((None, block_q, d_v), kq_map),
+                pl.BlockSpec((None, block_q, 1), kq_map),
+                pl.BlockSpec((None, block_q, 1), kq_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, block_k, d_qk), kk_map),
+                pl.BlockSpec((None, block_k, d_v), kk_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d_qk), jnp.float32),
+                pltpu.VMEM((block_k, d_v), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, s, d_qk), k.dtype),
+            jax.ShapeDtypeStruct((bh, s, d_v), v.dtype),
+        ],
+        compiler_params=_SEMANTICS,
+        interpret=interpret,
+        name="causal_attention_dkv",
+    )(kv_lens, k3, v3, q3, g3, lse, delta)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _causal_flash(q, k, v, kv_lens, scale, block_q, block_k, interpret):
+    return _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret)[0]
+
+
+def _causal_fwd(q, k, v, kv_lens, scale, block_q, block_k, interpret):
+    out, lse = _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret)
+    return out, (q, k, v, kv_lens, out, lse)
+
+
+def _causal_bwd(scale, block_q, block_k, interpret, res, g):
+    q, k, v, kv_lens, out, lse = res
+    dq, dk, dv = _causal_backward(
+        q, k, v, kv_lens, out, lse, g, scale, block_q, block_k, interpret
+    )
+    return dq, dk, dv, None
+
+
+_causal_flash.defvjp(_causal_fwd, _causal_bwd)
+
+
+def causal_flash_attention(
+    q: jax.Array,  # (B, H, S, Dqk)
+    k: jax.Array,  # (B, H, S, Dqk)
+    v: jax.Array,  # (B, H, S, Dv)
+    kv_lens: jax.Array,  # (B,) int32: keys at or beyond a row's length are masked
+    scale: Optional[float] = None,
+    block_q: int = CAUSAL_BLOCK,
+    block_k: int = CAUSAL_BLOCK,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal attention (B, H, S, Dv); differentiable in q, k and v. The
+    Pallas kernels run where the sequence is long enough to need them and
+    the blocks divide it; a short one takes the dense masked product
+    (blocks given by the caller always mean the kernels: a test's way to
+    reach them at a test's length)."""
+    s = q.shape[2]
+    scale = scale if scale is not None else q.shape[-1] ** -0.5
+    kv_lens = kv_lens.astype(jnp.int32)
+    if s < CAUSAL_MIN_SEQ and (block_q, block_k) == (CAUSAL_BLOCK, CAUSAL_BLOCK):
+        return _causal_attn_reference(q, k, v, kv_lens, scale)
+    if s % block_q or s % block_k:
+        raise ValueError(f"sequence length {s} is not a multiple of blocks {block_q}, {block_k}")
+    return _causal_flash(q, k, v, kv_lens, scale, block_q, block_k, interpret)

@@ -37,6 +37,7 @@ from moco_tpu.core import (
     make_train_step,
     place_state,
     reshard_state,
+    sample_input,
     zero_stage23,
 )
 from moco_tpu.data.pipeline import TwoCropPipeline
@@ -193,6 +194,16 @@ def train(
         tracer.close()
 
 
+def state_needs_single_copy(state_bytes: int) -> bool:
+    """Whether the first device can hold the train state only once: two
+    copies and the room a step's temporaries take (half a copy, at least)
+    pass what it reports as its limit. False where the backend reports no
+    limit (the CPU)."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return bool(limit) and 2.5 * state_bytes > limit
+
+
 def _train_impl(
     config: TrainConfig,
     dataset,
@@ -273,7 +284,7 @@ def _train_impl(
 
     rng = jax.random.PRNGKey(config.seed)
     init_rng, shuffle_rng = jax.random.split(rng)
-    sample = jnp.zeros((1, config.data.image_size, config.data.image_size, 3), jnp.float32)
+    sample = sample_input(config)
     zero = config.parallel.shard_weight_update
     zero23 = zero_stage23(config)
     with obs.span("setup/state_init"):
@@ -394,16 +405,6 @@ def _train_impl(
         print0(f"resumed from epoch {start_epoch - 1} (step {int(state.step)})")
 
     shard_q = config.parallel.num_model > 1 and config.moco.num_negatives > 0
-    step_fn = make_train_step(
-        config,
-        encoder,
-        tx,
-        mesh,
-        shard_queue_over_model=shard_q,
-        predictor=predictor,
-        total_steps=config.optim.epochs * steps_per_epoch,
-        state_template=state if zero else None,
-    )
     state = place_state(
         state, mesh, shard_queue_over_model=shard_q, zero=zero, zero_params=zero23
     )
@@ -414,6 +415,22 @@ def _train_impl(
     # layout is static) — the ZeRO stages' memory A/B gauge, available
     # on every backend including CPU meshes where memory_stats is not.
     hbm_state_bytes = tree_shard_bytes(state)
+    # A state the device cannot hold twice beside a step's temporaries is
+    # donated to the step, and the driver keeps no second reference to it
+    # (no rollback copy for the NaN guard: a non-finite loss then aborts,
+    # and the last checkpoint is the way back).
+    single_copy = state_needs_single_copy(hbm_state_bytes)
+    step_fn = make_train_step(
+        config,
+        encoder,
+        tx,
+        mesh,
+        shard_queue_over_model=shard_q,
+        donate=single_copy,
+        predictor=predictor,
+        total_steps=config.optim.epochs * steps_per_epoch,
+        state_template=state if zero else None,
+    )
     # Analytic PEAK model-param footprint per device (shards + the
     # transient gathered full params): whole-tree for plain zero23, the
     # largest adjacent group pair under layer-granular gathering — the
@@ -664,7 +681,9 @@ def _train_impl(
             # emergency checkpoint of the last known-finite state (the
             # fault-tolerance layer's save-first-die-second path)
             emergency_save(
-                guard["good_state"], epoch - 1,  # mid-epoch semantics (see watchdog)
+                # (the live state where no rollback copy is kept)
+                state if single_copy else guard["good_state"],
+                epoch - 1,  # mid-epoch semantics (see watchdog)
                 "alert", {"alert": fatal[0]["rule"]},
             )
             raise FatalAlertError(
@@ -693,7 +712,8 @@ def _train_impl(
             if k in info:
                 rescale_extra[k] = float(info[k])
         emergency_save(
-            guard["good_state"], epoch - 1,  # mid-epoch: redo this epoch
+            state if single_copy else guard["good_state"],
+            epoch - 1,  # mid-epoch: redo this epoch
             "rescale", {"rescale": rescale_extra},
         )
         line = {
@@ -727,7 +747,9 @@ def _train_impl(
     # extra on-device state reference; refreshed on log steps only). The
     # NaN guard rolls back to it, and the watchdog's emergency save uses
     # it — a wedged device can't be asked for the in-flight state.
-    guard = {"nan_steps": 0, "good_state": state, "epoch": start_epoch}
+    guard = {
+        "nan_steps": 0, "good_state": None if single_copy else state, "epoch": start_epoch,
+    }
     wd: Optional[StepWatchdog] = None
     if config.watchdog_timeout > 0:
 
@@ -745,6 +767,11 @@ def _train_impl(
                 pass
 
             def _save():
+                if single_copy:
+                    # the only copy is the step's own input or output, on a
+                    # device that no longer answers
+                    print("watchdog: no rollback copy of the state; nothing saved", flush=True)
+                    return
                 try:
                     # mid-epoch semantics, like the preemption path: the
                     # current epoch is NOT complete, resume redoes it
@@ -883,7 +910,7 @@ def _train_impl(
                             " — update skipped",
                             flush=True,
                         )
-                        if guard["nan_steps"] >= config.nan_guard_threshold:
+                        if single_copy or guard["nan_steps"] >= config.nan_guard_threshold:
                             raise FloatingPointError(
                                 f"aborting: {guard['nan_steps']} non-finite "
                                 f"loss steps (threshold "
@@ -902,6 +929,7 @@ def _train_impl(
                         return
                     # p["state"] is the state AS OF this logged step —
                     # `state` itself may already be one dispatch ahead
+                    # (None where no rollback copy is kept)
                     guard["good_state"] = p["state"]
                     bs = config.data.global_batch
                     losses.update(m["loss"], bs)
@@ -1113,7 +1141,8 @@ def _train_impl(
                             if i % config.log_every == 0 or i == steps_per_epoch - 1:
                                 pending = {
                                     "i": i, "gstep": gstep_host,
-                                    "metrics": metrics, "state": state,
+                                    "metrics": metrics,
+                                    "state": None if single_copy else state,
                                     "t_data": t_data,
                                 }
                     if pending is not None and not stop_now:

@@ -130,6 +130,14 @@ class MocoConfig:
     # (jax.checkpoint): trades ~30% more FLOPs for O(depth) less
     # activation HBM — for big models / big per-chip batches.
     remat: bool = False
+    # A decoder stack's cut of a deployment (moco_tpu/models/joyai.py):
+    # the layers of this pipeline stage (None = as published), the rows of
+    # the vocabulary held here (None = all), and this chip's share of each
+    # expert layer as (first_expert, experts_held) (() = every expert).
+    # Every width stays the arch's own.
+    lm_layers: Optional[int] = None
+    lm_vocab_rows: Optional[int] = None
+    expert_share: Tuple[int, ...] = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -151,6 +159,12 @@ class DataConfig:
     dataset: str = "synthetic"  # synthetic | cifar10 | imagefolder
     data_dir: Optional[str] = None
     image_size: int = 224
+    # What a row is: "images" (uint8 pixels, augmented on the device) or
+    # "tokens" (`seq_len` int32 ids and a length; the two views are two
+    # independent windows of one document, cut on the host, and no
+    # augmentation program runs).
+    input: str = "images"
+    seq_len: int = 0
     global_batch: int = 256
     aug_plus: bool = False  # v2 aug recipe (jitter+blur), main_moco.py:~L225-255
     # Geometric-only two-crop recipe (RRC + flip + normalize): the
@@ -647,5 +661,35 @@ PRESETS = {
             dataset="imagefolder", aug_plus=True, global_batch=1024, image_size=448
         ),
         parallel=ParallelConfig(num_model=8),
+    ),
+    # A language model's decoder stack as a momentum-contrast TEXT encoder
+    # (moco_tpu/models/joyai.py: JoyAI-LLM-Flash, latent attention + 256
+    # routed experts): the v2 path with the queue, the EMA key encoder and
+    # the fused InfoNCE, two independent 8192-token windows of one document
+    # as the views (Contriever's recipe, arXiv:2112.09118: T 0.05, m 0.9995,
+    # AdamW). As published it is 48 B parameters: a run states its cut of
+    # a deployment with moco.lm_layers / lm_vocab_rows / expert_share
+    # (benchmarks/configs/joyai_flash_ep16.json is one chip of 16).
+    "joyai_llm_flash": TrainConfig(
+        moco=MocoConfig(
+            arch="joyai_llm_flash", mlp=True, temperature=0.05, momentum=0.9995,
+            shuffle="none", remat=True,
+        ),
+        # one warm-up epoch of 25: over a corpus of 40 000 documents at 2 rows
+        # a step that is Contriever's 20 000 warm-up steps of 500 000. Without
+        # a warm-up AdamW moves every router logit by ~2 in its first 20 steps
+        optim=OptimConfig(
+            optimizer="adamw", lr=5e-5, weight_decay=0.01, epochs=25, cos=True, warmup_epochs=1
+        ),
+        data=DataConfig(dataset="synthetic", input="tokens", seq_len=8192, global_batch=2),
+    ),
+    # the same stack and path at a test's size, for the CPU
+    "joyai_tiny": TrainConfig(
+        moco=MocoConfig(
+            arch="joyai_tiny", mlp=True, temperature=0.05, momentum=0.9995,
+            num_negatives=256, shuffle="none", compute_dtype="float32",
+        ),
+        optim=OptimConfig(optimizer="adamw", lr=5e-5, weight_decay=0.01, epochs=1, cos=True),
+        data=DataConfig(dataset="synthetic", input="tokens", seq_len=64, global_batch=4),
     ),
 }
