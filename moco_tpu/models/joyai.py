@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from moco_tpu.ops.flash_attention import causal_flash_attention
+from moco_tpu.ops.flash_attention import CAUSAL_SAVED_NAMES, causal_flash_attention
 from moco_tpu.ops.grouped_matmul import grouped_matmul
 from moco_tpu.utils.platform import pallas_interpret
 
@@ -303,12 +303,24 @@ class Block(nn.Module):
         return x + layer(y.reshape(b * s, d), valid).reshape(b, s, d)
 
 
+# A block recomputed in the backward pass, which keeps nothing but the
+# causal kernel's two outputs. A short sequence takes the dense product,
+# names nothing, and is recomputed whole.
+RematBlock = nn.remat(
+    Block, policy=jax.checkpoint_policies.save_only_these_names(*CAUSAL_SAVED_NAMES)
+)
+
+
 class JoyAIBackbone(nn.Module):
     """Token ids -> pooled features (B, hidden) float32. `layers`,
     `vocab_rows` and the expert share are this chip's cut of a deployment
     (a pipeline stage's layers, a vocabulary slice, one chip's experts);
     every width is `cfg`'s. `remat`: recompute each block in the backward
-    pass instead of keeping its activations."""
+    pass instead of keeping its activations, with one exception: where the
+    attention product ran on the Pallas kernels its output and log-sum-exp
+    are kept (`RematBlock`), since they are all the backward kernels need
+    of the forward kernel and cost far less to hold (136 MB a layer at 2 x
+    8192 tokens) than to compute again (a third forward kernel a layer)."""
 
     cfg: StackSizes
     layers: int
@@ -327,7 +339,7 @@ class JoyAIBackbone(nn.Module):
             self.vocab_rows, self.cfg.hidden, dtype=self.dtype,
             embedding_init=nn.initializers.normal(0.02), name="embed",
         )(ids)
-        block_cls = nn.remat(Block) if self.remat else Block
+        block_cls = RematBlock if self.remat else Block
         for i in range(self.layers):
             x = block_cls(
                 cfg=self.cfg, moe=i >= 1, first_expert=self.first_expert,

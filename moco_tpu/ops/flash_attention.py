@@ -41,6 +41,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -459,6 +460,9 @@ CAUSAL_BLOCK = 512
 # below this the (S, S) scores of one head are a few VMEM tiles and XLA's
 # fused attention is as good: the kernels engage on length, never on a flag
 CAUSAL_MIN_SEQ = 1024
+# what `_causal_fwd` names for a remat policy to keep: the attention output
+# and its log-sum-exp, all the backward kernels need beyond q, k and v
+CAUSAL_SAVED_NAMES = ("causal_attention_out", "causal_attention_lse")
 
 
 def _causal_mask(s_q: int, s_k: int, kv_lens: jax.Array) -> jax.Array:
@@ -708,14 +712,22 @@ def _causal_flash(q, k, v, kv_lens, scale, block_q, block_k, interpret):
 
 
 def _causal_fwd(q, k, v, kv_lens, scale, block_q, block_k, interpret):
+    """The forward rule. The kernel's two outputs carry `CAUSAL_SAVED_NAMES`,
+    so a `jax.checkpoint` whose policy saves those names keeps them, and the
+    forward kernel is dead code in its recomputation: what the backward
+    kernels need arrives saved. Outside such a policy a name is an identity.
+    The log-sum-exp is named without its unit axis: (B*H, S, 1) float32 pads
+    the 1 to a tile's 128 lanes in HBM, 128 times the bytes of (B*H, S)."""
     out, lse = _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret)
+    out = checkpoint_name(out, CAUSAL_SAVED_NAMES[0])
+    lse = checkpoint_name(lse.reshape(lse.shape[:2]), CAUSAL_SAVED_NAMES[1])
     return out, (q, k, v, kv_lens, out, lse)
 
 
 def _causal_bwd(scale, block_q, block_k, interpret, res, g):
     q, k, v, kv_lens, out, lse = res
     dq, dk, dv = _causal_backward(
-        q, k, v, kv_lens, out, lse, g, scale, block_q, block_k, interpret
+        q, k, v, kv_lens, out, lse[..., None], g, scale, block_q, block_k, interpret
     )
     return dq, dk, dv, None
 

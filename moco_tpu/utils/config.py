@@ -128,7 +128,9 @@ class MocoConfig:
     fused_block_k: int = 0
     # Rematerialize the query-encoder forward in the backward pass
     # (jax.checkpoint): trades ~30% more FLOPs for O(depth) less
-    # activation HBM — for big models / big per-chip batches.
+    # activation HBM — for big models / big per-chip batches. A decoder
+    # stack recomputes block by block and spares the attention product
+    # (models/joyai.py: the kernels' output and log-sum-exp are kept).
     remat: bool = False
     # A decoder stack's cut of a deployment (moco_tpu/models/joyai.py):
     # the layers of this pipeline stage (None = as published), the rows of
@@ -670,6 +672,9 @@ PRESETS = {
     # AdamW). As published it is 48 B parameters: a run states its cut of
     # a deployment with moco.lm_layers / lm_vocab_rows / expert_share
     # (benchmarks/configs/joyai_flash_ep16.json is one chip of 16).
+    # remat: each decoder block is recomputed in the backward pass, all but
+    # its attention product: the kernels' output and log-sum-exp are kept
+    # (models/joyai.py RematBlock), 136 MB a layer at 2 x 8192 tokens.
     "joyai_llm_flash": TrainConfig(
         moco=MocoConfig(
             arch="joyai_llm_flash", mlp=True, temperature=0.05, momentum=0.9995,

@@ -6,7 +6,9 @@ and `make_train_step`."""
 
 import dataclasses
 import functools
+from unittest import mock
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,8 +17,13 @@ import pytest
 from benchmarks.reference import joyai_moco_v2 as ref
 from moco_tpu.core import build_encoder, create_state, make_train_step, place_state, sample_input
 from moco_tpu.data.pipeline import TwoCropPipeline
-from moco_tpu.models.joyai import ExpertLayer, _JOYAI_CONFIGS, create_joyai, routing_metrics
-from moco_tpu.ops.flash_attention import _causal_attn_reference, causal_flash_attention
+from moco_tpu.models import joyai
+from moco_tpu.models.joyai import (
+    Block, ExpertLayer, _JOYAI_CONFIGS, create_joyai, routing_metrics,
+)
+from moco_tpu.ops.flash_attention import (
+    CAUSAL_MIN_SEQ, _causal_attn_reference, causal_flash_attention,
+)
 from moco_tpu.ops.losses import cross_entropy, infonce_logits, l2_normalize
 from moco_tpu.parallel.mesh import create_mesh
 from moco_tpu.utils.config import PRESETS
@@ -192,6 +199,124 @@ def test_a_short_sequence_takes_the_dense_product_and_a_ragged_block_is_refused(
     assert out.shape == (1, 1, 64, 16)
     with pytest.raises(ValueError, match="multiple"):
         causal_flash_attention(q, q, q, jnp.asarray([64]), block_q=48, block_k=48, interpret=True)
+
+
+# ---- what a rematerialised block keeps of its attention (PR 30) ----------
+#
+# At CAUSAL_MIN_SEQ positions the tiny stack takes the kernels by length, as
+# the 8k cell does, and they run in interpret mode.
+
+KERNEL_LAYERS = 2  # one dense block, one expert block
+# how the stack treats a block in the backward pass -> (remat, the class it wraps a block in)
+REMAT_FORMS = {
+    "policy": (True, joyai.RematBlock),
+    "unpoliced": (True, nn.remat(Block)),
+    "no_remat": (False, None),
+}
+
+
+@functools.cache
+def _kernel_row_and_variables():
+    x = _rows(5, 1, CAUSAL_MIN_SEQ, [CAUSAL_MIN_SEQ])
+    encoder = create_joyai("joyai_tiny", layers=KERNEL_LAYERS)
+    return x, jax.jit(lambda r: encoder.init(r, x, train=False))(jax.random.PRNGKey(1))
+
+
+def _kernel_stack(form: str):
+    """(loss, params) of the tiny stack's first blocks on one full row of
+    CAUSAL_MIN_SEQ tokens; the parameters do not depend on the form."""
+    remat, remat_block = REMAT_FORMS[form]
+    x, variables = _kernel_row_and_variables()
+    encoder = create_joyai("joyai_tiny", layers=KERNEL_LAYERS, remat=remat)
+
+    def loss(params):
+        with mock.patch.object(joyai, "RematBlock", remat_block):
+            out, _ = encoder.apply(
+                {"params": params, "batch_stats": variables["batch_stats"]}, x, train=True,
+                mutable=["batch_stats"],
+            )
+        return jnp.sum(jnp.square(out))
+
+    return loss, variables["params"]
+
+
+def _jaxprs_in(eqn):
+    for value in eqn.params.values():
+        for v in value if isinstance(value, (list, tuple)) else (value,):
+            v = getattr(v, "jaxpr", v)  # a ClosedJaxpr's
+            if hasattr(v, "eqns"):
+                yield v
+
+
+def _kernel_calls(jaxpr, in_remat=False) -> list:
+    """(name of the `pallas_call`, whether it lies inside a `remat2`
+    equation) for every kernel call of a jaxpr, at any depth."""
+    calls = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls.append((eqn.params["name"], in_remat))
+            continue
+        for sub in _jaxprs_in(eqn):
+            calls += _kernel_calls(sub, in_remat or eqn.primitive.name == "remat2")
+    return calls
+
+
+@pytest.mark.parametrize("form,recomputed", [("policy", 0), ("unpoliced", 1)])
+def test_the_policy_takes_the_forward_kernel_out_of_the_backward_pass(form, recomputed):
+    """Inside the gradient's `remat2` equations: both backward kernels once
+    a layer, and the forward kernel once a layer only without the policy
+    (fails if a later change drops a name or the policy)."""
+    loss, params = _kernel_stack(form)
+    calls = _kernel_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    assert sorted(calls) == sorted(
+        [("causal_attention_fwd", False)] * KERNEL_LAYERS
+        + [("causal_attention_fwd", True)] * (KERNEL_LAYERS * recomputed)
+        + [("causal_attention_dq", True), ("causal_attention_dkv", True)] * KERNEL_LAYERS
+    )
+
+
+@functools.cache
+def _loss_and_grads(form: str):
+    loss, params = _kernel_stack(form)
+    return jax.jit(jax.value_and_grad(loss))(params)
+
+
+@pytest.mark.parametrize("form", ["unpoliced", "no_remat"])
+def test_the_policy_changes_no_bit_of_the_loss_or_a_gradient(form):
+    loss_p, grads_p = _loss_and_grads("policy")
+    loss_o, grads_o = _loss_and_grads(form)
+    assert np.asarray(loss_p) == np.asarray(loss_o)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads_p), jax.tree.leaves(grads_o)):
+        assert np.any(np.asarray(a) != 0), path
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=str(path))
+
+
+@pytest.mark.parametrize("moe", [False, True], ids=["dense_block", "expert_block"])
+def test_a_block_keeps_the_attention_output_and_a_lane_dense_log_sum_exp(moe):
+    """What one rematerialised block saves beside its arguments: `out` as
+    (B, H, S, Dv) and the log-sum-exp as (B*H, S), with no unit axis for
+    HBM to pad to 128 lanes, and nothing else of the attention's size."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    b, s = 2, CAUSAL_MIN_SEQ
+    block = joyai.RematBlock(
+        cfg=TINY, moe=moe, first_expert=0, experts_held=TINY.experts, train=True
+    )
+    x = jax.random.normal(jax.random.PRNGKey(0), (b, s, TINY.hidden))
+    lengths = jnp.full((b,), s, jnp.int32)
+    variables = jax.eval_shape(lambda: block.init(jax.random.PRNGKey(1), x, lengths))
+
+    def loss(variables, x):
+        y, _ = block.apply(variables, x, lengths, mutable=["batch_stats"])
+        return jnp.sum(y)
+
+    kept = [
+        (aval.shape, why) for aval, why in saved_residuals(loss, variables, x)
+        if "from the argument" not in why and aval.size >= b * TINY.heads * s
+    ]
+    assert sorted(shape for shape, _ in kept) == [
+        (b, TINY.heads, s, TINY.v_head), (b * TINY.heads, s)
+    ], kept
 
 
 class _DocIdDataset:

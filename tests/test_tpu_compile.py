@@ -6,6 +6,7 @@ here is a result or a time. One file and a fixture, so that only the
 worker that is handed this file loads the TPU's library."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -63,3 +64,41 @@ def test_grouped_matmul_compiles_at_the_worst_case_buffer(one_chip, monkeypatch,
         return jax.value_and_grad(loss, (0, 1))(x, w)
 
     assert "tpu_custom_call" in jax.jit(f).lower(x, w, sizes).compile().as_text()
+
+
+def test_a_remat_block_keeps_what_its_attention_backward_needs(one_chip, monkeypatch):
+    """Value and gradient of ONE rematerialised block (the dense one) at the
+    published widths, 2 rows x 8192 positions, bfloat16: it compiles; beside
+    its arguments it saves the attention output and the log-sum-exp with no
+    unit axis (136 MB; (64, 8192, 1) float32 pads to 128 lanes: 402 MB);
+    and the compiled module holds one forward kernel, the forward pass's,
+    where the unpoliced block holds a second for the backward pass."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from moco_tpu.models import joyai
+
+    monkeypatch.setattr(joyai, "pallas_interpret", lambda: False)  # this process's backend is the CPU
+    block = joyai.RematBlock(
+        cfg=joyai._JOYAI_CONFIGS["joyai_llm_flash"], moe=False, first_expert=0, experts_held=16,
+        train=True, dtype=jnp.bfloat16,
+    )
+    x = _shape(one_chip, (2, 8192, 2048), jnp.bfloat16)
+    lens = _shape(one_chip, (2,), jnp.int32)
+    params = jax.tree.map(
+        lambda a: _shape(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0), x, lens))["params"],
+    )
+
+    def loss(params, x, lens):
+        return jnp.sum(block.apply({"params": params}, x, lens).astype(jnp.float32))
+
+    kept = {
+        aval.str_short(): nbytes
+        for aval, why in saved_residuals(loss, params, x, lens)
+        if "from the argument" not in why
+        and (nbytes := aval.size * aval.dtype.itemsize) >= 2**20  # not RoPE's constants
+    }
+    assert kept == {"bfloat16[2,32,8192,128]": 2**27, "float32[64,8192]": 2**21}
+    text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(params, x, lens).compile().as_text()
+    for name in ("causal_attention_fwd", "causal_attention_dq", "causal_attention_dkv"):
+        assert len(re.findall(rf"custom-call\(.*{name}", text)) == 1, name
