@@ -555,7 +555,7 @@ def make_train_step(
     # GroupPlan over the encoder leaves (backbone groups in schedule
     # order, then the projection head) + a separate single-group plan
     # for the predictor; the analytic HBM peak for BOTH schedules so
-    # bench/harness legs can A/B without device memory_stats.
+    # tests can compare them without device memory_stats.
     enc_group_plan = None
     pred_bucket_plan = None
     g_names: tuple = ()
@@ -1464,7 +1464,7 @@ class Zero23TrainStep:
       with donation support).
 
     Calling the object runs both inline — the un-hoisted schedule —
-    so non-pipelined callers (tests, bench legs) keep the single-callable
+    so non-pipelined callers (tests, smokes) keep the single-callable
     contract of the classic step.
 
     `layer_granular` marks the per-group schedule
@@ -1473,8 +1473,8 @@ class Zero23TrainStep:
     `hbm_model_peak_bytes` is the ANALYTIC per-chip model-memory
     high-water mark (persistent shards + the schedule's transient full
     params: whole trainable + key tree for the classic gather, the
-    largest adjacent group pair for the layer schedule) — the gauge the
-    CPU-smoke bench legs track where `device_memory_stats` is None.
+    largest adjacent group pair for the layer schedule) — the gauge
+    tests/test_zero.py compares where `device_memory_stats` is None.
     """
 
     def __init__(

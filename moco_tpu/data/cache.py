@@ -2,8 +2,8 @@
 
 The reference hides JPEG-decode cost behind 32 DataLoader worker
 processes per GPU (`main_moco.py:~L256` num_workers); on TPU hosts with
-few cores the decode is the input-pipeline bound (see PROFILE.md /
-bench.py's with-data rate). This cache removes the per-epoch decode
+few cores the decode is the input-pipeline bound (see PROFILE.md).
+This cache removes the per-epoch decode
 entirely: every image is decoded ONCE at full original geometry and its
 raw RGB pixels appended to one packed file; epochs then read crops
 straight out of an `np.memmap` — no codec work, no per-image files, and

@@ -141,8 +141,8 @@ class TransferStats:
             }
 
     def wire_rate_bytes_per_sec(self) -> Optional[float]:
-        """Cumulative wire bandwidth (the `wire-rate` leg of bench.py's
-        overlap_efficiency denominator)."""
+        """Cumulative wire bandwidth (the `wire-rate` leg of
+        `scripts/profile_input.py`'s overlap_efficiency denominator)."""
         with self._lock:
             if self.total_seconds <= 0:
                 return None

@@ -16,8 +16,7 @@ append, collected on the batcher thread (never a client thread); the
 expensive parts — JSON encoding, span emission into the Perfetto
 stream, flight-recorder bookkeeping — all happen off-path on the
 server's metrics-flusher thread. With tracing off no trace object
-exists and every hook is a single `is None` check (the bench serving
-leg measures the residual as `serve/trace_overhead_pct`).
+exists and every hook is a single `is None` check.
 
 Request ids carry replica identity (`r<replica>-<seq>`), so a merged
 multi-replica Perfetto timeline and the flight-recorder dumps stay

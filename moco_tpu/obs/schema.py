@@ -219,13 +219,11 @@ FIELD_VALIDATORS = {
     # exposes with real cumulative buckets, the p99 exemplar linking the
     # latency gauges to the offending request id (a STRING — exempted
     # from the numeric serve/ prefix family below), its latency, the
-    # declared SLO objective, and the measured tracing overhead the
-    # bench serving leg reports
+    # declared SLO objective
     "serve/latency_hist": _latency_hist,
     "serve/p99_exemplar": _str_or_null,
     "serve/p99_exemplar_ms": _nonneg_or_null,
     "serve/slo_objective": lambda v: _num(v) and 0.0 < v < 1.0,
-    "serve/trace_overhead_pct": _num_or_null,
     # served-model identity (obs/quality.py): the checkpoint step the
     # live encoder came from (null when unknown — e.g. a hand-built
     # engine), its params content digest (a STRING, exempted from the

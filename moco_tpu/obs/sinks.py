@@ -349,7 +349,7 @@ class PrometheusSink(Sink):
         # join-on-close (mocolint JX011): shutdown() unblocks
         # serve_forever, but until the thread actually exits it pins the
         # bound port and the handler's references — a restart-in-process
-        # (tests, chained bench legs) would hit EADDRINUSE
+        # (tests, chained smoke legs) would hit EADDRINUSE
         self._thread.join(timeout=5.0)
 
 

@@ -43,8 +43,8 @@ Request tracing (obs/reqtrace.py): a future may carry a
 and the shared flush stages (`batch_assemble` / `engine_execute` /
 `index_query` / `scatter`) onto it — perf_counter pairs only, the
 expensive rendering happens off-path. With `reqtrace=True` the batcher
-allocates traces itself for trace-less submits (the bench serving leg's
-A/B); with tracing off the per-request cost is a `None` check.
+allocates traces itself for trace-less submits (standalone use);
+with tracing off the per-request cost is a `None` check.
 """
 
 from __future__ import annotations
@@ -338,7 +338,7 @@ class ContinuousBatcher:
             self._pass_modes = False
             self._pass_stages = False
         # reqtrace=True: allocate a RequestTrace for trace-less submits
-        # (standalone batcher use — bench A/B, tests); the server passes
+        # (standalone batcher use — tests); the server passes
         # traces explicitly so the ingress stage is already stamped
         self._ids = RequestIdAllocator(replica_index) if reqtrace else None
         self.max_batch = int(max_batch)

@@ -139,7 +139,7 @@ def load_serving_encoder(
     encoder is rebuilt in bf16 regardless of the training compute dtype
     (the serving default; params stay f32); CPU keeps f32 — XLA:CPU
     *emulates* bf16 at a measured ~50x slowdown, which would poison the
-    CPU smoke and the bench serving leg. ZeRO-2/3 checkpoints unshard
+    CPU smokes. ZeRO-2/3 checkpoints unshard
     through `lincls.restore_pretrain_state`, the shared eval-side
     path."""
     from moco_tpu.core.moco import build_encoder
@@ -431,8 +431,7 @@ class InferenceEngine:
         sliced away before anything downstream sees them. `stages` (the
         request-trace contract) accumulates per-stage seconds; timing a
         stage forces device readiness inside its window, so the split is
-        honest under async dispatch — that sync is the tracing cost the
-        bench reports as `serve/trace_overhead_pct`."""
+        honest under async dispatch — that sync is what tracing costs."""
         outs, executed = [], []
         for padded, n, bucket in self._padded_chunks(images):
             with obs_span("serve_embed", bucket=bucket, valid=n):

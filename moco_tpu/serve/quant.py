@@ -39,8 +39,8 @@ f32, so products and sums are exact integers (f32 is exact through
 are faithfully testable on the CPU smoke even though the arithmetic
 speedup only exists on a chip. The w8a8-vs-w8 queries/s claim is
 therefore an accelerator claim; the CPU smoke gates the cosine floor
-(`perf_ledger.py` QUANT_COSINE_FLOOR) and records `int8_kernels` so a
-ledger entry says which arithmetic actually ran.
+(`scripts/serve_smoke.py` QUANT_COSINE_FLOOR), and `default_int8_compute`
+says which arithmetic a backend runs.
 
 Calibration persists as a small JSON artifact next to the checkpoint
 (`quant_calib.json`: version, image size, sample size, per-path amax)

@@ -197,8 +197,7 @@ FAULT_SITES = {
 # runtime contract-coverage gates (analysis/contracts.py recorder)
 #
 # The serve/* schema validators the serving stack itself must exercise
-# in a full smoke (everything explicit under serve/ except the
-# bench-only trace-overhead gauge, which only bench.py emits).
+# in a full smoke (everything explicit under serve/).
 
 SERVE_GATED_VALIDATORS = (
     "serve/ingested_rows",

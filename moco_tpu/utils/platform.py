@@ -37,8 +37,8 @@ def _pinned_platform(env=None) -> str:
 def cpu_pinned(env=None) -> bool:
     """Whether `env` (default: this process's environment) pins jax to
     the CPU — the one spelling of that question (the compile-cache rule
-    below, bench.py's smoke mode, the replica supervisor's placement
-    check on its children's environments)."""
+    below, the replica supervisor's placement check on its children's
+    environments)."""
     return _pinned_platform(env).startswith("cpu")
 
 
@@ -84,7 +84,7 @@ def enable_persistent_compilation_cache() -> str | None:
 
 def log_devices(who: str, file=None) -> dict:
     """Print the platform, device kind and device count jax resolved —
-    the first line of the trainer, a replica, bench.py and chip_smoke —
+    the first line of the trainer, a replica and chip_smoke —
     and return them as `{"platform", "device_kind", "count"}`.
     Initialises the backend."""
     import jax
@@ -111,7 +111,8 @@ def pallas_interpret() -> bool:
     CPU test suite's mode, where the train-step and attention kernels
     interpret and the fused IVF scan takes its lax variant. Decided once
     per process, from the resolved backend, and logged to stderr (stdout
-    belongs to the entry point: bench.py's is one JSON record), so a run
+    belongs to the entry point: `benchmarks/run.py`'s last line is its
+    JSON result), so a run
     that lost its chip shows the switch instead of silently interpreting
     every kernel."""
     import jax

@@ -8,7 +8,7 @@ measures where each millisecond goes, per batch, for every input mode:
   or cache mmap) -> crop+resize (PIL or C++ resize_region) -> assemble
   [-> host-to-device transfer, when an accelerator is attached]
 
-Modes (the same ladder bench.py / PROFILE.md use):
+Modes (the ladder of PROFILE.md):
   jpeg_pil     — ImageFolderDataset: PIL decode + PIL crop/resize
   jpeg_native  — native/loader.cc decode pool + C++ crops
   cache_pil    — PackedRGBCacheDataset(use_native=False): mmap + PIL
@@ -52,6 +52,33 @@ pin_platform_from_env()
 import numpy as np
 
 ART_PATH = "artifacts/input_profile.json"
+
+
+def _ensure_jpeg_folder(root: str, n: int, size: int, classes: int = 8) -> str:
+    """Synthetic JPEG ImageFolder for the input profile (no datasets on
+    disk in this environment). Deterministic, built once, reused."""
+    from PIL import Image
+
+    stamp = os.path.join(root, f".complete_{n}_{size}")
+    if os.path.exists(stamp):
+        return root
+    rng = np.random.default_rng(0)
+    for c in range(classes):
+        os.makedirs(os.path.join(root, f"class_{c}"), exist_ok=True)
+    for i in range(n):
+        c = i % classes
+        # low-frequency field + noise ≈ natural-image JPEG work profile
+        coarse = rng.uniform(0, 255, (8, 8, 3))
+        img = np.asarray(
+            Image.fromarray(coarse.astype(np.uint8)).resize((size, size), Image.BILINEAR),
+            np.float32,
+        )
+        img += rng.normal(0, 12, img.shape)
+        Image.fromarray(np.clip(img, 0, 255).astype(np.uint8)).save(
+            os.path.join(root, f"class_{c}", f"img_{i:05d}.jpg"), quality=90
+        )
+    open(stamp, "w").close()
+    return root
 
 
 def _sample_boxes(dims: np.ndarray, n_crops: int, seed: int, epoch: int, step: int,
@@ -153,9 +180,6 @@ def main() -> None:
         "overlap_efficiency",
     )
     args = ap.parse_args()
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    from bench import _ensure_jpeg_folder
 
     from moco_tpu.data.cache import PackedRGBCacheDataset, build_rgb_cache
     from moco_tpu.data.datasets import ImageFolderDataset
@@ -268,8 +292,8 @@ def profile_overlap(folder: str, cache_dir: str, batch: int, out_size: int,
     The geometric-only recipe (crops_only) stands in for the augment:
     on a 1-core CPU host the full jitter/blur recipe costs ~80 s/batch
     of pure compute, which would bury the input path this script
-    profiles (on a TPU the augment is microseconds — bench.py's
-    overlapped with-data leg is the on-hardware measurement)."""
+    profiles (on a TPU the augmentation is a device program of its own:
+    `benchmarks/run.py --workload train_r50_v2` measures it there)."""
     import jax
 
     from moco_tpu.data.pipeline import TwoCropPipeline
@@ -423,8 +447,8 @@ def write_section(profile_md: str, payload: dict) -> None:
             "consumer (augment compute shares the single core) is the "
             "binding stage, and >1 means the serially-measured consume "
             "bound (transfer then augment, no overlap) understates the "
-            "pipelined bound; bench.py's overlapped with-data leg is "
-            "the on-hardware measurement",
+            "pipelined bound; the chip's number is the benchmark's "
+            "(`benchmarks/run.py`, PERF.md)",
         ]
     from moco_tpu.utils.report import replace_marker_block
 

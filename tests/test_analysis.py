@@ -114,7 +114,6 @@ def test_self_check_repo_is_lint_clean():
         os.path.join(REPO, "scripts"),
         os.path.join(REPO, "train.py"),
         os.path.join(REPO, "eval_lincls.py"),
-        os.path.join(REPO, "bench.py"),
     ]
     bad = [f for f in analyze_paths(paths) if not f.suppressed]
     assert bad == [], "\n".join(f.render() for f in bad)
@@ -168,7 +167,6 @@ def test_self_check_tests_tree_is_baseline_clean():
         os.path.join(REPO, "tests"),
         os.path.join(REPO, "train.py"),
         os.path.join(REPO, "eval_lincls.py"),
-        os.path.join(REPO, "bench.py"),
         os.path.join(REPO, "convert_pretrain.py"),
         os.path.join(REPO, "import_pretrain.py"),
     ]

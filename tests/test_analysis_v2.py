@@ -282,14 +282,14 @@ def test_checked_in_baseline_matches_tree():
         for d in ("moco_tpu", "scripts", "tests")
     ] + [
         os.path.join(REPO, f)
-        for f in ("train.py", "eval_lincls.py", "bench.py",
+        for f in ("train.py", "eval_lincls.py",
                   "convert_pretrain.py", "import_pretrain.py")
     ]
     findings = analyze_paths(paths)
     current = {f.fingerprint() for f in findings if not f.suppressed}
     assert current == baseline, (
         "baseline drift — rerun: python -m moco_tpu.analysis moco_tpu/ "
-        "scripts/ tests/ train.py eval_lincls.py bench.py "
+        "scripts/ tests/ train.py eval_lincls.py "
         "convert_pretrain.py import_pretrain.py --update-baseline"
     )
 
@@ -442,7 +442,7 @@ def test_sanitizer_catches_divergence_on_fake_8_device_mesh(tmp_path):
     one of them must be caught with a per-site diff, and the clean
     control must pass. Reuses scripts/sanitizer_smoke.py so the CI leg
     and the test cannot drift apart."""
-    from conftest import load_script
+    from tests.conftest import load_script
 
     smoke = load_script("sanitizer_smoke.py")
     report = smoke.run_smoke(str(tmp_path))

@@ -31,7 +31,7 @@ from moco_tpu.utils.config import (
     config_to_dict,
 )
 
-from conftest import load_script
+from tests.conftest import load_script
 
 
 def _beat(workdir, process, t):

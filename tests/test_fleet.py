@@ -506,7 +506,7 @@ def _write_span_stream(path, process, names, t0_us=0.0):
 
 
 def test_trace_merge_one_track_per_host_with_clock_offsets(tmp_path):
-    from conftest import load_script
+    from tests.conftest import load_script
 
     _write_span_stream(tmp_path / "trace_events.jsonl", 0, ["epoch", "step"])
     _write_span_stream(tmp_path / "trace_events.p1.jsonl", 1, ["epoch", "step"])
@@ -535,7 +535,7 @@ def test_trace_merge_one_track_per_host_with_clock_offsets(tmp_path):
 
 
 def test_trace_merge_survives_missing_heartbeat(tmp_path):
-    from conftest import load_script
+    from tests.conftest import load_script
 
     _write_span_stream(tmp_path / "trace_events.jsonl", 0, ["step"])
     tm = load_script("trace_merge.py")
@@ -597,7 +597,7 @@ def _train_line(step, **extra):
 
 
 def test_obs_report_merges_per_process_metrics(tmp_path):
-    from conftest import load_script
+    from tests.conftest import load_script
 
     w0 = sinks.JsonlSink(str(tmp_path))
     w0.write(1, _train_line(1, **{"straggler_skew": 0.1, "fleet_hosts": 2,

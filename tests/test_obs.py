@@ -735,7 +735,7 @@ def test_schema_validates_real_writer_output(tmp_path):
 
 
 def test_obs_report_renders_from_writer_output(tmp_path):
-    from conftest import load_script
+    from tests.conftest import load_script
 
     w = sinks.JsonlSink(str(tmp_path))
     for s in range(1, 4):
@@ -769,7 +769,7 @@ def test_obs_report_renders_from_writer_output(tmp_path):
 
 
 def test_obs_report_empty_file(tmp_path):
-    from conftest import load_script
+    from tests.conftest import load_script
 
     path = tmp_path / "metrics.jsonl"
     path.write_text("")

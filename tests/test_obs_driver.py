@@ -11,7 +11,7 @@ import os
 
 import pytest
 
-from conftest import load_script
+from tests.conftest import load_script
 
 
 @pytest.fixture(scope="module")

@@ -82,8 +82,8 @@ def test_cache_decision_initialises_no_backend():
 
 
 def test_diagnostics_stay_off_stdout(capsys, monkeypatch):
-    """An entry point owns its stdout (bench.py's is one JSON record,
-    chip_smoke's last line is the result): what the library has to say
+    """An entry point owns its stdout (the last line of `chip_smoke.py`
+    and of `benchmarks/run.py` is the result): what the library has to say
     about the platform goes to stderr."""
     from moco_tpu.data import native_loader
 
@@ -100,32 +100,6 @@ def test_diagnostics_stay_off_stdout(capsys, monkeypatch):
     assert "pallas kernels: not compiled on backend 'cpu'" in err
     assert "native loader unavailable" in err and "no compiler here" in err
     assert "test: platform=cpu" in err
-
-
-@pytest.mark.slow  # 40 s; tier-1 has no room for it (test_diagnostics_stay_off_stdout is its tier-1 guard)
-def test_bench_stdout_is_one_json_record(tmp_path):
-    """ci.yml's perf gate pipes `JAX_PLATFORMS=cpu python bench.py` into
-    scripts/perf_ledger.py: stdout must be the record and nothing else.
-    The headline and the ann_ab leg run (the leg that reaches
-    `pallas_interpret()` through serve/index.py); the serving, ZeRO, data
-    and obs legs are skipped for wall time."""
-    from conftest import load_script
-
-    env = dict(
-        os.environ, JAX_PLATFORMS="cpu", BENCH_SKIP_DATA="1", BENCH_SKIP_OBS_OVERHEAD="1",
-        BENCH_SKIP_ZERO="1", BENCH_SKIP_SERVE="1",
-    )
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py")],
-        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=600,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert len(proc.stdout.splitlines()) == 1, proc.stdout[:2000]
-    record = tmp_path / "bench_out.json"
-    record.write_text(proc.stdout)
-    rec = load_script("perf_ledger.py").load_bench_record(str(record))
-    assert rec["legs"]["ann_ab"]["ran"], rec["legs"]["ann_ab"]
-    assert "bench: platform=cpu" in proc.stderr
 
 
 def test_chip_smoke_fails_without_a_tpu(tmp_path):
@@ -161,7 +135,7 @@ def test_bn_compile_repro_grid_order():
     """The bisect harness must order each depth's cells baseline-first,
     shipped-slice-suspects last (a run cut short forfeits the least
     information — scripts/bn_compile_repro.py docstring)."""
-    from conftest import load_script
+    from tests.conftest import load_script
 
     mod = load_script("bn_compile_repro.py")
     cells = mod.depth_cells([0, 32, 8], ["mask", "fwd", "barrier", "slice"])
@@ -173,3 +147,29 @@ def test_bn_compile_repro_grid_order():
     }
     # no slice: no baseline cell, nothing crashes
     assert mod.depth_cells([0, 32], ["mask"]) == [("mask", 32)]
+
+
+def test_profile_input_jpeg_folder_same_bytes_and_built_once(tmp_path):
+    """scripts/profile_input.py makes its ImageFolder from a fixed seed:
+    the same arguments give the same bytes, and a second call on a
+    finished folder writes nothing."""
+    from tests.conftest import load_script
+
+    mod = load_script("profile_input.py")
+
+    def snapshot(root):
+        found = {}
+        for base, _, names in os.walk(root):
+            for n in names:
+                path = os.path.join(base, n)
+                with open(path, "rb") as f:
+                    found[os.path.relpath(path, root)] = (f.read(), os.stat(path).st_mtime_ns)
+        return found
+
+    a = snapshot(mod._ensure_jpeg_folder(str(tmp_path / "a"), 10, 32))
+    b = snapshot(mod._ensure_jpeg_folder(str(tmp_path / "b"), 10, 32))
+    assert sorted(a) == sorted(b) and len(a) == 11  # ten images over eight classes + the stamp
+    assert {k: v[0] for k, v in a.items()} == {k: v[0] for k, v in b.items()}
+    assert a["class_1/img_00009.jpg"][0][:2] == b"\xff\xd8"
+    assert mod._ensure_jpeg_folder(str(tmp_path / "a"), 10, 32) == str(tmp_path / "a")
+    assert snapshot(str(tmp_path / "a")) == a

@@ -615,43 +615,3 @@ def test_server_end_to_end(toy_engine, tmp_path):
     assert not errors, errors
     lines = schema.read_metrics(str(tmp_path / "metrics.jsonl"))
     assert any("serve/qps" in r for r in lines)
-
-
-# -- perf ledger: the serving series gates like the headline ------------
-
-
-def test_perf_ledger_gates_serving_series(tmp_path):
-    pl = load_script("perf_ledger.py")
-    ledger = str(tmp_path / "ledger.json")
-    base_rec = {
-        "metric": "moco_v1_r18_cpu_smoke_imgs_per_sec",
-        "value": 10.0,
-        "serving": {
-            "metric": "moco_serve_resnet18_cpu_smoke_queries_per_sec",
-            "value": 8.0,
-        },
-    }
-    cand = str(tmp_path / "bench.json")
-    with open(cand, "w") as f:
-        json.dump(base_rec, f)
-    assert pl.check(ledger, cand) == 0  # empty ledger: nothing comparable
-    pl.append(ledger, cand, "t01")
-    entry = pl.load_ledger(ledger)["entries"][0]
-    assert entry["serving"]["value"] == 8.0  # serving rides the entry
-    # healthy: same numbers pass
-    assert pl.check(ledger, cand) == 0
-    # training headline fine, serving regressed beyond the cpu threshold
-    bad = dict(base_rec, serving={**base_rec["serving"], "value": 2.0})
-    with open(cand, "w") as f:
-        json.dump(bad, f)
-    assert pl.check(ledger, cand) == 1
-    # serving fine, headline regressed -> still gated
-    bad2 = dict(base_rec, value=1.0)
-    with open(cand, "w") as f:
-        json.dump(bad2, f)
-    assert pl.check(ledger, cand) == 1
-    # a record with no serving block (old bench) still checks cleanly
-    legacy = {"metric": base_rec["metric"], "value": 9.9}
-    with open(cand, "w") as f:
-        json.dump(legacy, f)
-    assert pl.check(ledger, cand) == 0

@@ -2,10 +2,8 @@
 quantizer, recall properties vs the exact oracle across fill levels /
 shard widths / nprobe settings, freeze discipline per (m, k, nprobe),
 incremental FIFO maintenance, engine int8 PTQ, batcher mode routing,
-server wiring (mode knob, recall gauge, /ingest), schema validators,
-and the perf-ledger ann series gate."""
+server wiring (mode knob, recall gauge, /ingest), schema validators."""
 
-import json
 import time
 import urllib.request
 
@@ -449,45 +447,6 @@ def test_serve_ingest_fresh_rows_diff():
     np.testing.assert_array_equal(si.fresh_rows(q, 2, 5)[:, 0], [2, 3, 4])
     np.testing.assert_array_equal(si.fresh_rows(q, 6, 2)[:, 0], [6, 7, 0, 1])
     assert si.fresh_rows(q, 4, 4).shape[0] == 0
-
-
-# -- perf ledger: the ann series gates like the others --------------------
-
-
-def test_perf_ledger_gates_ann_series(tmp_path):
-    pl = load_script("perf_ledger.py")
-    ledger = str(tmp_path / "ledger.json")
-    rec = {
-        "metric": "moco_v1_r18_cpu_smoke_imgs_per_sec",
-        "value": 10.0,
-        "ann_ab": {
-            "metric": "moco_ann_ivf_cpu_smoke_queries_per_sec",
-            "value": 300.0,
-            "exact_qps": 40.0,
-            "speedup": 7.5,
-            "recall_at_10": 0.99,
-        },
-    }
-    cand = str(tmp_path / "bench.json")
-
-    def write(r):
-        with open(cand, "w") as f:
-            json.dump(r, f)
-
-    write(rec)
-    assert pl.check(ledger, cand) == 0  # empty ledger: nothing comparable
-    pl.append(ledger, cand, "t01")
-    assert pl.load_ledger(ledger)["entries"][0]["ann_ab"]["value"] == 300.0
-    assert pl.check(ledger, cand) == 0  # healthy
-    # qps regressed beyond the cpu-smoke threshold
-    write(dict(rec, ann_ab={**rec["ann_ab"], "value": 100.0}))
-    assert pl.check(ledger, cand) == 1
-    # qps fine but recall below the floor: a fast-and-wrong index fails
-    write(dict(rec, ann_ab={**rec["ann_ab"], "recall_at_10": 0.80}))
-    assert pl.check(ledger, cand) == 1
-    # old records without an ann block still check cleanly
-    write({"metric": rec["metric"], "value": 10.0})
-    assert pl.check(ledger, cand) == 0
 
 
 # -- fused gather-scan tier (ISSUE 11) -----------------------------------

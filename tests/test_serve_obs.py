@@ -645,62 +645,3 @@ def test_server_chaos_flight_capture(tmp_path):
     assert {"request", "req/engine_execute", "req/queue_wait"} <= names
     anchor = json.load(open(os.path.join(wd, "heartbeat.s0.json")))
     assert anchor["role"] == "serve" and "trace_wall_t0" in anchor
-
-
-# -- perf ledger: the trace-overhead cap --------------------------------
-
-
-def test_perf_ledger_gates_trace_overhead(tmp_path):
-    pl = load_script("perf_ledger.py")
-    ledger = str(tmp_path / "ledger.json")
-    rec = {
-        "metric": "moco_v1_r18_cpu_smoke_imgs_per_sec",
-        "value": 10.0,
-        "serving": {
-            "metric": "moco_serve_resnet18_cpu_smoke_queries_per_sec",
-            "value": 8.0,
-            "trace_overhead_pct": 3.0,
-        },
-    }
-    cand = str(tmp_path / "bench.json")
-    with open(cand, "w") as f:
-        json.dump(rec, f)
-    pl.append(ledger, cand, "t01")
-    assert pl.check(ledger, cand) == 0  # under the cap
-    bad = dict(rec, serving=dict(rec["serving"], trace_overhead_pct=60.0))
-    with open(cand, "w") as f:
-        json.dump(bad, f)
-    assert pl.check(ledger, cand) == 1  # cpu cap is 25%
-    # an accelerator serving record gates at the tight 5%
-    accel = {
-        "metric": "moco_v1_r50_imgs_per_sec_per_chip",
-        "value": 100.0,
-        "serving": {
-            "metric": "moco_serve_resnet50_queries_per_sec_per_chip",
-            "value": 50.0,
-            "trace_overhead_pct": 7.0,
-        },
-    }
-    with open(cand, "w") as f:
-        json.dump(accel, f)
-    assert pl.check(ledger, cand) == 1
-    # a record with no overhead field (old bench) still checks cleanly
-    legacy = dict(rec, serving={k: v for k, v in rec["serving"].items()
-                                if k != "trace_overhead_pct"})
-    with open(cand, "w") as f:
-        json.dump(legacy, f)
-    assert pl.check(ledger, cand) == 0
-    # the router-side distributed-tracing A/B (ISSUE 18) gates under the
-    # same caps as the replica-side series
-    routed = dict(rec, serving=dict(
-        rec["serving"], router_trace_overhead_pct=3.0
-    ))
-    with open(cand, "w") as f:
-        json.dump(routed, f)
-    assert pl.check(ledger, cand) == 0
-    routed_bad = dict(rec, serving=dict(
-        rec["serving"], router_trace_overhead_pct=60.0
-    ))
-    with open(cand, "w") as f:
-        json.dump(routed_bad, f)
-    assert pl.check(ledger, cand) == 1
