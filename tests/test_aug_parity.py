@@ -115,6 +115,31 @@ class TestRRCDistribution:
 # --------------------------------------------------------------- jitter
 
 
+def _jitter_all_candidates(rng, images, brightness, contrast, saturation, hue, apply_prob):
+    """`color_jitter` as it was before it evaluated each image's order
+    once: in each of four slots all four adjustments on the whole batch,
+    one selected per image. Kept as the count test's yardstick."""
+    b = images.shape[0]
+    k_order, k_apply, kb, kc, ks, kh = jax.random.split(rng, 6)
+    fb = jax.random.uniform(kb, (b, 1, 1, 1), minval=max(0.0, 1 - brightness), maxval=1 + brightness)
+    fc = jax.random.uniform(kc, (b, 1, 1, 1), minval=max(0.0, 1 - contrast), maxval=1 + contrast)
+    fs = jax.random.uniform(ks, (b, 1, 1, 1), minval=max(0.0, 1 - saturation), maxval=1 + saturation)
+    fh = jax.random.uniform(kh, (b, 1, 1, 1), minval=-hue, maxval=hue)
+    order = jnp.argsort(jax.random.uniform(k_order, (b, 4)), axis=1)
+    out = images
+    for slot in range(4):
+        idx = order[:, slot][:, None, None, None]
+        xb = adjust_brightness(out, fb)
+        xc = adjust_contrast(out, fc)
+        xs = adjust_saturation(out, fs)
+        xh = adjust_hue(out, fh) if hue > 0 else out
+        out = jnp.where(idx == 0, xb, jnp.where(idx == 1, xc, jnp.where(idx == 2, xs, xh)))
+    if apply_prob < 1.0:
+        keep = jax.random.bernoulli(k_apply, apply_prob, (b, 1, 1, 1))
+        out = jnp.where(keep, out, images)
+    return out
+
+
 class TestJitterPerImageOrder:
     def test_matches_per_image_composition(self):
         """color_jitter == applying the four adjusts in each image's drawn
@@ -139,6 +164,80 @@ class TestJitterPerImageOrder:
             for op in order[i]:
                 x = adjusts[op](x, factors[op][i : i + 1])
             np.testing.assert_allclose(np.asarray(out[i]), np.asarray(x[0]), atol=1e-5)
+
+    @pytest.mark.parametrize(
+        "seed,b,constants,apply_prob,orders",
+        [
+            (11, 6, (0.4, 0.4, 0.4, 0.0), 1.0, 2),
+            (12, 8, (0.4, 0.4, 0.4, 0.4), 1.0, 2),
+            (13, 16, (0.4, 0.4, 0.4, 0.1), 0.8, 2),
+            (1, 64, (0.4, 0.4, 0.4, 0.1), 1.0, 24),
+        ],
+        ids=["hue0", "v1", "apply_prob_0.8", "all_24_orders"],
+    )
+    def test_replays_public_adjusts(self, seed, b, constants, apply_prob, orders):
+        """The same replay over the recipes' constants: every position of
+        hue and every arrangement of the three blends against the public
+        `adjust_*`; a zero-range hue is skipped; an image RandomApply does
+        not keep is its input bit for bit."""
+        rng = jax.random.PRNGKey(seed)
+        images = jax.random.uniform(jax.random.PRNGKey(5), (b, 12, 12, 3))
+        out = np.asarray(color_jitter(rng, images, *constants, apply_prob=apply_prob))
+
+        k_order, k_apply, *k_factors = jax.random.split(rng, 6)
+        hue = constants[3]
+        ranges = [(max(0.0, 1 - c), 1 + c) for c in constants[:3]] + [(-hue, hue)]
+        factors = [
+            jax.random.uniform(k, (b, 1, 1, 1), minval=lo, maxval=hi)
+            for k, (lo, hi) in zip(k_factors, ranges)
+        ]
+        order = np.asarray(jnp.argsort(jax.random.uniform(k_order, (b, 4)), axis=1))
+        keep = np.asarray(jax.random.bernoulli(k_apply, apply_prob, (b,))) | (apply_prob >= 1.0)
+        if apply_prob < 1.0:
+            assert keep.any() and not keep.all()
+        assert len({tuple(o) for o in order}) >= orders
+
+        adjusts = [adjust_brightness, adjust_contrast, adjust_saturation, adjust_hue]
+        for i in range(b):
+            x = images[i : i + 1]
+            if not keep[i]:
+                np.testing.assert_array_equal(out[i], np.asarray(x[0]))
+                continue
+            for op in order[i]:
+                if op == 3 and hue == 0:
+                    continue
+                x = adjusts[op](x, factors[op][i : i + 1])
+            np.testing.assert_allclose(out[i], np.asarray(x[0]), atol=1e-5)
+
+    def test_evaluates_each_order_once(self):
+        """A count, so it runs on the CPU: the HSV round trip is traced
+        once (its `floor`), and XLA's flops an element stand under a third
+        of the all-candidates form's (`_jitter_all_candidates`)."""
+        shapes = (jax.ShapeDtypeStruct((2,), jnp.uint32), jax.ShapeDtypeStruct((8, 224, 224, 3), jnp.float32))
+        args = (0.4, 0.4, 0.4, 0.1, 0.8)
+
+        def count(jaxpr, name):
+            n = 0
+            for eqn in jaxpr.eqns:
+                n += eqn.primitive.name == name
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    n += count(sub, name)
+            return n
+
+        def flops_per_element(fn):
+            cost = jax.jit(lambda k, x: fn(k, x, *args)).lower(*shapes).compile().cost_analysis()
+            return cost["flops"] / np.prod(shapes[1].shape)
+
+        def floors(fn):
+            return count(jax.make_jaxpr(lambda k, x: fn(k, x, *args))(*shapes).jaxpr, "floor")
+
+        assert floors(color_jitter) == 1
+        assert floors(_jitter_all_candidates) == 4  # the counter sees what it should
+        new, old = flops_per_element(color_jitter), flops_per_element(_jitter_all_candidates)
+        assert new < 753 / 3, new  # a third of what the all-candidates form cost when it was replaced
+        # the oracle shares today's `adjust_hue`, whose nested where is cheaper than the
+        # `jnp.select` it had then: 585 where it read 753
+        assert old > 500 and new < old / 2.5, (new, old)
 
     def test_order_varies_across_images(self):
         orders = jnp.argsort(
