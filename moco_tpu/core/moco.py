@@ -41,7 +41,8 @@ from moco_tpu.core.queue import check_queue_divisibility, enqueue, init_queue
 from moco_tpu.obs import comms
 from moco_tpu.obs import health as obs_health
 from moco_tpu.models import ProjectionHead, V3MLPHead, create_resnet
-from moco_tpu.models.joyai import create_joyai, is_token_arch, routing_metrics
+from moco_tpu.models.decoder import routing_metrics
+from moco_tpu.models.token_encoders import create_token_encoder, is_token_arch
 from moco_tpu.ops.losses import cross_entropy, infonce_logits, l2_normalize, topk_accuracy
 from moco_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 from moco_tpu.parallel.shuffle import (
@@ -99,7 +100,7 @@ def create_backbone(cfg: MocoConfig, num_data: Optional[int] = None) -> nn.Modul
                 f"{cfg.arch!r} is a token encoder: it trains on the v1/v2 step "
                 "with shuffle='none'"
             )
-        return create_joyai(
+        return create_token_encoder(
             cfg.arch, dtype=dtype, layers=cfg.lm_layers, vocab_rows=cfg.lm_vocab_rows,
             expert_share=tuple(cfg.expert_share) or None, remat=cfg.remat,
         )

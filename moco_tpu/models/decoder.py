@@ -1,0 +1,228 @@
+"""What every decoder stack read as a text encoder needs, whatever the
+family: the half `models/joyai.py` and `models/smallthinker.py` share.
+
+- `RMSNorm` and the bias-free `dense`;
+- **the expert dispatch** (`ExpertDispatch.routed`): an expert layer is
+  told which experts it holds (`first_expert`, `experts_held`). The
+  family's router scores and selects over ALL experts; the dispatch sorts
+  the (token, expert) assignments that land on its own, runs one grouped
+  product over them (`ops/grouped_matmul.py`) and adds nothing for the
+  absent ones. No capacity, no dropped token: the buffers are the worst
+  case's. On one chip there is no exchange and nothing stands in for the
+  absent chips. The routing function and the gate's activation are the
+  family's; the held experts' load and the share's first expert live in
+  `batch_stats`, the collection the train step already carries;
+- **the backbone skeleton** (`DecoderBackbone`): embed the ids, run the
+  family's blocks, final RMSNorm, the mean over a row's valid positions;
+- **the remat policy** (`remat_block`): a block recomputed in the backward
+  pass, which keeps nothing but the causal kernels' two outputs;
+- `routing_metrics`: what a log line says of the routing.
+
+A family's file keeps its attention, its routing function, its block and
+its sizes. An input is `{"ids": (B, S) int32, "lengths": (B,) int32}`:
+positions at or beyond a row's length are padding: masked as keys, routed
+nowhere, counted nowhere and left out of the pool.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from moco_tpu.ops.flash_attention import CAUSAL_SAVED_NAMES
+from moco_tpu.ops.grouped_matmul import grouped_matmul
+
+RMS_EPS = 1e-6
+
+
+class RMSNorm(nn.Module):
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        xf = x.astype(jnp.float32)
+        y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + RMS_EPS)
+        return (y * scale).astype(self.dtype)
+
+
+def dense(features: int, dtype, name: str) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, dtype=dtype, name=name)
+
+
+@jax.custom_vjp
+def _permute(x, perm, inv):
+    """x[perm] for a permutation `perm` with inverse `inv`: its transpose
+    is the gather by `inv`, not the scatter XLA would derive."""
+    return jnp.take(x, perm, axis=0)
+
+
+def _permute_fwd(x, perm, inv):
+    return jnp.take(x, perm, axis=0), (perm, inv)
+
+
+def _permute_bwd(res, g):
+    perm, inv = res
+    return jnp.take(g, inv, axis=0), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+class ExpertDispatch(nn.Module):
+    """This chip's share of a layer's routed experts. A family's expert
+    layer derives from it, routes as the family routes and hands `routed`
+    the choice; the stacked weights, `load` and `first_expert` are the
+    deriving module's own variables."""
+
+    experts: int
+    top_k: int
+    expert_mlp: int
+    first_expert: int
+    experts_held: int
+    train: bool
+    dtype: jnp.dtype = jnp.float32
+
+    def routed(self, x, valid, chosen, weights, activation: Callable):
+        """x (T, d) tokens; valid (T,) bool, False on padding; chosen,
+        weights (T, k) over ALL experts. What the held experts give:
+        sum over a token's chosen e held here of w_e * W_out,e
+        (activation(W_gate,e x) * W_up,e x), (T, d)."""
+        t, d = x.shape
+        e, k, held, ff = self.experts, self.top_k, self.experts_held, self.expert_mlp
+        dt = self.dtype
+        fan_in = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=(0,)
+        )
+        w_in = self.param("experts_in", fan_in, (held, d, 2 * ff), jnp.float32)  # gate | up
+        w_out = self.param("experts_out", fan_in, (held, ff, d), jnp.float32)
+        load = self.variable("batch_stats", "load", jnp.zeros, (held,), jnp.float32)
+        # which share this is travels with the state (float: the step
+        # averages the collection over devices), so a checkpoint knows it
+        self.variable(
+            "batch_stats", "first_expert", lambda: jnp.asarray(self.first_expert, jnp.float32)
+        )
+
+        # assignments on held experts first, in expert order; the rest
+        # (absent experts, padding) share one key that sorts behind them
+        local = (chosen - self.first_expert) % e
+        mine = valid[:, None] & (local < held)
+        key = jnp.where(mine, local, held).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        inv = jnp.argsort(order)
+        sizes = jnp.bincount(key, length=held + 1)[:held]
+        xs = _permute(jnp.repeat(x.astype(dt), k, axis=0), order, inv)
+        gate_up = grouped_matmul(xs, w_in.astype(dt), sizes)
+        act = activation(gate_up[:, :ff]) * gate_up[:, ff:]
+        ys = grouped_matmul(act, w_out.astype(dt), sizes)
+        y = _permute(ys, inv, order).reshape(t, k, d)
+        y = jnp.sum(jnp.where(mine[..., None], y * weights[..., None].astype(dt), 0), axis=1)
+        if self.train and not self.is_initializing():
+            load.value = sizes.astype(jnp.float32)
+        return y
+
+
+def valid_positions(lengths: jax.Array, seq_len: int) -> jax.Array:
+    """(B, S) bool: the positions inside each row's length."""
+    return jnp.arange(seq_len)[None, :] < lengths[:, None]
+
+
+def remat_block(block_cls):
+    """A block recomputed in the backward pass, which keeps nothing but
+    the causal kernel's two outputs. A short sequence takes the dense
+    product, names nothing, and is recomputed whole."""
+    return nn.remat(
+        block_cls, policy=jax.checkpoint_policies.save_only_these_names(*CAUSAL_SAVED_NAMES)
+    )
+
+
+class DecoderBackbone(nn.Module):
+    """Token ids -> pooled features (B, hidden) float32. `layers`,
+    `vocab_rows` and the expert share are this chip's cut of a deployment
+    (a pipeline stage's layers, a vocabulary slice, one chip's experts);
+    every width is `cfg`'s. `remat`: recompute each block in the backward
+    pass instead of keeping its activations, with one exception: where the
+    attention product ran on the Pallas kernels its output and log-sum-exp
+    are kept (`remat_block`), since they are all the backward kernels need
+    of the forward kernel and cost far less to hold (136 MB a layer at 2 x
+    8192 tokens and 32 heads of 128) than to compute again (a third
+    forward kernel a layer). A family derives from it and says what its
+    i-th block is (`block`)."""
+
+    cfg: Any  # the family's sizes: `hidden` is read here
+    layers: int
+    vocab_rows: int
+    first_expert: int
+    experts_held: int
+    remat: bool = False
+    dtype: jnp.dtype = jnp.float32
+    # the embedding's initial standard deviation: the family's (a derived
+    # backbone may state another; no run reads a published weight)
+    embed_std: float = 0.02
+
+    def block(self, i: int, train: bool) -> nn.Module:
+        raise NotImplementedError
+
+    @nn.compact
+    def __call__(self, inputs, train: bool = True, group: Optional[str] = None):
+        if group is not None:
+            raise ValueError("the decoder stack has no layer-group schedule")
+        ids, lengths = inputs["ids"], inputs["lengths"].astype(jnp.int32)
+        x = nn.Embed(
+            self.vocab_rows, self.cfg.hidden, dtype=self.dtype,
+            embedding_init=nn.initializers.normal(self.embed_std), name="embed",
+        )(ids)
+        for i in range(self.layers):
+            x = self.block(i, train)(x, lengths)
+        x = RMSNorm(jnp.float32, name="final_norm")(x)
+        valid = valid_positions(lengths, x.shape[1])[..., None]
+        total = jnp.sum(jnp.where(valid, x, 0.0), axis=1)
+        return total / jnp.maximum(lengths, 1)[:, None].astype(jnp.float32)
+
+
+def create_stack(
+    backbone_cls,
+    configs: dict,
+    arch: str,
+    dtype=jnp.float32,
+    layers: Optional[int] = None,
+    vocab_rows: Optional[int] = None,
+    expert_share: Optional[tuple] = None,
+    remat: bool = False,
+):
+    """A family's backbone at its cut of a deployment: `None` means as
+    published (every layer, every vocabulary row, every expert)."""
+    if arch not in configs:
+        raise ValueError(f"unknown arch {arch!r}; choose from {sorted(configs)}")
+    cfg = configs[arch]
+    first, held = expert_share or (0, cfg.experts)
+    if not (0 <= first < cfg.experts and 0 < held <= cfg.experts):
+        raise ValueError(f"expert share {(first, held)} outside the {cfg.experts} routed experts")
+    return backbone_cls(
+        cfg=cfg, layers=layers or cfg.layers, vocab_rows=vocab_rows or cfg.vocab_size,
+        first_expert=int(first), experts_held=int(held), remat=remat, dtype=dtype,
+    )
+
+
+def routing_metrics(batch_stats) -> dict:
+    """What a log line says of the routing, from the counts the expert
+    layers left in `batch_stats` (each layer's `load`: tokens on each held
+    expert this step): the largest held expert's tokens over the mean,
+    worst layer; and the mean tokens a held expert saw. {} for an encoder
+    with no expert layer."""
+    loads = [
+        leaf for path, leaf in jax.tree_util.tree_leaves_with_path(batch_stats)
+        if getattr(path[-1], "key", None) == "load"
+    ]
+    if not loads:
+        return {}
+    loads = jnp.stack(loads)  # (layers, held)
+    mean = jnp.mean(loads, axis=1)
+    return {
+        "moe/load_max_over_mean": jnp.max(jnp.max(loads, axis=1) / jnp.maximum(mean, 1.0)),
+        "moe/tokens_per_expert": jnp.mean(mean),
+    }

@@ -305,7 +305,7 @@ PREFIX_VALIDATORS = {
     # and phase/steps, the steps it covers. Measured on every step, so
     # never null.
     "phase/": _num,
-    # an expert layer's routing this step (models/joyai.py
+    # an expert layer's routing this step (models/decoder.py
     # routing_metrics): moe/load_max_over_mean, moe/tokens_per_expert
     "moe/": _num,
     # the `setup` event line's parts (obs/stepstats.py setup_account)

@@ -450,11 +450,17 @@ def flash_attention(
 #
 # What a decoder stack's attention needs and the kernels above lack: a
 # causal mask whose blocks above the diagonal cost nothing, q/k of one
-# width and v of another, and keys masked beyond each row's own length.
+# width and v of another, keys masked beyond each row's own length, fewer
+# key heads than query heads (grouped heads: query head j reads key head
+# j // group, k and v are never copied out to the query heads), and a
+# window (key p is visible to query t iff t - p < window) whose blocks
+# older than the band cost nothing either.
 # The grid is (batch*head, query block, key block) with the softmax state
 # in VMEM scratch across the last axis, so neither K/V nor Q is ever whole
 # in VMEM (8192 positions x 192 would be). A skipped key block maps to the
-# block before it: its DMA is elided and its body does not run.
+# block before it: its DMA is elided and its body does not run. Under a
+# window the last axis counts from the first key block of the query
+# block's band, so the blocks older than the band are no grid steps at all.
 
 CAUSAL_BLOCK = 512
 # below this the (S, S) scores of one head are a few VMEM tiles and XLA's
@@ -465,38 +471,93 @@ CAUSAL_MIN_SEQ = 1024
 CAUSAL_SAVED_NAMES = ("causal_attention_out", "causal_attention_lse")
 
 
-def _causal_mask(s_q: int, s_k: int, kv_lens: jax.Array) -> jax.Array:
-    """(B, 1, S_q, S_k): key at or before the query, and inside the row's length."""
+def _causal_mask(s_q: int, s_k: int, kv_lens: jax.Array, window: Optional[int] = None) -> jax.Array:
+    """(B, 1, S_q, S_k): key at or before the query, inside the row's
+    length and, under a window, fewer than `window` positions back."""
     rows = jnp.arange(s_q)[:, None]
     cols = jnp.arange(s_k)[None, :]
-    return (cols <= rows)[None, None] & (cols[None, None] < kv_lens[:, None, None, None])
+    seen = cols <= rows
+    if window is not None:
+        seen = seen & (rows - cols < window)
+    return seen[None, None] & (cols[None, None] < kv_lens[:, None, None, None])
 
 
-def _causal_attn_reference(q, k, v, kv_lens, scale):
-    """Dense masked jnp attention, (B, H, S, Dqk) x (B, H, S, Dv) -> (B, H, S, Dv)."""
+def _causal_attn_reference(q, k, v, kv_lens, scale, window=None):
+    """Dense masked jnp attention, (B, H, S, Dqk) x (B, Hk, S, Dv) -> (B, H, S, Dv)."""
+    mask = _causal_mask(q.shape[2], k.shape[2], kv_lens, window)
+    if k.shape[1] != q.shape[1]:  # grouped heads: query head j reads key head j // group
+        b, h, s, _ = q.shape
+        qg = q.reshape(b, k.shape[1], h // k.shape[1], s, q.shape[-1])
+        logits = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k).astype(jnp.float32) * scale
+        probs = jax.nn.softmax(jnp.where(mask[:, :, None], logits, NEG_INF), axis=-1)
+        out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, v.astype(jnp.float32))
+        return out.reshape(b, h, s, v.shape[-1]).astype(v.dtype)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
-    logits = jnp.where(_causal_mask(q.shape[2], k.shape[2], kv_lens), logits, NEG_INF)
+    logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v.astype(jnp.float32)).astype(v.dtype)
 
 
-def _scores(q, kb, scale, q_start, k_start, kv_len):
+def _bound(pick_int, pick_traced, a, b):
+    """max or min of two block indices, static (Python ints, for a grid's
+    length) or traced (inside a kernel or an index map)."""
+    return pick_int(a, b) if isinstance(a, int) and isinstance(b, int) else pick_traced(a, b)
+
+
+def _key_block(i, j, block_q: int, block_k: int, window: Optional[int]):
+    """The key block of step `j` of query block `i`: without a window the
+    j-th; under one the j-th from the block that holds the oldest key the
+    query block's first row sees."""
+    if window is None:
+        return j
+    return _bound(max, jnp.maximum, i * block_q - (window - 1), 0) // block_k + j
+
+
+def _last_query_block(j, block_q: int, block_k: int, window: int, n_q: int):
+    """The last query block that reads key block `j` under a window: the
+    one that holds the newest query its last key is visible to."""
+    return _bound(min, jnp.minimum, (j * block_k + block_k - 1 + window - 1) // block_q, n_q - 1)
+
+
+def _band_steps(s: int, block_q: int, block_k: int, window: Optional[int]) -> tuple:
+    """(key blocks a query block reads at most, query blocks a key block is
+    read by at most): the lengths of the kernels' last grid axis. Without a
+    window every block of the sequence (those past the diagonal are skipped)."""
+    n_q, n_k = s // block_q, s // block_k
+    if window is None:
+        return n_k, n_q
+    keys = max(
+        (i * block_q + block_q - 1) // block_k - _key_block(i, 0, block_q, block_k, window) + 1
+        for i in range(n_q)
+    )
+    queries = max(
+        _last_query_block(j, block_q, block_k, window, n_q) - (j * block_k) // block_q + 1
+        for j in range(n_k)
+    )
+    return keys, queries
+
+
+def _scores(q, kb, scale, q_start, k_start, kv_len, window=None):
     """Masked scaled scores of one (query block, key block) tile, fp32."""
     s = jax.lax.dot_general(
         q, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
     rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where((cols <= rows) & (cols < kv_len), s, NEG_INF)
+    seen = (cols <= rows) & (cols < kv_len)
+    if window is not None:
+        seen = seen & (rows - cols < window)
+    return jnp.where(seen, s, NEG_INF)
 
 
 def _causal_fwd_kernel(
     lens_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc,
-    *, scale: float, block_q: int, block_k: int, heads: int,
+    *, scale: float, block_q: int, block_k: int, heads: int, window: Optional[int],
 ):
     i, j = pl.program_id(1), pl.program_id(2)
     kv_len = lens_ref[pl.program_id(0) // heads]
-    q_start, k_start = i * block_q, j * block_k
+    q_start = i * block_q
+    k_start = _key_block(i, j, block_q, block_k, window) * block_k
 
     @pl.when(j == 0)
     def _():
@@ -506,7 +567,11 @@ def _causal_fwd_kernel(
 
     @pl.when((k_start < q_start + block_q) & (k_start < kv_len))
     def _():
-        s = _scores(q_ref[...], k_ref[...], scale, q_start, k_start, kv_len)
+        # a row that sees no key of this block (the band's oldest block, under
+        # a window) adds exp(NEG_INF - NEG_INF) = 1 a key to l and acc; the
+        # first block with a key it does see (its own diagonal block at the
+        # latest) multiplies both by exp(NEG_INF - m) = 0
+        s = _scores(q_ref[...], k_ref[...], scale, q_start, k_start, kv_len, window)
         m_prev = m_sc[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -530,11 +595,12 @@ def _causal_fwd_kernel(
 
 def _causal_dq_kernel(
     lens_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref, acc,
-    *, scale: float, block_q: int, block_k: int, heads: int,
+    *, scale: float, block_q: int, block_k: int, heads: int, window: Optional[int],
 ):
     i, j = pl.program_id(1), pl.program_id(2)
     kv_len = lens_ref[pl.program_id(0) // heads]
-    q_start, k_start = i * block_q, j * block_k
+    q_start = i * block_q
+    k_start = _key_block(i, j, block_q, block_k, window) * block_k
 
     @pl.when(j == 0)
     def _():
@@ -543,7 +609,7 @@ def _causal_dq_kernel(
     @pl.when((k_start < q_start + block_q) & (k_start < kv_len))
     def _():
         kb, vb, g = k_ref[...], v_ref[...], g_ref[...]
-        s = _scores(q_ref[...], kb, scale, q_start, k_start, kv_len)
+        s = _scores(q_ref[...], kb, scale, q_start, k_start, kv_len, window)
         p = jnp.exp(s - lse_ref[...])
         dp = jax.lax.dot_general(
             g, vb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -560,21 +626,31 @@ def _causal_dq_kernel(
 
 def _causal_dkv_kernel(
     lens_ref, k_ref, v_ref, q_ref, g_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-    *, scale: float, block_q: int, block_k: int, heads: int,
+    *, scale: float, block_q: int, block_k: int, heads: int, window: Optional[int],
+    group: int, q_steps: int, n_q: int,
 ):
-    j, i = pl.program_id(1), pl.program_id(2)  # key block outside, query blocks stream
+    # key block outside; inside, the query blocks of every query head of the
+    # key head's group stream through one accumulator, `q_steps` a head
+    j, t = pl.program_id(1), pl.program_id(2)
     kv_len = lens_ref[pl.program_id(0) // heads]
+    i = t if group == 1 else t % q_steps
+    if window is not None:  # the axis counts from the key block's diagonal
+        i = i + (j * block_k) // block_q
     q_start, k_start = i * block_q, j * block_k
 
-    @pl.when(i == 0)
+    @pl.when(t == 0)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when((k_start < q_start + block_q) & (k_start < kv_len))
+    runs = (k_start < q_start + block_q) & (k_start < kv_len)
+    if window is not None:
+        runs = runs & (i <= _last_query_block(j, block_q, block_k, window, n_q))
+
+    @pl.when(runs)
     def _():
         qb, g, vb = q_ref[...], g_ref[...], v_ref[...]
-        s = _scores(qb, k_ref[...], scale, q_start, k_start, kv_len)
+        s = _scores(qb, k_ref[...], scale, q_start, k_start, kv_len, window)
         p = jnp.exp(s - lse_ref[...])
         dv_acc[...] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -587,18 +663,23 @@ def _causal_dkv_kernel(
             ds, qb, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(i == pl.num_programs(2) - 1)
+    @pl.when(t == pl.num_programs(2) - 1)
     def _():
         dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _causal_specs(block_q: int, block_k: int, d_qk: int, d_v: int):
+def _causal_specs(block_q: int, block_k: int, d_qk: int, d_v: int, group: int,
+                  window: Optional[int]):
     """Block specs over a (bh, query block, key block) grid. A key block
-    above the diagonal maps to the last one needed, so it is not fetched."""
+    above the diagonal maps to the last one needed, so it is not fetched;
+    query head `b` reads key head `b // group`."""
     last_k = lambda i: (i * block_q + block_q - 1) // block_k
     q_map = lambda b, i, j, lens: (b, i, 0)
-    k_map = lambda b, i, j, lens: (b, jnp.minimum(j, last_k(i)), 0)
+    kv_head = (lambda b: b) if group == 1 else (lambda b: b // group)
+    k_map = lambda b, i, j, lens: (
+        kv_head(b), jnp.minimum(_key_block(i, j, block_q, block_k, window), last_k(i)), 0
+    )
     return {
         "q": pl.BlockSpec((None, block_q, d_qk), q_map),
         "g": pl.BlockSpec((None, block_q, d_v), q_map),
@@ -611,18 +692,26 @@ def _causal_specs(block_q: int, block_k: int, d_qk: int, d_v: int):
 _SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret):
+def _kernel_name(window: Optional[int], which: str) -> str:
+    """What a trace finds the `pallas_call` by: a call with a window is
+    another program than one without."""
+    return f"{'causal' if window is None else 'window'}_attention_{which}"
+
+
+def _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret, window):
     b, h, s, d_qk = q.shape
-    d_v = v.shape[-1]
+    h_k, d_v = k.shape[1], v.shape[-1]
     bh = b * h
-    sp = _causal_specs(block_q, block_k, d_qk, d_v)
+    sp = _causal_specs(block_q, block_k, d_qk, d_v, h // h_k, window)
+    k_steps, _ = _band_steps(s, block_q, block_k, window)
     out, lse = pl.pallas_call(
         functools.partial(
-            _causal_fwd_kernel, scale=scale, block_q=block_q, block_k=block_k, heads=h
+            _causal_fwd_kernel, scale=scale, block_q=block_q, block_k=block_k, heads=h,
+            window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, s // block_q, s // block_k),
+            grid=(bh, s // block_q, k_steps),
             in_specs=[sp["q"], sp["k"], sp["v"]],
             out_specs=[sp["g"], sp["row"]],
             scratch_shapes=[
@@ -637,27 +726,29 @@ def _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret):
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
-        name="causal_attention_fwd",
-    )(kv_lens, q.reshape(bh, s, d_qk), k.reshape(bh, s, d_qk), v.reshape(bh, s, d_v))
+        name=_kernel_name(window, "fwd"),
+    )(kv_lens, q.reshape(bh, s, d_qk), k.reshape(b * h_k, s, d_qk), v.reshape(b * h_k, s, d_v))
     return out.reshape(b, h, s, d_v), lse
 
 
-def _causal_backward(q, k, v, kv_lens, out, lse, g, scale, block_q, block_k, interpret):
+def _causal_backward(q, k, v, kv_lens, out, lse, g, scale, block_q, block_k, interpret, window):
     b, h, s, d_qk = q.shape
-    d_v = v.shape[-1]
-    bh = b * h
+    h_k, d_v = k.shape[1], v.shape[-1]
+    bh, bh_k, group = b * h, b * h_k, h // h_k
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True
     ).reshape(bh, s, 1)
-    q3, k3 = q.reshape(bh, s, d_qk), k.reshape(bh, s, d_qk)
-    v3, g3 = v.reshape(bh, s, d_v), g.reshape(bh, s, d_v)
-    sp = _causal_specs(block_q, block_k, d_qk, d_v)
-    static = dict(scale=scale, block_q=block_q, block_k=block_k, heads=h)
+    q3, k3 = q.reshape(bh, s, d_qk), k.reshape(bh_k, s, d_qk)
+    v3, g3 = v.reshape(bh_k, s, d_v), g.reshape(bh, s, d_v)
+    sp = _causal_specs(block_q, block_k, d_qk, d_v, group, window)
+    k_steps, q_steps = _band_steps(s, block_q, block_k, window)
+    n_q = s // block_q
+    static = dict(scale=scale, block_q=block_q, block_k=block_k, window=window)
     dq = pl.pallas_call(
-        functools.partial(_causal_dq_kernel, **static),
+        functools.partial(_causal_dq_kernel, heads=h, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, s // block_q, s // block_k),
+            grid=(bh, n_q, k_steps),
             in_specs=[sp["q"], sp["g"], sp["row"], sp["row"], sp["k"], sp["v"]],
             out_specs=sp["q"],
             scratch_shapes=[pltpu.VMEM((block_q, d_qk), jnp.float32)],
@@ -665,19 +756,32 @@ def _causal_backward(q, k, v, kv_lens, out, lse, g, scale, block_q, block_k, int
         out_shape=jax.ShapeDtypeStruct((bh, s, d_qk), q.dtype),
         compiler_params=_SEMANTICS,
         interpret=interpret,
-        name="causal_attention_dq",
+        name=_kernel_name(window, "dq"),
     )(kv_lens, q3, g3, lse, delta, k3, v3)
 
-    # key block outside, query blocks stream: those before the diagonal
-    # map to the first one needed and are skipped
+    # key block outside, one grid cell a KEY head: the query blocks of its
+    # group's query heads stream, head after head, and dk, dv are written
+    # once. Those before the diagonal map to the first one needed and are
+    # skipped; under a window the axis starts at the diagonal and those
+    # past the band map to the last one needed
     first_q = lambda j: (j * block_k) // block_q
-    kq_map = lambda b_, j, i, lens: (b_, jnp.maximum(i, first_q(j)), 0)
-    kk_map = lambda b_, j, i, lens: (b_, j, 0)
+    q_head = (lambda b_, t: b_) if group == 1 else (lambda b_, t: b_ * group + t // q_steps)
+    q_step = (lambda t: t) if group == 1 else (lambda t: t % q_steps)
+    if window is None:
+        q_block = lambda j, t: jnp.maximum(q_step(t), first_q(j))
+    else:
+        q_block = lambda j, t: jnp.minimum(
+            first_q(j) + q_step(t), _last_query_block(j, block_q, block_k, window, n_q)
+        )
+    kq_map = lambda b_, j, t, lens: (q_head(b_, t), q_block(j, t), 0)
+    kk_map = lambda b_, j, t, lens: (b_, j, 0)
     dk, dv = pl.pallas_call(
-        functools.partial(_causal_dkv_kernel, **static),
+        functools.partial(
+            _causal_dkv_kernel, heads=h_k, group=group, q_steps=q_steps, n_q=n_q, **static
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, s // block_k, s // block_q),
+            grid=(bh_k, s // block_k, group * q_steps),
             in_specs=[
                 pl.BlockSpec((None, block_k, d_qk), kk_map),
                 pl.BlockSpec((None, block_k, d_v), kk_map),
@@ -696,38 +800,38 @@ def _causal_backward(q, k, v, kv_lens, out, lse, g, scale, block_q, block_k, int
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d_qk), k.dtype),
-            jax.ShapeDtypeStruct((bh, s, d_v), v.dtype),
+            jax.ShapeDtypeStruct((bh_k, s, d_qk), k.dtype),
+            jax.ShapeDtypeStruct((bh_k, s, d_v), v.dtype),
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
-        name="causal_attention_dkv",
+        name=_kernel_name(window, "dkv"),
     )(kv_lens, k3, v3, q3, g3, lse, delta)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _causal_flash(q, k, v, kv_lens, scale, block_q, block_k, interpret):
-    return _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _causal_flash(q, k, v, kv_lens, scale, block_q, block_k, interpret, window):
+    return _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret, window)[0]
 
 
-def _causal_fwd(q, k, v, kv_lens, scale, block_q, block_k, interpret):
+def _causal_fwd(q, k, v, kv_lens, scale, block_q, block_k, interpret, window):
     """The forward rule. The kernel's two outputs carry `CAUSAL_SAVED_NAMES`,
     so a `jax.checkpoint` whose policy saves those names keeps them, and the
     forward kernel is dead code in its recomputation: what the backward
     kernels need arrives saved. Outside such a policy a name is an identity.
     The log-sum-exp is named without its unit axis: (B*H, S, 1) float32 pads
     the 1 to a tile's 128 lanes in HBM, 128 times the bytes of (B*H, S)."""
-    out, lse = _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret)
+    out, lse = _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret, window)
     out = checkpoint_name(out, CAUSAL_SAVED_NAMES[0])
     lse = checkpoint_name(lse.reshape(lse.shape[:2]), CAUSAL_SAVED_NAMES[1])
     return out, (q, k, v, kv_lens, out, lse)
 
 
-def _causal_bwd(scale, block_q, block_k, interpret, res, g):
+def _causal_bwd(scale, block_q, block_k, interpret, window, res, g):
     q, k, v, kv_lens, out, lse = res
     dq, dk, dv = _causal_backward(
-        q, k, v, kv_lens, out, lse[..., None], g, scale, block_q, block_k, interpret
+        q, k, v, kv_lens, out, lse[..., None], g, scale, block_q, block_k, interpret, window
     )
     return dq, dk, dv, None
 
@@ -737,24 +841,31 @@ _causal_flash.defvjp(_causal_fwd, _causal_bwd)
 
 def causal_flash_attention(
     q: jax.Array,  # (B, H, S, Dqk)
-    k: jax.Array,  # (B, H, S, Dqk)
-    v: jax.Array,  # (B, H, S, Dv)
+    k: jax.Array,  # (B, Hk, S, Dqk): Hk divides H; query head j reads key head j // (H / Hk)
+    v: jax.Array,  # (B, Hk, S, Dv)
     kv_lens: jax.Array,  # (B,) int32: keys at or beyond a row's length are masked
     scale: Optional[float] = None,
     block_q: int = CAUSAL_BLOCK,
     block_k: int = CAUSAL_BLOCK,
     interpret: bool = False,
+    window: Optional[int] = None,  # key p is visible to query t iff t - p < window
 ) -> jax.Array:
     """Causal attention (B, H, S, Dv); differentiable in q, k and v. The
     Pallas kernels run where the sequence is long enough to need them and
     the blocks divide it; a short one takes the dense masked product
     (blocks given by the caller always mean the kernels: a test's way to
-    reach them at a test's length)."""
+    reach them at a test's length). The group and the window are the
+    layer's own and static: with one key head a query head and no window
+    the kernels are the programs they were without either."""
     s = q.shape[2]
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     kv_lens = kv_lens.astype(jnp.int32)
+    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(f"{k.shape[1]} key and {v.shape[1]} value heads under {q.shape[1]} query heads")
+    if window is not None and window < 1:
+        raise ValueError(f"a window holds at least the query's own key, got {window}")
     if s < CAUSAL_MIN_SEQ and (block_q, block_k) == (CAUSAL_BLOCK, CAUSAL_BLOCK):
-        return _causal_attn_reference(q, k, v, kv_lens, scale)
+        return _causal_attn_reference(q, k, v, kv_lens, scale, window)
     if s % block_q or s % block_k:
         raise ValueError(f"sequence length {s} is not a multiple of blocks {block_q}, {block_k}")
-    return _causal_flash(q, k, v, kv_lens, scale, block_q, block_k, interpret)
+    return _causal_flash(q, k, v, kv_lens, scale, block_q, block_k, interpret, window)

@@ -130,9 +130,9 @@ class MocoConfig:
     # (jax.checkpoint): trades ~30% more FLOPs for O(depth) less
     # activation HBM — for big models / big per-chip batches. A decoder
     # stack recomputes block by block and spares the attention product
-    # (models/joyai.py: the kernels' output and log-sum-exp are kept).
+    # (models/decoder.py: the kernels' output and log-sum-exp are kept).
     remat: bool = False
-    # A decoder stack's cut of a deployment (moco_tpu/models/joyai.py):
+    # A decoder stack's cut of a deployment (moco_tpu/models/decoder.py):
     # the layers of this pipeline stage (None = as published), the rows of
     # the vocabulary held here (None = all), and this chip's share of each
     # expert layer as (first_expert, experts_held) (() = every expert).
@@ -674,7 +674,7 @@ PRESETS = {
     # (benchmarks/configs/joyai_flash_ep16.json is one chip of 16).
     # remat: each decoder block is recomputed in the backward pass, all but
     # its attention product: the kernels' output and log-sum-exp are kept
-    # (models/joyai.py RematBlock), 136 MB a layer at 2 x 8192 tokens.
+    # (models/decoder.py remat_block), 136 MB a layer at 2 x 8192 tokens.
     "joyai_llm_flash": TrainConfig(
         moco=MocoConfig(
             arch="joyai_llm_flash", mlp=True, temperature=0.05, momentum=0.9995,
@@ -692,6 +692,33 @@ PRESETS = {
     "joyai_tiny": TrainConfig(
         moco=MocoConfig(
             arch="joyai_tiny", mlp=True, temperature=0.05, momentum=0.9995,
+            num_negatives=256, shuffle="none", compute_dtype="float32",
+        ),
+        optim=OptimConfig(optimizer="adamw", lr=5e-5, weight_decay=0.01, epochs=1, cos=True),
+        data=DataConfig(dataset="synthetic", input="tokens", seq_len=64, global_batch=4),
+    ),
+    # A second decoder stack on the same path (moco_tpu/models/smallthinker.py:
+    # SmallThinker-21BA3B, window and full attention over 4 grouped key heads,
+    # a softmax-of-the-top-6 router that reads before attention, 64 ReGLU
+    # experts): the same recipe over two independent windows of the model's
+    # whole 16 384-token context, one row a chip. As published it is 21 B
+    # parameters: a run states its cut as above
+    # (benchmarks/configs/smallthinker_21b_ep8.json is one chip of 8).
+    "smallthinker_21b": TrainConfig(
+        moco=MocoConfig(
+            arch="smallthinker_21b", mlp=True, temperature=0.05, momentum=0.9995,
+            shuffle="none", remat=True,
+        ),
+        # one warm-up epoch of 25 over the 40 000 documents, as joyai_llm_flash
+        optim=OptimConfig(
+            optimizer="adamw", lr=5e-5, weight_decay=0.01, epochs=25, cos=True, warmup_epochs=1
+        ),
+        data=DataConfig(dataset="synthetic", input="tokens", seq_len=16384, global_batch=1),
+    ),
+    # the same stack and path at a test's size, for the CPU
+    "smallthinker_tiny": TrainConfig(
+        moco=MocoConfig(
+            arch="smallthinker_tiny", mlp=True, temperature=0.05, momentum=0.9995,
             num_negatives=256, shuffle="none", compute_dtype="float32",
         ),
         optim=OptimConfig(optimizer="adamw", lr=5e-5, weight_decay=0.01, epochs=1, cos=True),

@@ -48,16 +48,46 @@ def test_causal_attention_compiles_at_8192_positions(one_chip):
         assert name in text
 
 
-@pytest.mark.parametrize("k,n", [(2048, 1536), (768, 2048)], ids=["experts_in", "experts_out"])
-def test_grouped_matmul_compiles_at_the_worst_case_buffer(one_chip, monkeypatch, k, n):
-    """16 held experts over the 2 x 8192 x 8 rows of the worst case, both
+@pytest.mark.parametrize("window", [None, 4096], ids=["full_layer", "window_layer"])
+def test_grouped_head_attention_compiles_at_16384_positions(one_chip, window):
+    """Forward, dq and dk/dv at 1 row x 28 query / 4 key heads x 16 384
+    positions of 128, bfloat16, with and without the 4096-key window: the
+    second token cell's own calls. dk and dv come back at the 4 key heads."""
+    from moco_tpu.ops.flash_attention import causal_flash_attention
+
+    q = _shape(one_chip, (1, 28, 16384, 128), jnp.bfloat16)
+    kv = _shape(one_chip, (1, 4, 16384, 128), jnp.bfloat16)
+    lens = _shape(one_chip, (1,), jnp.int32)
+
+    def f(q, k, v, lens):
+        loss = lambda q, k, v: jnp.sum(
+            causal_flash_attention(q, k, v, lens, window=window).astype(jnp.float32)
+        )
+        return jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(f).lower(q, kv, kv, lens).compile()
+    kind = "causal" if window is None else "window"
+    for which in ("fwd", "dq", "dkv"):
+        assert f"{kind}_attention_{which}" in compiled.as_text()
+    _, (dq, dk, dv) = jax.eval_shape(f, q, kv, kv, lens)
+    assert dq.shape == (1, 28, 16384, 128) and dk.shape == dv.shape == (1, 4, 16384, 128)
+
+
+@pytest.mark.parametrize(
+    "rows,held,k,n",
+    [(131072, 16, 2048, 1536), (131072, 16, 768, 2048), (98304, 8, 2560, 1536), (98304, 8, 768, 2560)],
+    ids=["experts_in", "experts_out", "reglu_experts_in", "reglu_experts_out"],
+)
+def test_grouped_matmul_compiles_at_the_worst_case_buffer(one_chip, monkeypatch, rows, held, k, n):
+    """16 held experts over the 2 x 8192 x 8 rows of the first token
+    cell's worst case, 8 over the 16 384 x 6 of the second's: both
     products of an expert and their gradients."""
     from moco_tpu.ops import grouped_matmul as gm
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    x = _shape(one_chip, (131072, k), jnp.bfloat16)
-    w = _shape(one_chip, (16, k, n), jnp.bfloat16)
-    sizes = _shape(one_chip, (16,), jnp.int32)
+    x = _shape(one_chip, (rows, k), jnp.bfloat16)
+    w = _shape(one_chip, (held, k, n), jnp.bfloat16)
+    sizes = _shape(one_chip, (held,), jnp.int32)
 
     def f(x, w, sizes):
         loss = lambda x, w: jnp.sum(gm.grouped_matmul(x, w, sizes).astype(jnp.float32))
