@@ -5,12 +5,19 @@ family: the half `models/joyai.py` and `models/smallthinker.py` share.
 - **the expert dispatch** (`ExpertDispatch.routed`): an expert layer is
   told which experts it holds (`first_expert`, `experts_held`). The
   family's router scores and selects over ALL experts; the dispatch sorts
-  the (token, expert) assignments that land on its own, runs one grouped
-  product over them (`ops/grouped_matmul.py`) and adds nothing for the
-  absent ones. No capacity, no dropped token: the buffers are the worst
-  case's. On one chip there is no exchange and nothing stands in for the
-  absent chips. The routing function and the gate's activation are the
-  family's; the held experts' load and the share's first expert live in
+  the (token, expert) assignments that land on its own, gathers their rows
+  straight from the tokens, runs one grouped product over them
+  (`ops/grouped_matmul.py`), adds each weighted row straight into its
+  token and adds nothing for the absent experts. No token is ever
+  dropped, and the buffers are not the worst case's either: a share of
+  `experts_held` in `experts` has a ladder of buffer sizes (`rung_ladder`:
+  twice its even share of the tokens x top_k assignments, and all of
+  them), and each call takes, on the device and from its own
+  count, the smallest that holds what is live. The worst case is the last
+  rung's program; an uncut layer has that one and no branch. On one chip
+  there is no exchange and nothing stands in for the absent chips. The
+  routing function and the gate's activation are the family's; the held
+  experts' load, the rung taken and the share's first expert live in
   `batch_stats`, the collection the train step already carries;
 - **the backbone skeleton** (`DecoderBackbone`): embed the ids, run the
   family's blocks, final RMSNorm, the mean over a row's valid positions;
@@ -26,6 +33,7 @@ nowhere, counted nowhere and left out of the pool.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -34,7 +42,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from moco_tpu.ops.flash_attention import CAUSAL_SAVED_NAMES
-from moco_tpu.ops.grouped_matmul import grouped_matmul
+from moco_tpu.ops.grouped_matmul import TILE_ROWS, grouped_matmul
 
 RMS_EPS = 1e-6
 
@@ -54,23 +62,72 @@ def dense(features: int, dtype, name: str) -> nn.Dense:
     return nn.Dense(features, use_bias=False, dtype=dtype, name=name)
 
 
-@jax.custom_vjp
-def _permute(x, perm, inv):
-    """x[perm] for a permutation `perm` with inverse `inv`: its transpose
-    is the gather by `inv`, not the scatter XLA would derive."""
-    return jnp.take(x, perm, axis=0)
+def rung_ladder(rows: int, experts: int, held: int) -> tuple:
+    """The buffer sizes a share of `held` of `experts` experts runs its
+    rows = tokens x top_k assignments on: twice its even share, in whole
+    row tiles, and `rows` itself, the worst case. An uncut layer has the
+    one. No rung between them: each is a program of its own in every
+    expert layer's forward and backward pass, one at four times the even
+    share was taken by no measured step and cost a warm start seconds of
+    loading (PERF.md section 6, PR 34)."""
+    even = -(-rows * held // experts)
+    bounded = -(-2 * even // TILE_ROWS) * TILE_ROWS
+    return (bounded, rows) if bounded < rows else (rows,)
 
 
-def _permute_fwd(x, perm, inv):
-    return jnp.take(x, perm, axis=0), (perm, inv)
+def _rung(rows: int, k: int, activation: Callable, x, w_in, w_out, weights, order, sizes):
+    """The held experts' outputs from a buffer of `rows` rows, which holds
+    every live assignment (`sum(sizes) <= rows`). x (T, d); weights (T, k)
+    float32; `order` the assignments (token * k + choice) sorted by held
+    expert, the live ones first; `sizes` (held,) their count an expert.
+    Nothing here has more than `rows` rows of width d or ff: a row is
+    gathered straight from its token and added straight into it."""
+    ff = w_out.shape[1]
+    slot = order[:rows]
+    token = slot // k
+    live = jnp.arange(rows) < jnp.sum(sizes)
+    # masked for the backward pass's sake: on a TPU the grouped product's
+    # gradient leaves the rows of no group unwritten
+    xs = jnp.where(live[:, None], jnp.take(x, token, axis=0), 0)
+    gate_up = grouped_matmul(xs, w_in, sizes)
+    act = activation(gate_up[:, :ff]) * gate_up[:, ff:]
+    ys = grouped_matmul(act, w_out, sizes)
+    w = jnp.where(live, jnp.take(weights.reshape(-1), slot), 0.0)
+    y = jnp.zeros(x.shape, jnp.float32).at[token].add(ys.astype(jnp.float32) * w[:, None])
+    return y.astype(x.dtype)
 
 
-def _permute_bwd(res, g):
-    perm, inv = res
-    return jnp.take(g, inv, axis=0), None, None
+def rung_taken(ladder: tuple, sizes) -> jax.Array:
+    """Index of the smallest rung that holds every live assignment."""
+    return jnp.sum(jnp.sum(sizes) > jnp.asarray(ladder[:-1], sizes.dtype))
 
 
-_permute.defvjp(_permute_fwd, _permute_bwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _laddered(ladder: tuple, k: int, activation: Callable, x, w_in, w_out, weights, order, sizes):
+    """`_rung` at the smallest size of `ladder` that holds this call's live
+    assignments, chosen on the device. The backward pass chooses by the
+    same count and computes its rung again from the inputs: differentiated
+    as it stands, the conditional would hand out every rung's residuals
+    from every rung, the worst case's filled with zeros by the others."""
+    rungs = [functools.partial(_rung, rows, k, activation) for rows in ladder]
+    return lax.switch(rung_taken(ladder, sizes), rungs, x, w_in, w_out, weights, order, sizes)
+
+
+def _laddered_fwd(ladder, k, activation, *operands):
+    return _laddered(ladder, k, activation, *operands), operands
+
+
+def _laddered_bwd(ladder, k, activation, operands, g):
+    *floats, order, sizes = operands
+
+    def pull(rows, floats, order, sizes, g):
+        return jax.vjp(lambda *f: _rung(rows, k, activation, *f, order, sizes), *floats)[1](g)
+
+    rungs = [functools.partial(pull, rows) for rows in ladder]
+    return (*lax.switch(rung_taken(ladder, sizes), rungs, floats, order, sizes, g), None, None)
+
+
+_laddered.defvjp(_laddered_fwd, _laddered_bwd)
 
 
 class ExpertDispatch(nn.Module):
@@ -101,6 +158,9 @@ class ExpertDispatch(nn.Module):
         w_in = self.param("experts_in", fan_in, (held, d, 2 * ff), jnp.float32)  # gate | up
         w_out = self.param("experts_out", fan_in, (held, ff, d), jnp.float32)
         load = self.variable("batch_stats", "load", jnp.zeros, (held,), jnp.float32)
+        # the rung this call took: its rows, and 1.0 where that is not the worst case
+        buffer_rows = self.variable("batch_stats", "buffer_rows", jnp.zeros, (), jnp.float32)
+        bounded = self.variable("batch_stats", "bounded", jnp.zeros, (), jnp.float32)
         # which share this is travels with the state (float: the step
         # averages the collection over devices), so a checkpoint knows it
         self.variable(
@@ -113,16 +173,18 @@ class ExpertDispatch(nn.Module):
         mine = valid[:, None] & (local < held)
         key = jnp.where(mine, local, held).reshape(-1)
         order = jnp.argsort(key, stable=True)
-        inv = jnp.argsort(order)
         sizes = jnp.bincount(key, length=held + 1)[:held]
-        xs = _permute(jnp.repeat(x.astype(dt), k, axis=0), order, inv)
-        gate_up = grouped_matmul(xs, w_in.astype(dt), sizes)
-        act = activation(gate_up[:, :ff]) * gate_up[:, ff:]
-        ys = grouped_matmul(act, w_out.astype(dt), sizes)
-        y = _permute(ys, inv, order).reshape(t, k, d)
-        y = jnp.sum(jnp.where(mine[..., None], y * weights[..., None].astype(dt), 0), axis=1)
+        ladder = rung_ladder(t * k, e, held)
+        operands = (x.astype(dt), w_in.astype(dt), w_out.astype(dt), weights, order, sizes)
+        if len(ladder) == 1:  # the uncut layer: one program, nothing to choose
+            y = _rung(t * k, k, activation, *operands)
+        else:
+            y = _laddered(ladder, k, activation, *operands)
         if self.train and not self.is_initializing():
             load.value = sizes.astype(jnp.float32)
+            taken = rung_taken(ladder, sizes)
+            buffer_rows.value = jnp.asarray(ladder, jnp.float32)[taken]
+            bounded.value = (taken < len(ladder) - 1).astype(jnp.float32)
         return y
 
 
@@ -209,20 +271,23 @@ def create_stack(
 
 
 def routing_metrics(batch_stats) -> dict:
-    """What a log line says of the routing, from the counts the expert
-    layers left in `batch_stats` (each layer's `load`: tokens on each held
-    expert this step): the largest held expert's tokens over the mean,
-    worst layer; and the mean tokens a held expert saw. {} for an encoder
-    with no expert layer."""
-    loads = [
-        leaf for path, leaf in jax.tree_util.tree_leaves_with_path(batch_stats)
-        if getattr(path[-1], "key", None) == "load"
-    ]
-    if not loads:
+    """What a log line says of the routing, from what the expert layers
+    left in `batch_stats` this step (each layer's `load`: tokens on each
+    held expert; `buffer_rows`, `bounded`: the rung its dispatch took): the
+    largest held expert's tokens over the mean, worst layer; the mean
+    tokens a held expert saw; the share of the layers that ran below the
+    worst case; and the mean rows of the buffers they ran on. {} for an
+    encoder with no expert layer."""
+    named = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(batch_stats):
+        named.setdefault(getattr(path[-1], "key", None), []).append(leaf)
+    if "load" not in named:
         return {}
-    loads = jnp.stack(loads)  # (layers, held)
+    loads = jnp.stack(named["load"])  # (layers, held)
     mean = jnp.mean(loads, axis=1)
     return {
         "moe/load_max_over_mean": jnp.max(jnp.max(loads, axis=1) / jnp.maximum(mean, 1.0)),
         "moe/tokens_per_expert": jnp.mean(mean),
+        "moe/bounded_share": jnp.mean(jnp.stack(named["bounded"])),
+        "moe/buffer_rows": jnp.mean(jnp.stack(named["buffer_rows"])),
     }

@@ -19,7 +19,8 @@ feature row out. Pre-norm blocks, RMSNorm, residual adds:
 - **the share**: an expert layer is told which experts it holds
   (`first_expert`, `experts_held`): it scores and selects over ALL of
   them and `models/decoder.py::ExpertDispatch` computes the part its own
-  give (no capacity, no dropped token, nothing for the absent ones).
+  give (buffers sized by what is live, no token dropped at any load,
+  nothing for the absent ones).
 - **the bias** (`e_score_correction_bias`) is not trained by the gradient.
   Every training forward moves it by `BIAS_UPDATE_RATE` towards balance,
   from the selection counts over all experts (the DeepSeek-V3 report's

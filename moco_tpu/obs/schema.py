@@ -306,7 +306,8 @@ PREFIX_VALIDATORS = {
     # never null.
     "phase/": _num,
     # an expert layer's routing this step (models/decoder.py
-    # routing_metrics): moe/load_max_over_mean, moe/tokens_per_expert
+    # routing_metrics): moe/load_max_over_mean, moe/tokens_per_expert, and
+    # the rung the dispatch took: moe/bounded_share, moe/buffer_rows
     "moe/": _num,
     # the `setup` event line's parts (obs/stepstats.py setup_account)
     "setup/": _num,

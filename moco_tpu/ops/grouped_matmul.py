@@ -6,9 +6,10 @@
 `group_sizes` (g,) says how many rows each group has; `rhs` is (g, k, n).
 Rows past `sum(group_sizes)` belong to no group: they cost nothing and
 come back as zeros. This is the product an expert layer needs once it
-has sorted its (token, expert) assignments by expert: the buffer is as
-large as the worst case, so no assignment is ever dropped, and only the
-tiles that hold rows are computed.
+has sorted its (token, expert) assignments by expert: the caller sizes
+the buffer (`models/decoder.py` takes the smallest of a short ladder that
+holds the call's live assignments, the worst case last, so none is ever
+dropped), and only the tiles that hold rows are computed.
 
 On a TPU the product is the Pallas grouped matmul that ships with JAX
 (`jax.experimental.pallas.ops.tpu.megablox`: a grid over the row tiles

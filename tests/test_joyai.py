@@ -386,6 +386,9 @@ def test_one_train_step_on_tokens_enqueues_as_the_image_path_does():
     assert float(metrics["tokens_per_step"]) == 32 + 32 + 20 + 9 + 32 + 15 + 32 + 32
     assert float(metrics["moe/tokens_per_expert"]) == pytest.approx((32 + 32 + 20 + 9) * 2 / 8)
     assert float(metrics["moe/load_max_over_mean"]) >= 1.0 and np.isfinite(float(metrics["loss"]))
+    # the step's log fields say which rung the dispatch took: at 4 x 32 tokens x 2 choices twice
+    # the even share is under one row tile, so the ladder is the worst case alone
+    assert float(metrics["moe/buffer_rows"]) == 4 * 32 * 2 and float(metrics["moe/bounded_share"]) == 0.0
 
 
 def test_the_cut_of_a_deployment_changes_counts_and_no_width():

@@ -73,15 +73,32 @@ def test_grouped_head_attention_compiles_at_16384_positions(one_chip, window):
     assert dq.shape == (1, 28, 16384, 128) and dk.shape == dv.shape == (1, 4, 16384, 128)
 
 
+_EXPERT_PRODUCTS = {  # cell: (held, hidden, the ladder's rows), expert width 768
+    "experts": (16, 2048, (16384, 131072)),
+    "reglu_experts": (8, 2560, (24576, 98304)),
+}
+
+
 @pytest.mark.parametrize(
     "rows,held,k,n",
-    [(131072, 16, 2048, 1536), (131072, 16, 768, 2048), (98304, 8, 2560, 1536), (98304, 8, 768, 2560)],
-    ids=["experts_in", "experts_out", "reglu_experts_in", "reglu_experts_out"],
+    [
+        shape
+        for held, hidden, ladder in _EXPERT_PRODUCTS.values()
+        for rows in ladder
+        for shape in ((rows, held, hidden, 1536), (rows, held, 768, hidden))
+    ],
+    ids=[
+        f"{cell}_{which}_{rows}"
+        for cell, (_, _, ladder) in _EXPERT_PRODUCTS.items()
+        for rows in ladder
+        for which in ("in", "out")
+    ],
 )
-def test_grouped_matmul_compiles_at_the_worst_case_buffer(one_chip, monkeypatch, rows, held, k, n):
-    """16 held experts over the 2 x 8192 x 8 rows of the first token
-    cell's worst case, 8 over the 16 384 x 6 of the second's: both
-    products of an expert and their gradients."""
+def test_grouped_matmul_compiles_at_every_rung_s_buffer(one_chip, monkeypatch, rows, held, k, n):
+    """16 held experts over the rungs of the first token cell's ladder
+    (twice the even share of its 2 x 8192 x 8 assignments, and all of
+    them), 8 over those of the second's 16 384 x 6: both products of an
+    expert and their gradients."""
     from moco_tpu.ops import grouped_matmul as gm
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")

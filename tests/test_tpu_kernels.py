@@ -196,3 +196,54 @@ class TestFusedIVFScan:
         # bf16-pass scores may reorder near-ties: compare neighbour SETS
         overlap = np.mean([len(set(a) & set(b)) / 5 for a, b in zip(ic, i_f)])
         assert overlap >= 0.9, overlap
+
+
+class TestExpertDispatch:
+    """`models/decoder.py`'s dispatch over the compiled grouped product,
+    at a cut share (2 of 16 experts held, 4096 tokens x 2 choices: rungs of
+    2048 and 8192 rows). On the chip the grouped product's gradient
+    leaves the rows of no group unwritten, which the CPU's does not: the
+    dispatch has to keep them out of the tokens' gradient."""
+
+    @pytest.mark.parametrize("skew,rung", [(0.0, 2048), (1.2, 8192), (30.0, 8192)], ids=str)
+    def test_output_and_gradients_match_dense(self, skew, rung):
+        import flax.linen as nn
+
+        from moco_tpu.models import decoder
+
+        class Layer(decoder.ExpertDispatch):
+            @nn.compact
+            def __call__(self, x, valid, chosen, weights):
+                return self.routed(x, valid, chosen, weights, nn.silu)
+
+        t, k, e, held, d, ff = 4096, 2, 16, 2, 256, 128
+        x, r = _rand((t, d), 40), _rand((t, d), 41)
+        logits = _rand((t, e), 42) + skew * (jnp.arange(e) == 0)
+        picked, chosen = jax.lax.top_k(logits, k)
+        weights = jax.nn.softmax(picked, axis=-1)
+        valid = jnp.arange(t) < t - 7
+        layer = Layer(experts=e, top_k=k, expert_mlp=ff, first_expert=0, experts_held=held, train=True)
+        variables = layer.init(jax.random.PRNGKey(43), x, valid, chosen, weights)
+
+        def program(params, x, weights):
+            y, mut = layer.apply({"params": params, "batch_stats": variables["batch_stats"]},
+                                 x, valid, chosen, weights, mutable=["batch_stats"])
+            return jnp.sum(y * r), mut["batch_stats"]
+
+        def dense(params, x, weights):
+            y = 0.0
+            for h in range(held):
+                gate_up = jnp.matmul(x, params["experts_in"][h], precision="highest")
+                hidden = nn.silu(gate_up[:, :ff]) * gate_up[:, ff:]
+                share = jnp.sum(jnp.where((chosen == h) & valid[:, None], weights, 0.0), axis=1)
+                y = y + share[:, None] * jnp.matmul(hidden, params["experts_out"][h], precision="highest")
+            return jnp.sum(y * r)
+
+        args = (variables["params"], x, weights)
+        (loss, stats), grads = jax.jit(jax.value_and_grad(program, (0, 1, 2), has_aux=True))(*args)
+        want_loss, want = jax.jit(jax.value_and_grad(dense, (0, 1, 2)))(*args)
+        assert float(stats["buffer_rows"]) == rung
+        # the grouped product's float32 runs as bf16 passes on the matrix unit
+        assert abs(float(loss) - float(want_loss)) <= 0.03 * abs(float(want_loss))
+        for got, ref in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+            assert float(jnp.max(jnp.abs(got - ref))) <= 0.01 * float(jnp.max(jnp.abs(ref)))
