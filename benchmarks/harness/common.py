@@ -9,7 +9,7 @@ import os
 import sys
 import time
 
-from benchmarks.harness.manifest import BENCH_DIR, REPO_ROOT
+from benchmarks.harness.manifest import BENCH_DIR, REPO_ROOT, ManifestError
 
 OUT_DIR = os.path.join(BENCH_DIR, "out")
 NO_DEVICE_EXIT = 3
@@ -122,11 +122,32 @@ def _replace_dotted(cfg, dotted: str, value):
     return dataclasses.replace(cfg, **{head: _replace_dotted(getattr(cfg, head), rest, value)})
 
 
+def weights_seed(cfg_file: dict, seed: int) -> int:
+    """The seed the timed run's weights are drawn from: `--seed`, unless
+    the configuration file carries `weights_seed`, which makes one draw of
+    the weights part of the cell (README, `configs/`: one configuration
+    has it, as a stop-gap). The key is an integer, and the file's
+    `assumed` says why it is there and what removes it."""
+    if "weights_seed" not in cfg_file:
+        return int(seed)
+    value = cfg_file["weights_seed"]
+    if type(value) is not int or value < 0:
+        raise ManifestError(f"{cfg_file.get('name')}: weights_seed is {value!r}, not a whole number")
+    if "weights_seed" not in cfg_file.get("assumed", {}):
+        raise ManifestError(f"{cfg_file.get('name')}: weights_seed has no reason under `assumed`")
+    return value
+
+
 def build_train_config(cfg_file: dict, traffic: dict, seed: int, workdir: str, rehearse: bool):
     """The program's `TrainConfig`: the preset the configuration file
     names, its `overrides`, the traffic file's `overrides` (mesh shape),
     then the seed and the workdir. A rehearsal adds each file's
-    `rehearsal.overrides` (a tiny model on the CPU)."""
+    `rehearsal.overrides` (a tiny model on the CPU). `cfg.seed` is what
+    the program draws its weights from (and the order it reads the pool
+    in, and where it cuts a document's two windows): `weights_seed` says
+    which. The pool's documents and `correct`'s sample are the caller's,
+    from `--seed` always; `correct`'s weights are `cfg.seed`'s, the timed
+    run's."""
     from moco_tpu.utils.config import PRESETS
 
     cfg = PRESETS[cfg_file["preset"]]
@@ -137,7 +158,9 @@ def build_train_config(cfg_file: dict, traffic: dict, seed: int, workdir: str, r
     for layer in layers:
         for key, value in layer.items():
             cfg = _replace_dotted(cfg, key, value)
-    return dataclasses.replace(cfg, seed=int(seed), workdir=workdir, knn_every_epochs=0)
+    return dataclasses.replace(
+        cfg, seed=weights_seed(cfg_file, seed), workdir=workdir, knn_every_epochs=0
+    )
 
 
 def merged(d: dict, rehearse: bool) -> dict:

@@ -1,7 +1,8 @@
 """`correct`: the system against the plain references, outside the window.
 
-Train cells: on weights made from the seed (the program's `create_state`,
-one jitted call) and the seeded sample the family's input module gives
+Train cells: on the weights the timed run starts from (the program's
+`create_state` from `config.seed`, one jitted call) and the sample the
+family's input module draws from `--seed`
 (`benchmarks/inputs/<name>.py::correct_views`), the system's
 own modules (`build_encoder`/`build_predictor` in the configuration's
 compute dtype, its `l2_normalize`, its loss: the fused Pallas InfoNCE
@@ -132,7 +133,9 @@ def check_train(config, ref, inputs, seed: int, sample_n: int, gradient: bool,
     from moco_tpu.ops.losses import cross_entropy, infonce_logits, l2_normalize
 
     tolerances(ref)  # a family that states no embedding limit is refused before any work
-    state, encoder, predictor = seeded_state(config, seed, inputs)
+    # the weights the timed run starts from (`config.seed`: `--seed`, or the configuration
+    # file's `weights_seed`); `seed` draws the sample
+    state, encoder, predictor = seeded_state(config, config.seed, inputs)
     cfg = config.moco
     x1, x2 = inputs.correct_views(seed, sample_n, config)
 

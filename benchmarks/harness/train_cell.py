@@ -169,10 +169,12 @@ def run(manifest, cell: dict, args, t_start: float) -> dict:
         "failed": len(nonfinite),
         "metrics": {},
         "device": {**device, "memory_peak_bytes": peak},
+        # what drew the inputs and `correct`'s sample, and what drew the weights (timed and compared)
+        "seed": args.seed, "weights_seed": config.seed,
         "compared": compared,
     }
     detail = {
-        "cell": cell["name"], "seed": args.seed, "trace": args.trace,
+        "cell": cell["name"], "seed": args.seed, "weights_seed": config.seed, "trace": args.trace,
         "window": {"t_open": t_open, "seconds": args.seconds, "lines": lines},
         "compiled_in_window": compiled_in_window, "correct_detail": check,
         "train_config": config_to_dict(config),

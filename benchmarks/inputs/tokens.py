@@ -7,7 +7,13 @@ read here and nowhere in the harness: `pool_documents` (how many seeded
 documents the pool holds), `doc_len_median` / `doc_len_sigma` /
 `doc_len_min` / `doc_len_max` (document lengths: log-normal, clipped) and
 `zipf_exponent` (token ids: rank r of the held vocabulary rows drawn with
-probability proportional to r^-exponent, so that routing is uneven). The
+probability proportional to r^-exponent, so that routing is uneven) and,
+in one cell's file as a stop-gap, `rank_seed` (which id has which rank is a
+permutation drawn from it and no longer from `--seed`: every run's
+commonest tokens then read the same embedding rows; the documents' lengths
+and the ranks they hold stay `--seed`'s. The two windows cut from a
+document are not drawn here: the program's pipeline draws them, and the
+order it reads the pool in, from `cfg.seed`). The
 vocabulary is the rows the configuration holds (`moco.lm_vocab_rows`): a
 sliced vocabulary is a smaller vocabulary, and ids are drawn from it.
 """
@@ -37,7 +43,9 @@ class TokenPool:
         # distribution; which id has which rank is a seeded permutation
         weights = np.arange(1, vocab + 1, dtype=np.float64) ** -float(traffic["zipf_exponent"])
         cdf = np.cumsum(weights / weights.sum())
-        id_of_rank = rng.permutation(vocab).astype(np.int32)
+        # (a traffic file with `rank_seed` fixes which rows the Zipf head reads; its `assumed` says why)
+        perm_rng = np.random.default_rng(int(traffic["rank_seed"])) if "rank_seed" in traffic else rng
+        id_of_rank = perm_rng.permutation(vocab).astype(np.int32)
         ranks = np.searchsorted(cdf, rng.random(int(lengths.sum())), side="left")
         tokens = id_of_rank[np.minimum(ranks, vocab - 1)]
         self._docs = np.split(tokens, np.cumsum(lengths)[:-1])

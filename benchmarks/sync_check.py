@@ -7,8 +7,9 @@ Times the same chain of dependent steps (a ResNet-50 forward and backward
 at batch 128, each step consuming the last one's parameters) twice over,
 alternating: once ended by `jax.block_until_ready` on the outputs, as the
 driver's `StepTimeProbe` does, and once by fetching the last loss to the
-host, as `bench.py` does. If the runtime returned from `block_until_ready`
-before the device had finished, the first would read shorter.
+host, as the driver's log line does (the `device_get` the benchmark's rate
+is stamped after). If the runtime returned from `block_until_ready` before
+the device had finished, the first would read shorter.
 """
 
 from __future__ import annotations

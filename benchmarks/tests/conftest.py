@@ -2,7 +2,8 @@
 
     pytest benchmarks/tests -q
 
-Tier-1 collects `tests/` only, so these neither add to nor take from its count.
+Tier-1 collects them too since PR 31, through the link `tests/benchmark_harness`:
+they count there, and one that costs more than ~30 s has to be marked `slow`.
 """
 
 import os

@@ -57,6 +57,7 @@ def test_a_run_with_an_altered_embedding_is_not_correct(monkeypatch, capsys):
     assert run.main(["--workload", "train_r50_v2", "--seed", "13", "--seconds", "3", "--rehearse"]) == 0
     result = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert result["correct"] is False and list(result)[-1] == "compared"
+    assert result["seed"] == result["weights_seed"] == 13
     beside = result["compared"]
     assert beside["emb_centred_rel_error"]["value"] > beside["emb_centred_rel_error"]["at_most"]
     assert beside["nonfinite_losses"]["value"] == 0 and beside["compiled_in_window"]["value"] == 0

@@ -9,6 +9,7 @@ import shutil
 
 import pytest
 
+from benchmarks.harness import common
 from benchmarks.harness.manifest import (
     BENCH_DIR, NAME_RE, REPO_ROOT, UNIT_RE, Manifest, ManifestError, load_module, read_layer_metrics,
 )
@@ -101,6 +102,9 @@ def test_files_found_by_name(manifest):
         cfg = manifest.config_file(w["config"])
         assert cfg["name"] == w["config"] and "preset" in cfg and "reference" in cfg
         assert sorted(cfg["reduced"]) == sorted(manifest.configs[w["config"]]["reduced"])
+        # a draw of the weights made part of the cell: a whole number, its reason under `assumed`
+        assert common.weights_seed(cfg, 7) == cfg.get("weights_seed", 7)
+        assert ("weights_seed" in cfg) == ("weights_seed" in cfg["assumed"])
         # the family's file states its input, its tolerances and its operation count, and the
         # input module has what the harness asks of a modality
         ref, inputs = manifest.family(cfg)

@@ -95,6 +95,8 @@ def test_the_cell_rehearses_correct_and_a_fault_in_the_program_does_not(fault, m
     assert result["compared"]["nonfinite_losses"]["value"] == 0
     assert set(result["compared"]) >= {"emb_centred_rel_error", "loss_abs_diff", "grad_cosine"}
     if fault is None:
+        # no `weights_seed` in this configuration: the timed run's weights are `--seed`'s
+        assert result["seed"] == result["weights_seed"] == 2147483659
         assert result["correct"] is True
         assert result["compared"]["emb_centred_rel_error"]["value"] < 1e-5
         return
@@ -237,15 +239,17 @@ def test_the_configuration_file_keeps_every_published_number_but_the_three_reduc
     built, traffic, _, _ = _family(False)
     assert built.data.seq_len == 16384 and built.data.global_batch == 1 and built.moco.remat
     assert (traffic["doc_len_median"], traffic["doc_len_min"], traffic["doc_len_max"]) == (32768, 16384, 131072)
-    assert set(traffic) == set(m.traffic_file("job_loop_tokens_8k"))  # the same keys, data only
+    # the same keys, data only (`rank_seed`, with its reason under `assumed`, is the other token
+    # cell's own fixed draw, since PR 36)
+    assert set(traffic) == set(m.traffic_file("job_loop_tokens_8k")) - {"rank_seed", "assumed"}
 
 
 def test_the_cell_came_as_new_files_and_list_entries_alone():
     """Every benchmark file the accepted benchmark had (the fixture: PR
-    32's tree; a later `benchmark` PR that edits one refreshes it) has the
-    bytes it had, and BENCHMARK.json without this PR's configuration, cell,
-    six metrics and the cell's name on the shared metrics' lists is the
-    accepted manifest. (Later PRs append theirs: only what was there is held.)"""
+    36's tree; a later `benchmark` PR that edits one refreshes it) has the
+    bytes it had, and every entry the accepted manifest had is in
+    BENCHMARK.json as it was, a metric's list of cells appended to at most.
+    (Later PRs append theirs: only what was there is held.)"""
     accepted = json.load(open(ACCEPTED))
     for path, digest in accepted["files"].items():
         with open(os.path.join(REPO_ROOT, path), "rb") as f:

@@ -38,6 +38,10 @@ def _run(capsys) -> dict:
 def test_the_cell_rehearses_correct_and_a_wrong_routing_scale_does_not(monkeypatch, capsys):
     result = _run(capsys)
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
+    # the line says what drew the documents and what drew the timed run's weights
+    fixed = Manifest().config_file("joyai_flash_ep16")["weights_seed"]
+    assert result["seed"] == 2147483659 != fixed == result["weights_seed"]
+    assert list(result)[-1] == "compared"
     assert set(result["compared"]) >= {"emb_centred_rel_error", "loss_abs_diff", "grad_cosine"}
     # the program weighs its routed experts by 1.0 where the model says 2.5
     import moco_tpu.models.joyai as joyai
@@ -85,6 +89,26 @@ def test_the_pool_is_seeded_clipped_and_skewed():
     x1, x2 = inputs.correct_views(5, 2, dataclasses.replace(cfg, data=dataclasses.replace(cfg.data, seq_len=16)))
     assert x1["ids"].shape == (2, 16) and not np.array_equal(x1["ids"], x2["ids"])
     assert list(x1["lengths"]) == [16, 16]
+
+
+def test_rank_seed_fixes_the_rows_the_commonest_ids_read_and_nothing_else():
+    """The cell's traffic file carries `rank_seed`, a whole number with its reason under the file's
+    own `assumed`: the Zipf head reads the same vocabulary rows whatever `--seed`; the documents
+    stay the seed's, and a traffic file without the key (the other token cell's) draws what it drew."""
+    cfg, traffic, _, inputs = _family(False)
+    assert type(traffic["rank_seed"]) is int and "rank_seed" in traffic["assumed"]
+    assert "rank_seed" not in Manifest().traffic_file("job_loop_tokens_16k")
+    small = {**traffic, "pool_documents": 8}
+    free = {k: v for k, v in small.items() if k != "rank_seed"}
+    fixed5, fixed6, free5, free6 = (inputs.dataset(s, t, cfg) for t in (small, free) for s in (5, 6))
+    head = lambda pool: list(np.argsort(-np.bincount(np.concatenate(pool._docs), minlength=16160))[:3])
+    assert head(fixed5) == head(fixed6) and head(free5) != head(free6) != head(fixed6)
+    lengths = lambda pool: [len(d) for d in pool._docs]
+    assert lengths(fixed5) == lengths(free5) != lengths(fixed6) == lengths(free6)
+    assert not np.array_equal(fixed5._docs[0], fixed6._docs[0][: len(fixed5._docs[0])])
+    # another number, other rows: the key is read, not its presence
+    other = inputs.dataset(5, {**small, "rank_seed": traffic["rank_seed"] + 1}, cfg)
+    assert head(other) != head(fixed5) and lengths(other) == lengths(fixed5)
 
 
 def test_operation_counts_at_the_published_widths():
