@@ -103,6 +103,7 @@ def create_backbone(cfg: MocoConfig, num_data: Optional[int] = None) -> nn.Modul
         return create_token_encoder(
             cfg.arch, dtype=dtype, layers=cfg.lm_layers, vocab_rows=cfg.lm_vocab_rows,
             expert_share=tuple(cfg.expert_share) or None, remat=cfg.remat,
+            first_layer=cfg.lm_first_layer,
         )
     if cfg.vit_sequence_parallel and not cfg.arch.startswith("vit"):
         # must fail HERE, not just in the vit branch: v3_step keys its

@@ -1,5 +1,6 @@
 """What every decoder stack read as a text encoder needs, whatever the
-family: the half `models/joyai.py` and `models/smallthinker.py` share.
+family: the half `models/joyai.py`, `models/smallthinker.py` and
+`models/phi4flash.py` share.
 
 - `RMSNorm` and the bias-free `dense`;
 - **the expert dispatch** (`ExpertDispatch.routed`): an expert layer is
@@ -20,9 +21,13 @@ family: the half `models/joyai.py` and `models/smallthinker.py` share.
   experts' load, the rung taken and the share's first expert live in
   `batch_stats`, the collection the train step already carries;
 - **the backbone skeleton** (`DecoderBackbone`): embed the ids, run the
-  family's blocks, final RMSNorm, the mean over a row's valid positions;
+  family's blocks, the family's final norm (RMSNorm unless it says
+  otherwise), the mean over a row's valid positions. A block may leave
+  state for later blocks (`run_block`'s `carry`), and a cut may start at a
+  published layer other than the first (`first_layer`);
 - **the remat policy** (`remat_block`): a block recomputed in the backward
-  pass, which keeps nothing but the causal kernels' two outputs;
+  pass, which keeps nothing but the causal kernels' and the selective
+  scan's outputs;
 - `routing_metrics`: what a log line says of the routing.
 
 A family's file keeps its attention, its routing function, its block and
@@ -42,6 +47,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from moco_tpu.ops.flash_attention import CAUSAL_SAVED_NAMES
+from moco_tpu.ops.selective_scan import SCAN_SAVED_NAMES
 from moco_tpu.ops.grouped_matmul import TILE_ROWS, grouped_matmul
 
 RMS_EPS = 1e-6
@@ -195,10 +201,15 @@ def valid_positions(lengths: jax.Array, seq_len: int) -> jax.Array:
 
 def remat_block(block_cls):
     """A block recomputed in the backward pass, which keeps nothing but
-    the causal kernel's two outputs. A short sequence takes the dense
-    product, names nothing, and is recomputed whole."""
+    the causal kernel's two outputs and the selective scan's (its output
+    and the state entering each chunk): with them the forward kernels are
+    dead code in the recomputation. A short sequence takes the dense
+    attention product, names nothing, and is recomputed whole. What a
+    block receives from an earlier one (`DecoderBackbone.run_block`'s
+    carry) is its argument, so it is kept and never recomputed here."""
     return nn.remat(
-        block_cls, policy=jax.checkpoint_policies.save_only_these_names(*CAUSAL_SAVED_NAMES)
+        block_cls,
+        policy=jax.checkpoint_policies.save_only_these_names(*CAUSAL_SAVED_NAMES, *SCAN_SAVED_NAMES),
     )
 
 
@@ -213,7 +224,9 @@ class DecoderBackbone(nn.Module):
     of the forward kernel and cost far less to hold (136 MB a layer at 2 x
     8192 tokens and 32 heads of 128) than to compute again (a third
     forward kernel a layer). A family derives from it and says what its
-    i-th block is (`block`)."""
+    i-th block is (`block`); `first_layer` is the published index of the
+    first block held (a pipeline stage past the first), for a family whose
+    layers differ by depth."""
 
     cfg: Any  # the family's sizes: `hidden` is read here
     layers: int
@@ -225,9 +238,20 @@ class DecoderBackbone(nn.Module):
     # the embedding's initial standard deviation: the family's (a derived
     # backbone may state another; no run reads a published weight)
     embed_std: float = 0.02
+    first_layer: int = 0
 
     def block(self, i: int, train: bool) -> nn.Module:
         raise NotImplementedError
+
+    def run_block(self, i: int, train: bool, x, lengths, carry: dict):
+        """Block i on the residual stream -> (x, carry). `carry` holds what
+        earlier blocks left for later ones; a family whose blocks share
+        nothing leaves it empty."""
+        return self.block(i, train)(x, lengths), carry
+
+    def norm(self, name: str) -> nn.Module:
+        """The family's final norm, float32."""
+        return RMSNorm(jnp.float32, name=name)
 
     @nn.compact
     def __call__(self, inputs, train: bool = True, group: Optional[str] = None):
@@ -238,9 +262,10 @@ class DecoderBackbone(nn.Module):
             self.vocab_rows, self.cfg.hidden, dtype=self.dtype,
             embedding_init=nn.initializers.normal(self.embed_std), name="embed",
         )(ids)
+        carry = {}
         for i in range(self.layers):
-            x = self.block(i, train)(x, lengths)
-        x = RMSNorm(jnp.float32, name="final_norm")(x)
+            x, carry = self.run_block(i, train, x, lengths, carry)
+        x = self.norm("final_norm")(x)
         valid = valid_positions(lengths, x.shape[1])[..., None]
         total = jnp.sum(jnp.where(valid, x, 0.0), axis=1)
         return total / jnp.maximum(lengths, 1)[:, None].astype(jnp.float32)
@@ -255,18 +280,31 @@ def create_stack(
     vocab_rows: Optional[int] = None,
     expert_share: Optional[tuple] = None,
     remat: bool = False,
+    first_layer: int = 0,
 ):
     """A family's backbone at its cut of a deployment: `None` means as
-    published (every layer, every vocabulary row, every expert)."""
+    published (every layer from `first_layer` on, every vocabulary row,
+    every expert). A family with no routed experts (`cfg` has no
+    `experts`) takes no share."""
     if arch not in configs:
         raise ValueError(f"unknown arch {arch!r}; choose from {sorted(configs)}")
     cfg = configs[arch]
-    first, held = expert_share or (0, cfg.experts)
-    if not (0 <= first < cfg.experts and 0 < held <= cfg.experts):
-        raise ValueError(f"expert share {(first, held)} outside the {cfg.experts} routed experts")
+    experts = getattr(cfg, "experts", 0)
+    if experts:
+        first, held = expert_share or (0, experts)
+        if not (0 <= first < experts and 0 < held <= experts):
+            raise ValueError(f"expert share {(first, held)} outside the {experts} routed experts")
+    elif expert_share:
+        raise ValueError(f"{arch!r} has no routed experts to share, got {expert_share}")
+    else:
+        first, held = 0, 0
+    layers = layers or cfg.layers - first_layer
+    if not (0 <= first_layer and first_layer + layers <= cfg.layers):
+        raise ValueError(f"layers {first_layer}..{first_layer + layers - 1} outside the {cfg.layers} published")
     return backbone_cls(
-        cfg=cfg, layers=layers or cfg.layers, vocab_rows=vocab_rows or cfg.vocab_size,
+        cfg=cfg, layers=layers, vocab_rows=vocab_rows or cfg.vocab_size,
         first_expert=int(first), experts_held=int(held), remat=remat, dtype=dtype,
+        first_layer=int(first_layer),
     )
 
 

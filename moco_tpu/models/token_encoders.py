@@ -3,11 +3,13 @@
 from __future__ import annotations
 
 from moco_tpu.models.joyai import _JOYAI_CONFIGS, create_joyai
+from moco_tpu.models.phi4flash import _PHI4FLASH_CONFIGS, create_phi4flash
 from moco_tpu.models.smallthinker import _SMALLTHINKER_CONFIGS, create_smallthinker
 
 _CREATE = {
     **dict.fromkeys(_JOYAI_CONFIGS, create_joyai),
     **dict.fromkeys(_SMALLTHINKER_CONFIGS, create_smallthinker),
+    **dict.fromkeys(_PHI4FLASH_CONFIGS, create_phi4flash),
 }
 
 

@@ -692,13 +692,7 @@ def _causal_specs(block_q: int, block_k: int, d_qk: int, d_v: int, group: int,
 _SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _kernel_name(window: Optional[int], which: str) -> str:
-    """What a trace finds the `pallas_call` by: a call with a window is
-    another program than one without."""
-    return f"{'causal' if window is None else 'window'}_attention_{which}"
-
-
-def _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret, window):
+def _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret, window, name):
     b, h, s, d_qk = q.shape
     h_k, d_v = k.shape[1], v.shape[-1]
     bh = b * h
@@ -726,12 +720,13 @@ def _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret, window
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
-        name=_kernel_name(window, "fwd"),
+        name=f"{name}_fwd",
     )(kv_lens, q.reshape(bh, s, d_qk), k.reshape(b * h_k, s, d_qk), v.reshape(b * h_k, s, d_v))
     return out.reshape(b, h, s, d_v), lse
 
 
-def _causal_backward(q, k, v, kv_lens, out, lse, g, scale, block_q, block_k, interpret, window):
+def _causal_backward(q, k, v, kv_lens, out, lse, g, scale, block_q, block_k, interpret, window,
+                     name):
     b, h, s, d_qk = q.shape
     h_k, d_v = k.shape[1], v.shape[-1]
     bh, bh_k, group = b * h, b * h_k, h // h_k
@@ -756,7 +751,7 @@ def _causal_backward(q, k, v, kv_lens, out, lse, g, scale, block_q, block_k, int
         out_shape=jax.ShapeDtypeStruct((bh, s, d_qk), q.dtype),
         compiler_params=_SEMANTICS,
         interpret=interpret,
-        name=_kernel_name(window, "dq"),
+        name=f"{name}_dq",
     )(kv_lens, q3, g3, lse, delta, k3, v3)
 
     # key block outside, one grid cell a KEY head: the query blocks of its
@@ -805,33 +800,33 @@ def _causal_backward(q, k, v, kv_lens, out, lse, g, scale, block_q, block_k, int
         ],
         compiler_params=_SEMANTICS,
         interpret=interpret,
-        name=_kernel_name(window, "dkv"),
+        name=f"{name}_dkv",
     )(kv_lens, k3, v3, q3, g3, lse, delta)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _causal_flash(q, k, v, kv_lens, scale, block_q, block_k, interpret, window):
-    return _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret, window)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _causal_flash(q, k, v, kv_lens, scale, block_q, block_k, interpret, window, name):
+    return _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret, window, name)[0]
 
 
-def _causal_fwd(q, k, v, kv_lens, scale, block_q, block_k, interpret, window):
+def _causal_fwd(q, k, v, kv_lens, scale, block_q, block_k, interpret, window, name):
     """The forward rule. The kernel's two outputs carry `CAUSAL_SAVED_NAMES`,
     so a `jax.checkpoint` whose policy saves those names keeps them, and the
     forward kernel is dead code in its recomputation: what the backward
     kernels need arrives saved. Outside such a policy a name is an identity.
     The log-sum-exp is named without its unit axis: (B*H, S, 1) float32 pads
     the 1 to a tile's 128 lanes in HBM, 128 times the bytes of (B*H, S)."""
-    out, lse = _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret, window)
+    out, lse = _causal_forward(q, k, v, kv_lens, scale, block_q, block_k, interpret, window, name)
     out = checkpoint_name(out, CAUSAL_SAVED_NAMES[0])
     lse = checkpoint_name(lse.reshape(lse.shape[:2]), CAUSAL_SAVED_NAMES[1])
     return out, (q, k, v, kv_lens, out, lse)
 
 
-def _causal_bwd(scale, block_q, block_k, interpret, window, res, g):
+def _causal_bwd(scale, block_q, block_k, interpret, window, name, res, g):
     q, k, v, kv_lens, out, lse = res
     dq, dk, dv = _causal_backward(
-        q, k, v, kv_lens, out, lse[..., None], g, scale, block_q, block_k, interpret, window
+        q, k, v, kv_lens, out, lse[..., None], g, scale, block_q, block_k, interpret, window, name
     )
     return dq, dk, dv, None
 
@@ -849,6 +844,7 @@ def causal_flash_attention(
     block_k: int = CAUSAL_BLOCK,
     interpret: bool = False,
     window: Optional[int] = None,  # key p is visible to query t iff t - p < window
+    name: Optional[str] = None,  # the kernels' names are `<name>_{fwd,dq,dkv}`
 ) -> jax.Array:
     """Causal attention (B, H, S, Dv); differentiable in q, k and v. The
     Pallas kernels run where the sequence is long enough to need them and
@@ -856,7 +852,9 @@ def causal_flash_attention(
     (blocks given by the caller always mean the kernels: a test's way to
     reach them at a test's length). The group and the window are the
     layer's own and static: with one key head a query head and no window
-    the kernels are the programs they were without either."""
+    the kernels are the programs they were without either. `name`: what a
+    trace finds this caller's three `pallas_call`s by (default
+    `causal_attention_*`, or `window_attention_*` under a window)."""
     s = q.shape[2]
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     kv_lens = kv_lens.astype(jnp.int32)
@@ -864,8 +862,11 @@ def causal_flash_attention(
         raise ValueError(f"{k.shape[1]} key and {v.shape[1]} value heads under {q.shape[1]} query heads")
     if window is not None and window < 1:
         raise ValueError(f"a window holds at least the query's own key, got {window}")
+    # what a trace finds the three `pallas_call`s by: a call with a window is
+    # another program than one without
+    name = name or ("causal" if window is None else "window") + "_attention"
     if s < CAUSAL_MIN_SEQ and (block_q, block_k) == (CAUSAL_BLOCK, CAUSAL_BLOCK):
         return _causal_attn_reference(q, k, v, kv_lens, scale, window)
     if s % block_q or s % block_k:
         raise ValueError(f"sequence length {s} is not a multiple of blocks {block_q}, {block_k}")
-    return _causal_flash(q, k, v, kv_lens, scale, block_q, block_k, interpret, window)
+    return _causal_flash(q, k, v, kv_lens, scale, block_q, block_k, interpret, window, name)

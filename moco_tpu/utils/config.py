@@ -136,10 +136,13 @@ class MocoConfig:
     # the layers of this pipeline stage (None = as published), the rows of
     # the vocabulary held here (None = all), and this chip's share of each
     # expert layer as (first_expert, experts_held) (() = every expert).
-    # Every width stays the arch's own.
+    # Every width stays the arch's own. `lm_first_layer`: the published
+    # index of the stage's first layer (a family whose layers differ by
+    # depth builds the stage's own).
     lm_layers: Optional[int] = None
     lm_vocab_rows: Optional[int] = None
     expert_share: Tuple[int, ...] = ()
+    lm_first_layer: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -719,6 +722,33 @@ PRESETS = {
     "smallthinker_tiny": TrainConfig(
         moco=MocoConfig(
             arch="smallthinker_tiny", mlp=True, temperature=0.05, momentum=0.9995,
+            num_negatives=256, shuffle="none", compute_dtype="float32",
+        ),
+        optim=OptimConfig(optimizer="adamw", lr=5e-5, weight_decay=0.01, epochs=1, cos=True),
+        data=DataConfig(dataset="synthetic", input="tokens", seq_len=64, global_batch=4),
+    ),
+    # A third decoder stack on the same path (moco_tpu/models/phi4flash.py:
+    # Phi-4-mini-flash-reasoning, SambaY: Mamba layers on the selective-scan
+    # kernel, differential attention over window, full and cross-decoder
+    # layers, gated memory units): the same recipe over two independent
+    # 16 384-token windows, one row a chip. As published it is 3.8 B
+    # parameters: a run states its cut as above, and where its stage starts
+    # (moco.lm_first_layer: benchmarks/configs/phi4_mini_flash_stage5.json
+    # holds published layers 15-19).
+    "phi4_mini_flash": TrainConfig(
+        moco=MocoConfig(
+            arch="phi4_mini_flash", mlp=True, temperature=0.05, momentum=0.9995,
+            shuffle="none", remat=True,
+        ),
+        optim=OptimConfig(
+            optimizer="adamw", lr=5e-5, weight_decay=0.01, epochs=25, cos=True, warmup_epochs=1
+        ),
+        data=DataConfig(dataset="synthetic", input="tokens", seq_len=16384, global_batch=1),
+    ),
+    # the same stack and path at a test's size, for the CPU
+    "phi4_flash_tiny": TrainConfig(
+        moco=MocoConfig(
+            arch="phi4_flash_tiny", mlp=True, temperature=0.05, momentum=0.9995,
             num_negatives=256, shuffle="none", compute_dtype="float32",
         ),
         optim=OptimConfig(optimizer="adamw", lr=5e-5, weight_decay=0.01, epochs=1, cos=True),

@@ -149,3 +149,46 @@ def test_a_remat_block_keeps_what_its_attention_backward_needs(one_chip, monkeyp
     text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(params, x, lens).compile().as_text()
     for name in ("causal_attention_fwd", "causal_attention_dq", "causal_attention_dkv"):
         assert len(re.findall(rf"custom-call\(.*{name}", text)) == 1, name
+
+
+@pytest.mark.parametrize("window", [None, 512], ids=["full_and_cross_layers", "window_layer"])
+def test_differential_attention_compiles_at_16384_positions(one_chip, window):
+    """One softmax of a differential layer as the third token cell calls
+    it: 1 row x 20 query pairs of 64 over 10 key pairs of 64 and 10 value
+    heads of 128, bfloat16, under the family's own kernel names."""
+    from moco_tpu.ops.flash_attention import causal_flash_attention
+
+    q = _shape(one_chip, (1, 20, 16384, 64), jnp.bfloat16)
+    k = _shape(one_chip, (1, 10, 16384, 64), jnp.bfloat16)
+    v = _shape(one_chip, (1, 10, 16384, 128), jnp.bfloat16)
+    lens = _shape(one_chip, (1,), jnp.int32)
+
+    def f(q, k, v, lens):
+        loss = lambda q, k, v: jnp.sum(causal_flash_attention(
+            q, k, v, lens, scale=0.125, window=window, name="diff_attention").astype(jnp.float32))
+        return jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+
+    text = jax.jit(f).lower(q, k, v, lens).compile().as_text()
+    for which in ("fwd", "dq", "dkv"):
+        assert f"diff_attention_{which}" in text
+    assert "causal_attention_fwd" not in text and "window_attention_fwd" not in text
+
+
+def test_the_selective_scan_compiles_at_the_published_width(one_chip):
+    """Forward and backward of the Mamba scan at 1 row x 16 384 positions x
+    5120 channels x 16 states: the tiles fit the chip's fast memory."""
+    from moco_tpu.ops.selective_scan import selective_scan
+
+    x = _shape(one_chip, (1, 16384, 5120), jnp.bfloat16)
+    dt = _shape(one_chip, (1, 16384, 5120), jnp.float32)
+    a_log = _shape(one_chip, (5120, 16), jnp.float32)
+    bc = _shape(one_chip, (1, 16384, 16), jnp.float32)
+    d = _shape(one_chip, (5120,), jnp.float32)
+    lens = _shape(one_chip, (1,), jnp.int32)
+
+    def f(x, dt, a_log, b, c, d, lens):
+        loss = lambda *a: jnp.sum(selective_scan(*a, lens).astype(jnp.float32))
+        return jax.value_and_grad(loss, tuple(range(6)))(x, dt, a_log, b, c, d)
+
+    text = jax.jit(f).lower(x, dt, a_log, bc, bc, d, lens).compile().as_text()
+    assert "selective_scan_fwd" in text and "selective_scan_bwd" in text

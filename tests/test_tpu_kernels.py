@@ -247,3 +247,60 @@ class TestExpertDispatch:
         assert abs(float(loss) - float(want_loss)) <= 0.03 * abs(float(want_loss))
         for got, ref in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
             assert float(jnp.max(jnp.abs(got - ref))) <= 0.01 * float(jnp.max(jnp.abs(ref)))
+
+
+class TestSelectiveScan:
+    """The Mamba scan's two kernels compiled, against the sequential
+    recurrence: 8 chunks and 2 channel blocks, a row shorter than the
+    others, forward and all six gradients."""
+
+    def test_forward_and_gradients_match_the_recurrence(self):
+        from moco_tpu.ops.selective_scan import selective_scan, selective_scan_reference
+
+        bt, length, width, states = 2, 1024, 1024, 16
+        x = _rand((bt, length, width), 50)
+        dt = jax.nn.softplus(_rand((bt, length, width), 51) - 2.0)
+        a_log = jnp.log(jnp.broadcast_to(jnp.arange(1, states + 1, dtype=jnp.float32), (width, states)))
+        b, c = _rand((bt, length, states), 52), _rand((bt, length, states), 53)
+        d, gy = _rand((width,), 54), _rand((bt, length, width), 55)
+        lens = jnp.asarray([length, 700], jnp.int32)
+        valid = (jnp.arange(length)[None] < lens[:, None])[..., None]
+
+        def loss(fn):
+            return lambda *a: jnp.sum(jnp.where(valid, fn(*a, lens), 0.0) * gy)
+
+        args = (x, dt, a_log, b, c, d)
+        got = jax.jit(jax.value_and_grad(loss(selective_scan), tuple(range(6))))(*args)
+        want = jax.jit(jax.value_and_grad(loss(selective_scan_reference), tuple(range(6))))(*args)
+        assert abs(float(got[0]) - float(want[0])) <= 1e-4 * float(jnp.sum(jnp.abs(gy)))
+        for name, g, r in zip(("x", "dt", "a_log", "b", "c", "d"), got[1], want[1]):
+            assert float(jnp.max(jnp.abs(g - r))) <= 1e-3 * float(jnp.max(jnp.abs(r))), name
+
+
+class TestDiffAttention:
+    """The causal kernels as the differential layers call them: 64-wide q
+    and k, 128-wide v, two query heads a key head, with and without the
+    512-key window, under their own names."""
+
+    @pytest.mark.parametrize("window", [None, 512], ids=["full", "window"])
+    def test_kernels_match_dense(self, window):
+        from moco_tpu.ops.flash_attention import _causal_attn_reference, causal_flash_attention
+
+        q = _rand((1, 4, 2048, 64), 60, jnp.bfloat16)
+        k = _rand((1, 2, 2048, 64), 61, jnp.bfloat16)
+        v = _rand((1, 2, 2048, 128), 62, jnp.bfloat16)
+        g = _rand((1, 4, 2048, 128), 63)
+        lens = jnp.asarray([1900], jnp.int32)
+
+        def loss(fn, **kw):
+            return lambda q, k, v: jnp.sum(fn(q, k, v, lens, 0.125, **kw).astype(jnp.float32)[..., :1900, :] * g[..., :1900, :])
+
+        got = jax.jit(jax.value_and_grad(loss(causal_flash_attention, window=window, name="diff_attention"), (0, 1, 2)))
+        want = jax.jit(jax.value_and_grad(loss(_causal_attn_reference, window=window), (0, 1, 2)))
+        (lg, gg), (lw, gw) = got(q, k, v), want(q, k, v)
+        assert abs(float(lg) - float(lw)) <= 0.02 * float(jnp.sum(jnp.abs(g)))
+        for a, b in zip(gg, gw):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            assert float(jnp.max(jnp.abs(a - b))) <= 0.05 * float(jnp.max(jnp.abs(b)))
+        text = jax.jit(got).lower(q, k, v).compile().as_text()
+        assert all(f"diff_attention_{w}" in text for w in ("fwd", "dq", "dkv"))
