@@ -6,6 +6,9 @@ The reference decodes and augments per-image in 32 DataLoader worker
 processes (PIL C code). On TPU the elementwise augmentation work
 (jitter/grayscale/blur/flip/normalize) fuses into one XLA program and runs
 on-device on the whole batch, leaving the host only JPEG decode + crop.
+Where the images fill a lane tile (224 px), the v2 recipe's colour stage,
+jitter then grayscale, is one Pallas kernel in that program
+(`colour_stage`, `ops/colour_jitter.py`) with the batched ops' draws.
 Every op takes images in [0, 1] float, NHWC, and a per-call PRNG key; all
 randomness is per-example (`jax.vmap` over split keys) except where noted.
 
@@ -40,6 +43,12 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import Mesh
+
+from moco_tpu.ops.colour_jitter import blend, hue_planes, luma
+from moco_tpu.ops.colour_jitter import colour_jitter as colour_kernel
+from moco_tpu.ops.colour_jitter import fits as colour_kernel_fits
+from moco_tpu.utils.platform import pallas_interpret
 
 IMAGENET_MEAN = (0.485, 0.456, 0.406)
 IMAGENET_STD = (0.229, 0.224, 0.225)
@@ -149,35 +158,25 @@ def center_crop(images: jax.Array, out_size: int, resize_to: int = 256) -> jax.A
 # ------------------------------------------------------------ color ops
 
 
-def _blend(a: jax.Array, b: jax.Array, factor: jax.Array) -> jax.Array:
-    """torchvision _blend: factor*a + (1-factor)*b, clipped to [0,1]."""
-    return jnp.clip(factor * a + (1.0 - factor) * b, 0.0, 1.0)
-
-
-def _luma(r: jax.Array, g: jax.Array, b: jax.Array) -> jax.Array:
-    """ITU-R 601 luma, as PIL convert('L') uses."""
-    return 0.299 * r + 0.587 * g + 0.114 * b
-
-
 def _planes(img: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
     return img[..., 0], img[..., 1], img[..., 2]
 
 
 def _rgb_to_gray(img: jax.Array) -> jax.Array:
-    return _luma(*_planes(img))[..., None]
+    return luma(*_planes(img))[..., None]
 
 
 def adjust_brightness(img, factor):
-    return _blend(img, jnp.zeros_like(img), factor)
+    return blend(img, jnp.zeros_like(img), factor)
 
 
 def adjust_contrast(img, factor):
     mean = jnp.mean(_rgb_to_gray(img), axis=(-3, -2, -1), keepdims=True)
-    return _blend(img, mean, factor)
+    return blend(img, mean, factor)
 
 
 def adjust_saturation(img, factor):
-    return _blend(img, _rgb_to_gray(img), factor)
+    return blend(img, _rgb_to_gray(img), factor)
 
 
 def adjust_hue(img, delta):
@@ -191,55 +190,37 @@ def adjust_hue(img, delta):
     # delta arrives (B,1,1,1); drop the channel dim so it broadcasts
     # against the (B,H,W) planes.
     d = jnp.reshape(delta, delta.shape[:-1]) if delta.ndim == img.ndim else delta
-    return jnp.stack(_hue_planes(*_planes(img), d), axis=-1)
-
-
-def _hue_planes(r, g, b, d):
-    """`adjust_hue` on (B,H,W) channel planes, `d` broadcastable to one."""
-    maxc = jnp.maximum(jnp.maximum(r, g), b)
-    minc = jnp.minimum(jnp.minimum(r, g), b)
-    v = maxc
-    c = maxc - minc
-    s = jnp.where(maxc > 0, c / jnp.where(maxc > 0, maxc, 1.0), 0.0)
-    safe_c = jnp.where(c > 0, c, 1.0)
-    rc = (maxc - r) / safe_c
-    gc = (maxc - g) / safe_c
-    bc = (maxc - b) / safe_c
-    h = jnp.where(
-        r == maxc, bc - gc, jnp.where(g == maxc, 2.0 + rc - bc, 4.0 + gc - rc)
-    )
-    h = jnp.where(c > 0, (h / 6.0) % 1.0, 0.0)
-    h = (h + d) % 1.0
-
-    # HSV -> RGB (colorsys sextant form)
-    h6 = h * 6.0
-    i = jnp.floor(h6)
-    f = h6 - i
-    p = v * (1.0 - s)
-    q = v * (1.0 - s * f)
-    t = v * (1.0 - s * (1.0 - f))
-    i = i.astype(jnp.int32) % 6
-
-    def by_sextant(*choices):
-        # nested where: `jnp.select` lowers to a concatenate and an argmax over
-        # six stacked (B,H,W) conditions, three passes of their own on the chip
-        out = choices[5]
-        for n in (4, 3, 2, 1, 0):
-            out = jnp.where(i == n, choices[n], out)
-        return jnp.clip(out, 0.0, 1.0)
-
-    return by_sextant(v, q, p, p, t, v), by_sextant(t, v, v, q, p, p), by_sextant(p, p, t, v, v, q)
+    return jnp.stack(hue_planes(*_planes(img), d), axis=-1)
 
 
 def _blend_slot(planes, factor, alpha, beta):
     """One slot of `color_jitter` on (B,H,W) channel planes:
-    `_blend(c, alpha*luma + beta*mean(luma), factor)` for each channel c,
+    `blend(c, alpha*luma + beta*mean(luma), factor)` for each channel c,
     with per-image (B,1,1) parameters. (alpha, beta) = (0, 0) is
     brightness, (0, 1) contrast, (1, 0) saturation; with factor 1 as well
     it returns the planes (in [0,1]) bit for bit."""
-    gray = _luma(*planes)
+    gray = luma(*planes)
     base = alpha * gray + beta * jnp.mean(gray, axis=(-2, -1), keepdims=True)
-    return tuple(_blend(c, base, factor) for c in planes)
+    return tuple(blend(c, base, factor) for c in planes)
+
+
+def _jitter_draws(rng, b, brightness, contrast, saturation, hue, apply_prob):
+    """`color_jitter`'s per-image draws from its key: the (B, 4) order of
+    the op indices (0 brightness, 1 contrast, 2 saturation, 3 hue), the
+    four (B, 1, 1, 1) factors in that index order (hue's is its delta),
+    and the (B, 1, 1, 1) RandomApply flag (all kept at `apply_prob` 1)."""
+    k_order, k_apply, kb, kc, ks, kh = jax.random.split(rng, 6)
+    fb = jax.random.uniform(kb, (b, 1, 1, 1), minval=max(0.0, 1 - brightness), maxval=1 + brightness)
+    fc = jax.random.uniform(kc, (b, 1, 1, 1), minval=max(0.0, 1 - contrast), maxval=1 + contrast)
+    fs = jax.random.uniform(ks, (b, 1, 1, 1), minval=max(0.0, 1 - saturation), maxval=1 + saturation)
+    fh = jax.random.uniform(kh, (b, 1, 1, 1), minval=-hue, maxval=hue)
+    # independent per-image permutations, as torchvision's randperm(4) per call
+    order = jnp.argsort(jax.random.uniform(k_order, (b, 4)), axis=1)
+    if apply_prob < 1.0:
+        keep = jax.random.bernoulli(k_apply, apply_prob, (b, 1, 1, 1))
+    else:
+        keep = jnp.ones((b, 1, 1, 1), bool)
+    return order, (fb, fc, fs, fh), keep
 
 
 def color_jitter(
@@ -260,7 +241,7 @@ def color_jitter(
     Each image's order is evaluated once, fully batched, on the three
     channel planes: brightness, contrast and saturation are one blend with
     per-image parameters (`_blend_slot`), and the HSV round trip
-    (`_hue_planes`) runs once on the batch between three leading and three
+    (`hue_planes`) runs once on the batch between three leading and three
     trailing blend slots. An image whose order has hue at position p takes
     its p earlier ops in the leading slots and its 3 - p later ones in the
     trailing slots; its other slots are the identity. (Computing all four candidates in each of four slots and
@@ -269,15 +250,7 @@ def color_jitter(
     device time.) `hue == 0` is static: no round trip, three slots.
     """
     b = images.shape[0]
-    k_order, k_apply, kb, kc, ks, kh = jax.random.split(rng, 6)
-    fb = jax.random.uniform(kb, (b, 1, 1, 1), minval=max(0.0, 1 - brightness), maxval=1 + brightness)
-    fc = jax.random.uniform(kc, (b, 1, 1, 1), minval=max(0.0, 1 - contrast), maxval=1 + contrast)
-    fs = jax.random.uniform(ks, (b, 1, 1, 1), minval=max(0.0, 1 - saturation), maxval=1 + saturation)
-    fh = jax.random.uniform(kh, (b, 1, 1, 1), minval=-hue, maxval=hue)
-
-    # (B, 4) independent per-image permutations of the op indices
-    # (0 brightness, 1 contrast, 2 saturation, 3 hue).
-    order = jnp.argsort(jax.random.uniform(k_order, (b, 4)), axis=1)
+    order, (fb, fc, fs, fh), keep = _jitter_draws(rng, b, brightness, contrast, saturation, hue, apply_prob)
     hue_pos = jnp.argmax(order == 3, axis=1)[:, None]
     # (B, 3): each image's three blends in its drawn order (its order with
     # hue taken out), their factors, and whether blend j comes before hue.
@@ -300,13 +273,12 @@ def color_jitter(
     planes = _planes(images)
     if hue > 0:
         planes = blend_slots(planes, before_hue)
-        planes = _hue_planes(*planes, fh[..., 0])
+        planes = hue_planes(*planes, fh[..., 0])
         planes = blend_slots(planes, ~before_hue)
     else:
         planes = blend_slots(planes, jnp.ones_like(before_hue))
     out = jnp.stack(planes, axis=-1)
     if apply_prob < 1.0:
-        keep = jax.random.bernoulli(k_apply, apply_prob, (b, 1, 1, 1))
         out = jnp.where(keep, out, images)
     return out
 
@@ -316,6 +288,34 @@ def random_grayscale(rng: jax.Array, images: jax.Array, prob: float = 0.2) -> ja
     gray = jnp.broadcast_to(_rgb_to_gray(images), images.shape)
     take = jax.random.bernoulli(rng, prob, (b, 1, 1, 1))
     return jnp.where(take, gray, images)
+
+
+def colour_stage(
+    k_jit: jax.Array,
+    k_gray: jax.Array,
+    images: jax.Array,
+    jitter: tuple[float, float, float, float],
+    jitter_prob: float,
+    grayscale_prob: float,
+    mesh: Mesh | None = None,
+) -> jax.Array:
+    """`color_jitter(k_jit, ...)` then `random_grayscale(k_gray, ...)`, the
+    v2 recipe's colour stage, with the same draws. Images whose planes fill
+    a lane tile take one kernel that holds each image in VMEM
+    (`ops/colour_jitter.py`), run on each device's own images where `mesh`
+    shards them; smaller ones compose the two batched ops."""
+    b, h, w, _ = images.shape
+    if not colour_kernel_fits(h, w):
+        x = color_jitter(k_jit, images, *jitter, apply_prob=jitter_prob)
+        return random_grayscale(k_gray, x, grayscale_prob)
+    order, factors, keep = _jitter_draws(k_jit, b, *jitter, jitter_prob)
+    factor = jnp.take_along_axis(jnp.concatenate(factors, axis=1)[:, :, 0, 0], order, axis=1)
+    gray = jax.random.bernoulli(k_gray, grayscale_prob, (b, 1, 1, 1))
+    planes = colour_kernel(
+        jnp.moveaxis(images, -1, 0), order, factor, keep.reshape(b), gray.reshape(b),
+        hue=jitter[3] > 0, mesh=mesh, interpret=pallas_interpret(),
+    )
+    return jnp.moveaxis(planes, 0, -1)
 
 
 # ---------------------------------------------------------------- blur
@@ -404,9 +404,11 @@ CROPS_ONLY_RECIPE = AugRecipe("probe", True, (0.0, 0.0, 0.0, 0.0), 0.0, 0.0, 0.0
 
 
 def apply_recipe(
-    recipe: AugRecipe, rng: jax.Array, images: jax.Array, out_size: int
+    recipe: AugRecipe, rng: jax.Array, images: jax.Array, out_size: int,
+    mesh: Mesh | None = None,
 ) -> jax.Array:
-    """One view. `images` float [0,1] NHWC, any (H, W) ≥ out_size."""
+    """One view. `images` float [0,1] NHWC, any (H, W) ≥ out_size, their
+    rows sharded over `mesh`'s data axis where one is given."""
     k_crop, k_jit, k_gray, k_blur, k_flip = jax.random.split(rng, 5)
     x = images
     if recipe.crop:
@@ -419,8 +421,9 @@ def apply_recipe(
         pass  # crop + flip + normalize only
     else:
         # v2 order: crop, jitter(p=0.8), grayscale, blur, flip (~L228-240)
-        x = color_jitter(k_jit, x, *recipe.jitter, apply_prob=recipe.jitter_prob)
-        x = random_grayscale(k_gray, x, recipe.grayscale_prob)
+        x = colour_stage(
+            k_jit, k_gray, x, recipe.jitter, recipe.jitter_prob, recipe.grayscale_prob, mesh
+        )
         if recipe.blur_prob > 0:
             x = gaussian_blur(k_blur, x, apply_prob=recipe.blur_prob)
     x = random_horizontal_flip(k_flip, x)
@@ -428,14 +431,15 @@ def apply_recipe(
 
 
 def two_crop_augment(
-    recipe: AugRecipe, rng: jax.Array, images: jax.Array, out_size: int
+    recipe: AugRecipe, rng: jax.Array, images: jax.Array, out_size: int,
+    mesh: Mesh | None = None,
 ) -> dict[str, jax.Array]:
     """TwoCropsTransform (`moco/loader.py:~L10-20`): the same recipe applied
     twice with independent randomness → query and key views."""
     k_q, k_k = jax.random.split(rng)
     return {
-        "im_q": apply_recipe(recipe, k_q, images, out_size),
-        "im_k": apply_recipe(recipe, k_k, images, out_size),
+        "im_q": apply_recipe(recipe, k_q, images, out_size, mesh),
+        "im_k": apply_recipe(recipe, k_k, images, out_size, mesh),
     }
 
 

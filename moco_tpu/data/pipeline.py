@@ -416,7 +416,7 @@ class TwoCropPipeline(_HostPipeline):
 
         def _augment(rng, raw_uint8):
             images = raw_uint8.astype(jnp.float32) / 255.0
-            return two_crop_augment(recipe, rng, images, out_size)
+            return two_crop_augment(recipe, rng, images, out_size, mesh)
 
         self._augment, self._augment_donated = _jit_pair(_augment, (1,))
 
@@ -426,8 +426,8 @@ class TwoCropPipeline(_HostPipeline):
 
         def _augment_precropped(rng, q_uint8, k_uint8):
             k_q, k_k = jax.random.split(rng)
-            q = apply_recipe(nocrop, k_q, q_uint8.astype(jnp.float32) / 255.0, out_size)
-            k = apply_recipe(nocrop, k_k, k_uint8.astype(jnp.float32) / 255.0, out_size)
+            q = apply_recipe(nocrop, k_q, q_uint8.astype(jnp.float32) / 255.0, out_size, mesh)
+            k = apply_recipe(nocrop, k_k, k_uint8.astype(jnp.float32) / 255.0, out_size, mesh)
             return {"im_q": q, "im_k": k}
 
         self._augment_precropped, self._augment_precropped_donated = _jit_pair(

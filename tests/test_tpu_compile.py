@@ -1,9 +1,10 @@
-"""The Pallas kernels of the token path compile for the chip at the
-published widths: the TPU's compiler is installed here and compiles for a
-described `v5e:2x2` chip that is not attached (what interpret mode cannot
-show: tiling, fast-memory use, partitioning). Nothing runs, so nothing
-here is a result or a time. One file and a fixture, so that only the
-worker that is handed this file loads the TPU's library."""
+"""The Pallas kernels of the token path, and the image path's colour
+kernel, compile for the chip at the published widths: the TPU's compiler
+is installed here and compiles for a described `v5e:2x2` chip that is not
+attached (what interpret mode cannot show: tiling, fast-memory use,
+partitioning). Nothing runs, so nothing here is a result or a time. One
+file and a fixture, so that only the worker that is handed this file
+loads the TPU's library."""
 
 import os
 import re
@@ -11,11 +12,12 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def four_chips():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
@@ -23,7 +25,12 @@ def one_chip():
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    return SingleDeviceSharding(four_chips[0])
 
 
 def _shape(one_chip, shape, dtype):
@@ -192,3 +199,50 @@ def test_the_selective_scan_compiles_at_the_published_width(one_chip):
 
     text = jax.jit(f).lower(x, dt, a_log, bc, bc, d, lens).compile().as_text()
     assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
+
+
+@pytest.mark.parametrize("hue", [True, False], ids=["hue", "no_hue"])
+def test_the_colour_kernel_compiles_at_an_r50_view(one_chip, hue):
+    """The v2 colour stage's kernel on one view of the R50 cell, 256 images'
+    (3, 256, 224, 224) float32 planes, with the hue round trip and without
+    it: its blocks fit the default scoped VMEM (no limit is asked for), and
+    it is in the program under its name."""
+    from moco_tpu.ops.colour_jitter import colour_jitter
+
+    planes = _shape(one_chip, (3, 256, 224, 224), jnp.float32)
+    kinds = _shape(one_chip, (256, 4), jnp.int32)
+    factors = _shape(one_chip, (256, 4), jnp.float32)
+    flag = _shape(one_chip, (256,), jnp.bool_)
+
+    def f(planes, kinds, factors, keep, gray):
+        return colour_jitter(planes, kinds, factors, keep, gray, hue=hue)
+
+    text = jax.jit(f).lower(planes, kinds, factors, flag, flag).compile().as_text()
+    assert len(re.findall(r"%colour_jitter[.\d]* = .*custom-call\(", text)) == 1
+
+
+@pytest.mark.parametrize("host_crops", [False, True], ids=["canvas", "host_crops"])
+def test_the_two_view_program_compiles_over_four_chips(four_chips, monkeypatch, host_crops):
+    """`TwoCropPipeline`'s two-view program, on the canvas and on host
+    crops, for the R50 cell's 256 images over a 2x2 mesh: XLA refuses to
+    partition a Mosaic call, so the colour kernel has to arrive under
+    `shard_map`, once a view, on each chip's 64 images."""
+    from moco_tpu.data import augment
+    from moco_tpu.data.pipeline import TwoCropPipeline
+    from moco_tpu.parallel import create_mesh
+    from moco_tpu.utils.config import DataConfig
+
+    monkeypatch.setattr(augment, "pallas_interpret", lambda: False)  # this process's backend is the CPU
+    mesh = create_mesh(devices=four_chips)
+    cfg = DataConfig(dataset="synthetic", image_size=224, global_batch=256, num_workers=1, aug_plus=True)
+    pipe = TwoCropPipeline(cfg, mesh)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=NamedSharding(mesh, P()))
+    rows = NamedSharding(mesh, P("data"))
+    if host_crops:
+        view = jax.ShapeDtypeStruct((256, 224, 224, 3), jnp.uint8, sharding=rows)
+        lowered = pipe._augment_precropped.lower(rng, view, view)
+    else:
+        canvas = pipe.dataset.load(0)[0].shape
+        lowered = pipe._augment.lower(rng, jax.ShapeDtypeStruct((256, *canvas), jnp.uint8, sharding=rows))
+    calls = re.findall(r"%colour_jitter[.\d]* = (.*) custom-call\(", lowered.compile().as_text())
+    assert len(calls) == 2 and all("f32[3,64,224,224]" in c for c in calls), calls

@@ -304,3 +304,22 @@ class TestDiffAttention:
             assert float(jnp.max(jnp.abs(a - b))) <= 0.05 * float(jnp.max(jnp.abs(b)))
         text = jax.jit(got).lower(q, k, v).compile().as_text()
         assert all(f"diff_attention_{w}" in text for w in ("fwd", "dq", "dkv"))
+
+
+class TestColourJitter:
+    """The v2 colour stage's kernel compiled, against the batched jnp
+    composition on the same keys, at one R50 view (256 images, 224 px)."""
+
+    @pytest.mark.parametrize("hue,apply_prob", [(0.1, 0.8), (0.4, 1.0), (0.0, 0.8)])
+    def test_stage_matches_jitter_then_grayscale(self, hue, apply_prob):
+        from moco_tpu.data.augment import color_jitter, colour_stage, random_grayscale
+
+        k_jit, k_gray = jax.random.split(jax.random.PRNGKey(2147483659))
+        x = jax.random.uniform(jax.random.PRNGKey(70), (256, 224, 224, 3))
+        jitter = (0.4, 0.4, 0.4, hue)
+        got = jax.jit(lambda x: colour_stage(k_jit, k_gray, x, jitter, apply_prob, 0.2))(x)
+        want = jax.jit(
+            # the kernel's own keys on purpose: the same draws, the batched ops' arithmetic
+            lambda x: random_grayscale(k_gray, color_jitter(k_jit, x, *jitter, apply_prob=apply_prob), 0.2)  # mocolint: disable=JX003
+        )(x)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=0)
