@@ -852,9 +852,10 @@ def make_train_step(
             "enc": squeeze_opt_state(state.params_q),
             "pred": squeeze_opt_state(state.params_pred),
         }
-        k_sh = ema_update(
-            squeeze_opt_state(state.params_k), trainable_sh["enc"], m
-        )
+        with jax.named_scope("moco.ema"):
+            k_sh = ema_update(
+                squeeze_opt_state(state.params_k), trainable_sh["enc"], m
+            )
         t_leaves, t_def = jax.tree.flatten(trainable_sh)
         trainable_full = jax.tree.unflatten(
             t_def, plan_trainable.gather(t_leaves, site="zero.gather_q")
@@ -878,7 +879,8 @@ def make_train_step(
         the layer step differentiates over the shards directly."""
         m = ema_momentum(state.step)
         enc_sh = squeeze_opt_state(state.params_q)
-        k_sh = ema_update(squeeze_opt_state(state.params_k), enc_sh, m)
+        with jax.named_scope("moco.ema"):
+            k_sh = ema_update(squeeze_opt_state(state.params_k), enc_sh, m)
         k_leaves = jax.tree.leaves(k_sh)
         g0_full = enc_group_plan.gather_group(
             enc_group_plan.group_shards(k_leaves, 0), 0, site_prefix="zero.gather.k"
@@ -900,31 +902,34 @@ def make_train_step(
         x_cat = jnp.concatenate([im_q, im_k], axis=0)
 
         if zero_layer:
-            # grouped key forward over the freshly-EMA'd shards; group 0
-            # arrives pre-gathered from the prefetch program
             params_k = None
-            k_cat, stats_k = layer_key_forward(
-                gathered.params_k,
-                squeeze_opt_state(gathered.shards_k),
-                state.batch_stats_k,
-                x_cat,
-            )
-        else:
-            if gathered is None:
+        elif gathered is None:
+            with jax.named_scope("moco.ema"):
                 params_k = ema_update(
                     state.params_k, state.params_q, ema_momentum(state.step)
                 )
-            else:
-                params_k = gathered.params_k
-            k_cat, stats_k = apply_encoder(params_k, state.batch_stats_k, x_cat)
-        k1, k2 = jnp.split(lax.stop_gradient(l2_normalize(k_cat)), 2, axis=0)
-        if n_data > 1:
-            with comms.tag("v3.key_gather", "all_gather", (k1, k2), n_data):
-                k1_g = lax.all_gather(k1, DATA_AXIS).reshape(-1, cfg.dim)
-                k2_g = lax.all_gather(k2, DATA_AXIS).reshape(-1, cfg.dim)
-            rank = lax.axis_index(DATA_AXIS)
         else:
-            k1_g, k2_g, rank = k1, k2, 0
+            params_k = gathered.params_k
+        with jax.named_scope("moco.key_encoder"):
+            if zero_layer:
+                # grouped key forward over the freshly-EMA'd shards; group 0
+                # arrives pre-gathered from the prefetch program
+                k_cat, stats_k = layer_key_forward(
+                    gathered.params_k,
+                    squeeze_opt_state(gathered.shards_k),
+                    state.batch_stats_k,
+                    x_cat,
+                )
+            else:
+                k_cat, stats_k = apply_encoder(params_k, state.batch_stats_k, x_cat)
+            k1, k2 = jnp.split(lax.stop_gradient(l2_normalize(k_cat)), 2, axis=0)
+            if n_data > 1:
+                with comms.tag("v3.key_gather", "all_gather", (k1, k2), n_data):
+                    k1_g = lax.all_gather(k1, DATA_AXIS).reshape(-1, cfg.dim)
+                    k2_g = lax.all_gather(k2, DATA_AXIS).reshape(-1, cfg.dim)
+                rank = lax.axis_index(DATA_AXIS)
+            else:
+                k1_g, k2_g, rank = k1, k2, 0
         labels = rank * local_b + jnp.arange(local_b, dtype=jnp.int32)
 
         def ctr(q, k_g):
@@ -932,25 +937,27 @@ def make_train_step(
             return 2.0 * cfg.temperature * cross_entropy(logits, labels), logits
 
         def loss_fn(trainable):
-            if zero_layer:
-                # layer-granular: `trainable` is the SHARD tree; each
-                # segment gathers its group's full params just-in-time
-                feats, stats_q = layer_query_forward(
-                    trainable["enc"], state.batch_stats_q, x_cat
-                )
-                preds, stats_pred = layer_pred_forward(
-                    trainable["pred"], state.batch_stats_pred, feats
-                )
-            else:
-                feats, stats_q = grad_apply_encoder(
-                    trainable["enc"], state.batch_stats_q, x_cat
-                )
-                preds, stats_pred = apply_predictor(
-                    trainable["pred"], state.batch_stats_pred, feats
-                )
-            q1, q2 = jnp.split(l2_normalize(preds), 2, axis=0)
-            loss1, logits = ctr(q1, k2_g)
-            loss2, _ = ctr(q2, k1_g)
+            with jax.named_scope("moco.query_encoder"):
+                if zero_layer:
+                    # layer-granular: `trainable` is the SHARD tree; each
+                    # segment gathers its group's full params just-in-time
+                    feats, stats_q = layer_query_forward(
+                        trainable["enc"], state.batch_stats_q, x_cat
+                    )
+                    preds, stats_pred = layer_pred_forward(
+                        trainable["pred"], state.batch_stats_pred, feats
+                    )
+                else:
+                    feats, stats_q = grad_apply_encoder(
+                        trainable["enc"], state.batch_stats_q, x_cat
+                    )
+                    preds, stats_pred = apply_predictor(
+                        trainable["pred"], state.batch_stats_pred, feats
+                    )
+            with jax.named_scope("moco.contrastive_loss"):
+                q1, q2 = jnp.split(l2_normalize(preds), 2, axis=0)
+                loss1, logits = ctr(q1, k2_g)
+                loss2, _ = ctr(q2, k1_g)
             return loss1 + loss2, (stats_q, stats_pred, logits, q1)
 
         if zero_layer:
@@ -967,104 +974,107 @@ def make_train_step(
         (loss, (stats_q, stats_pred, logits, q1)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(trainable)
-        if cfg.freeze_patch_embed and "patch_embed" in grads["enc"].get("backbone", {}):
-            grads["enc"]["backbone"]["patch_embed"] = jax.tree.map(
-                jnp.zeros_like, grads["enc"]["backbone"]["patch_embed"]
-            )
-        if cfg.vit_sequence_parallel:
-            # Sequence parallelism: each model-axis member backprops only
-            # through ITS token shard, so backbone grads are PARTIAL sums
-            # — psum over the sequence (model) axis restores the full
-            # gradient. Head/predictor grads are replicated-identical
-            # (they consume the psum-pooled feature) and stay untouched.
-            with comms.tag(
-                "grad.seq_psum", "psum", grads["enc"]["backbone"], n_model
-            ):
-                grads["enc"]["backbone"] = lax.psum(
-                    grads["enc"]["backbone"], MODEL_AXIS
+        with jax.named_scope("moco.optimizer"):
+            if cfg.freeze_patch_embed and "patch_embed" in grads["enc"].get("backbone", {}):
+                grads["enc"]["backbone"]["patch_embed"] = jax.tree.map(
+                    jnp.zeros_like, grads["enc"]["backbone"]["patch_embed"]
                 )
+            if cfg.vit_sequence_parallel:
+                # Sequence parallelism: each model-axis member backprops only
+                # through ITS token shard, so backbone grads are PARTIAL sums
+                # — psum over the sequence (model) axis restores the full
+                # gradient. Head/predictor grads are replicated-identical
+                # (they consume the psum-pooled feature) and stay untouched.
+                with comms.tag(
+                    "grad.seq_psum", "psum", grads["enc"]["backbone"], n_model
+                ):
+                    grads["enc"]["backbone"] = lax.psum(
+                        grads["enc"]["backbone"], MODEL_AXIS
+                    )
         metrics = {"loss": loss, **topk_accuracy(logits, labels)}
         metrics = lax.pmean(metrics, DATA_AXIS)
         stats_q = lax.pmean(stats_q, DATA_AXIS)
         stats_k = lax.pmean(stats_k, DATA_AXIS)
         stats_pred = lax.pmean(stats_pred, DATA_AXIS)
 
-        if gathered is not None:
-            # ZeRO-2/3: bucketed psum_scatter + shard-local update; the
-            # params never re-materialize — the next step's gather does.
-            # In layer mode the scatter already ran inside the segments'
-            # backward, so `grads` arrived as summed (m,) shards.
-            if zero_layer:
-                trainable_sh, new_tr_sh, opt_state = zero_layer_update(state, grads)
-            else:
-                trainable_sh, new_tr_sh, opt_state = zero23_update(state, grads)
-            if cfg.freeze_patch_embed and "patch_embed" in new_tr_sh["enc"].get(
-                "backbone", {}
-            ):
-                # zeroed grads stop the gradient; restoring the OLD
-                # shards also blocks AdamW's decoupled decay — the
-                # shard-level mirror of the stage-1 full-params freeze
-                new_tr_sh["enc"]["backbone"]["patch_embed"] = trainable_sh["enc"][
-                    "backbone"
-                ]["patch_embed"]
-            drift = lambda: obs_health.ema_drift_sharded(
-                new_tr_sh["enc"], squeeze_opt_state(gathered.shards_k), DATA_AXIS
-            )
-            out_params = dict(
-                params_q=expand_opt_state(new_tr_sh["enc"]),
-                params_pred=expand_opt_state(new_tr_sh["pred"]),
-                params_k=gathered.shards_k,
-            )
-        elif zero:
-            # Sharded weight update (parallel/zero.py stage 1):
-            # psum_scatter fuses the grad mean-reduction with the 1/n
-            # sharding. The patch-embed freeze is applied to the
-            # gathered FULL params below, so AdamW's decoupled decay
-            # cannot move them either.
-            frozen_pe = (
-                trainable["enc"]["backbone"]["patch_embed"]
-                if cfg.freeze_patch_embed
-                and "patch_embed" in trainable["enc"].get("backbone", {})
-                else None
-            )
-            new_trainable, opt_state = sharded_update(
-                tx, grads, state.opt_state, trainable
-            )
-            if frozen_pe is not None:
-                new_trainable["enc"]["backbone"]["patch_embed"] = frozen_pe
-            drift = lambda: obs_health.ema_drift(new_trainable["enc"], params_k)
-            out_params = dict(
-                params_q=new_trainable["enc"],
-                params_pred=new_trainable["pred"],
-                params_k=params_k,
-            )
-        else:
-            with comms.tag("grad.psum", "psum", grads, n_data):
-                grads = lax.pmean(grads, DATA_AXIS)
-            updates, opt_state = tx.update(grads, state.opt_state, trainable)
-            if cfg.freeze_patch_embed and "patch_embed" in updates["enc"].get("backbone", {}):
-                # zeroed grads are not enough: AdamW's decoupled weight decay
-                # still moves zero-grad params, so zero the *update* as well
-                updates["enc"]["backbone"]["patch_embed"] = jax.tree.map(
-                    jnp.zeros_like, updates["enc"]["backbone"]["patch_embed"]
+        with jax.named_scope("moco.optimizer"):
+            if gathered is not None:
+                # ZeRO-2/3: bucketed psum_scatter + shard-local update; the
+                # params never re-materialize — the next step's gather does.
+                # In layer mode the scatter already ran inside the segments'
+                # backward, so `grads` arrived as summed (m,) shards.
+                if zero_layer:
+                    trainable_sh, new_tr_sh, opt_state = zero_layer_update(state, grads)
+                else:
+                    trainable_sh, new_tr_sh, opt_state = zero23_update(state, grads)
+                if cfg.freeze_patch_embed and "patch_embed" in new_tr_sh["enc"].get(
+                    "backbone", {}
+                ):
+                    # zeroed grads stop the gradient; restoring the OLD
+                    # shards also blocks AdamW's decoupled decay — the
+                    # shard-level mirror of the stage-1 full-params freeze
+                    new_tr_sh["enc"]["backbone"]["patch_embed"] = trainable_sh["enc"][
+                        "backbone"
+                    ]["patch_embed"]
+                drift = lambda: obs_health.ema_drift_sharded(
+                    new_tr_sh["enc"], squeeze_opt_state(gathered.shards_k), DATA_AXIS
                 )
-            new_trainable = optax.apply_updates(trainable, updates)
-            drift = lambda: obs_health.ema_drift(new_trainable["enc"], params_k)
-            out_params = dict(
-                params_q=new_trainable["enc"],
-                params_pred=new_trainable["pred"],
-                params_k=params_k,
-            )
-        if health_on:
-            # batch-local stats pmean over data; drift is a function of
-            # replicated params — or, at ZeRO stage 2/3, of the shards
-            # with a psum'd norm (v3 has no queue, so no staleness gauges)
-            hlocal = {
-                **obs_health.logit_stats_from_dense(logits, labels),
-                **obs_health.feature_stats(q1),
-            }
-            metrics.update(lax.pmean(hlocal, DATA_AXIS))
-            metrics.update(drift())
+                out_params = dict(
+                    params_q=expand_opt_state(new_tr_sh["enc"]),
+                    params_pred=expand_opt_state(new_tr_sh["pred"]),
+                    params_k=gathered.shards_k,
+                )
+            elif zero:
+                # Sharded weight update (parallel/zero.py stage 1):
+                # psum_scatter fuses the grad mean-reduction with the 1/n
+                # sharding. The patch-embed freeze is applied to the
+                # gathered FULL params below, so AdamW's decoupled decay
+                # cannot move them either.
+                frozen_pe = (
+                    trainable["enc"]["backbone"]["patch_embed"]
+                    if cfg.freeze_patch_embed
+                    and "patch_embed" in trainable["enc"].get("backbone", {})
+                    else None
+                )
+                new_trainable, opt_state = sharded_update(
+                    tx, grads, state.opt_state, trainable
+                )
+                if frozen_pe is not None:
+                    new_trainable["enc"]["backbone"]["patch_embed"] = frozen_pe
+                drift = lambda: obs_health.ema_drift(new_trainable["enc"], params_k)
+                out_params = dict(
+                    params_q=new_trainable["enc"],
+                    params_pred=new_trainable["pred"],
+                    params_k=params_k,
+                )
+            else:
+                with comms.tag("grad.psum", "psum", grads, n_data):
+                    grads = lax.pmean(grads, DATA_AXIS)
+                updates, opt_state = tx.update(grads, state.opt_state, trainable)
+                if cfg.freeze_patch_embed and "patch_embed" in updates["enc"].get("backbone", {}):
+                    # zeroed grads are not enough: AdamW's decoupled weight decay
+                    # still moves zero-grad params, so zero the *update* as well
+                    updates["enc"]["backbone"]["patch_embed"] = jax.tree.map(
+                        jnp.zeros_like, updates["enc"]["backbone"]["patch_embed"]
+                    )
+                new_trainable = optax.apply_updates(trainable, updates)
+                drift = lambda: obs_health.ema_drift(new_trainable["enc"], params_k)
+                out_params = dict(
+                    params_q=new_trainable["enc"],
+                    params_pred=new_trainable["pred"],
+                    params_k=params_k,
+                )
+        with jax.named_scope("moco.health"):
+            if health_on:
+                # batch-local stats pmean over data; drift is a function of
+                # replicated params — or, at ZeRO stage 2/3, of the shards
+                # with a psum'd norm (v3 has no queue, so no staleness gauges)
+                hlocal = {
+                    **obs_health.logit_stats_from_dense(logits, labels),
+                    **obs_health.feature_stats(q1),
+                }
+                metrics.update(lax.pmean(hlocal, DATA_AXIS))
+                metrics.update(drift())
         new_state = state.replace(
             step=state.step + 1,
             batch_stats_q=stats_q,
@@ -1099,9 +1109,10 @@ def make_train_step(
             )
         else:
             if gathered is None:
-                params_k = ema_update(
-                    state.params_k, state.params_q, ema_momentum(state.step)
-                )
+                with jax.named_scope("moco.ema"):
+                    params_k = ema_update(
+                        state.params_k, state.params_q, ema_momentum(state.step)
+                    )
             else:
                 params_k = gathered.params_k
             key_apply = lambda stats, x, train=True: apply_encoder(
@@ -1115,81 +1126,84 @@ def make_train_step(
         # pure in-batch permutation): per-group BN statistics + permuted
         # group composition = the reference's G-GPU Shuffle-BN inside a
         # single chip's batch.
-        shuffle_active = n_data > 1 or cfg.bn_virtual_groups > 1
-        if cfg.shuffle == "gather_perm" and shuffle_active:
-            perm, inv_perm = make_permutation(step_rng, global_batch)
-            im_k_sh = shuffle_gather(im_k, perm, DATA_AXIS)
-            k_sh, stats_k = key_apply(state.batch_stats_k, im_k_sh)
-            k_sh = l2_normalize(k_sh)
-            k_local, k_global = unshuffle_gather(k_sh, inv_perm, DATA_AXIS)
-        elif cfg.shuffle == "a2a" and shuffle_active:
-            im_k_sh = balanced_shuffle(step_rng, im_k, DATA_AXIS)
-            k_sh, stats_k = key_apply(state.batch_stats_k, im_k_sh)
-            k_sh = l2_normalize(k_sh)
-            # the unshuffle must regenerate the SAME permutation as the
-            # shuffle above, so reusing step_rng is the contract, not a bug
-            k_local = balanced_unshuffle(step_rng, k_sh, DATA_AXIS)  # mocolint: disable=JX003
-            with comms.tag("queue.enqueue_gather", "all_gather", k_local, n_data):
-                k_global = lax.all_gather(k_local, DATA_AXIS).reshape(-1, cfg.dim)
-        else:  # 'syncbn' (cross-replica BN handles decorrelation) or 'none'
-            # key_bn_running_stats (EMAN, config.py rationale): the key
-            # forward runs EVAL-mode BN against the EMA'd running stats —
-            # no statistics pass, no composition leak, no shuffle
-            # collectives; the returned stats tree is unchanged and is
-            # replaced by the EMA advance in (4) below.
-            k_local, stats_k = key_apply(
-                state.batch_stats_k, im_k, train=not cfg.key_bn_running_stats
-            )
-            k_local = l2_normalize(k_local)
-            if n_data > 1:
+        with jax.named_scope("moco.key_encoder"):
+            shuffle_active = n_data > 1 or cfg.bn_virtual_groups > 1
+            if cfg.shuffle == "gather_perm" and shuffle_active:
+                perm, inv_perm = make_permutation(step_rng, global_batch)
+                im_k_sh = shuffle_gather(im_k, perm, DATA_AXIS)
+                k_sh, stats_k = key_apply(state.batch_stats_k, im_k_sh)
+                k_sh = l2_normalize(k_sh)
+                k_local, k_global = unshuffle_gather(k_sh, inv_perm, DATA_AXIS)
+            elif cfg.shuffle == "a2a" and shuffle_active:
+                im_k_sh = balanced_shuffle(step_rng, im_k, DATA_AXIS)
+                k_sh, stats_k = key_apply(state.batch_stats_k, im_k_sh)
+                k_sh = l2_normalize(k_sh)
+                # the unshuffle must regenerate the SAME permutation as the
+                # shuffle above, so reusing step_rng is the contract, not a bug
+                k_local = balanced_unshuffle(step_rng, k_sh, DATA_AXIS)  # mocolint: disable=JX003
                 with comms.tag("queue.enqueue_gather", "all_gather", k_local, n_data):
                     k_global = lax.all_gather(k_local, DATA_AXIS).reshape(-1, cfg.dim)
-            else:
-                k_global = k_local
-        k_local = lax.stop_gradient(k_local)
-        k_global = lax.stop_gradient(k_global)
+            else:  # 'syncbn' (cross-replica BN handles decorrelation) or 'none'
+                # key_bn_running_stats (EMAN, config.py rationale): the key
+                # forward runs EVAL-mode BN against the EMA'd running stats —
+                # no statistics pass, no composition leak, no shuffle
+                # collectives; the returned stats tree is unchanged and is
+                # replaced by the EMA advance in (4) below.
+                k_local, stats_k = key_apply(
+                    state.batch_stats_k, im_k, train=not cfg.key_bn_running_stats
+                )
+                k_local = l2_normalize(k_local)
+                if n_data > 1:
+                    with comms.tag("queue.enqueue_gather", "all_gather", k_local, n_data):
+                        k_global = lax.all_gather(k_local, DATA_AXIS).reshape(-1, cfg.dim)
+                else:
+                    k_global = k_local
+            k_local = lax.stop_gradient(k_local)
+            k_global = lax.stop_gradient(k_global)
 
         # (3) Query forward + InfoNCE loss (moco/builder.py:~L128-161).
         def loss_fn(trainable):
-            if zero_layer:
-                q, stats_q = layer_query_forward(
-                    trainable["enc"], state.batch_stats_q, im_q
-                )
-            else:
-                q, stats_q = grad_apply_encoder(
-                    trainable["enc"], state.batch_stats_q, im_q
-                )
-            q = l2_normalize(q)
-            if cfg.num_negatives and use_fused:
-                # streaming pallas kernel: never materializes (B, 1+K)
-                from moco_tpu.ops.fused_infonce import fused_infonce_loss
+            with jax.named_scope("moco.query_encoder"):
+                if zero_layer:
+                    q, stats_q = layer_query_forward(
+                        trainable["enc"], state.batch_stats_q, im_q
+                    )
+                else:
+                    q, stats_q = grad_apply_encoder(
+                        trainable["enc"], state.batch_stats_q, im_q
+                    )
+            with jax.named_scope("moco.contrastive_loss"):
+                q = l2_normalize(q)
+                if cfg.num_negatives and use_fused:
+                    # streaming pallas kernel: never materializes (B, 1+K)
+                    from moco_tpu.ops.fused_infonce import fused_infonce_loss
 
-                loss, acc = fused_infonce_loss(
-                    q,
-                    k_local,
-                    state.queue,
-                    cfg.temperature,
-                    block_k=fused_block_k,
-                    interpret=pallas_interpret(),
-                )
-            elif cfg.num_negatives:
-                logits, labels = infonce_logits(q, k_local, state.queue, cfg.temperature)
-                if shard_queue_over_model:
-                    # queue rows are sharded over `model`: logits currently
-                    # hold [pos | my negative shard]; assemble full rows.
-                    l_pos, l_neg = logits[:, :1], logits[:, 1:]
-                    with comms.tag("queue.logits_gather", "all_gather", l_neg, n_model):
-                        l_neg = lax.all_gather(l_neg, MODEL_AXIS, axis=1, tiled=True)
-                    logits = jnp.concatenate([l_pos, l_neg], axis=1)
-                loss = cross_entropy(logits, labels)
-                acc = topk_accuracy(logits, labels)
-            else:
-                # v3-style queue-free: global batch keys are the negatives.
-                logits = q @ k_global.T / cfg.temperature
-                rank = lax.axis_index(DATA_AXIS)
-                labels = rank * local_b + jnp.arange(local_b, dtype=jnp.int32)
-                loss = cross_entropy(logits, labels)
-                acc = topk_accuracy(logits, labels)
+                    loss, acc = fused_infonce_loss(
+                        q,
+                        k_local,
+                        state.queue,
+                        cfg.temperature,
+                        block_k=fused_block_k,
+                        interpret=pallas_interpret(),
+                    )
+                elif cfg.num_negatives:
+                    logits, labels = infonce_logits(q, k_local, state.queue, cfg.temperature)
+                    if shard_queue_over_model:
+                        # queue rows are sharded over `model`: logits currently
+                        # hold [pos | my negative shard]; assemble full rows.
+                        l_pos, l_neg = logits[:, :1], logits[:, 1:]
+                        with comms.tag("queue.logits_gather", "all_gather", l_neg, n_model):
+                            l_neg = lax.all_gather(l_neg, MODEL_AXIS, axis=1, tiled=True)
+                        logits = jnp.concatenate([l_pos, l_neg], axis=1)
+                    loss = cross_entropy(logits, labels)
+                    acc = topk_accuracy(logits, labels)
+                else:
+                    # v3-style queue-free: global batch keys are the negatives.
+                    logits = q @ k_global.T / cfg.temperature
+                    rank = lax.axis_index(DATA_AXIS)
+                    labels = rank * local_b + jnp.arange(local_b, dtype=jnp.int32)
+                    loss = cross_entropy(logits, labels)
+                    acc = topk_accuracy(logits, labels)
             return loss, (stats_q, acc, q)
 
         if zero_layer:
@@ -1237,7 +1251,8 @@ def make_train_step(
                 # — the r4 accuracy arm's suspected failure mechanism
                 step_f = state.step.astype(jnp.float32)
                 m_stats = jnp.minimum(m_stats, (1.0 + step_f) / (10.0 + step_f))
-            stats_k = ema_update(state.batch_stats_k, stats_q, m_stats)
+            with jax.named_scope("moco.ema"):
+                stats_k = ema_update(state.batch_stats_k, stats_q, m_stats)
         else:
             stats_k = lax.pmean(stats_k, DATA_AXIS)
 
@@ -1247,86 +1262,89 @@ def make_train_step(
         # optimizer touches only this replica's 1/n shard, and an
         # all_gather rebuilds the full params (stage 1) — or never does,
         # because the params persist as shards (stage 2/3).
-        if gathered is not None:
-            if shard_queue_over_model:
-                grads = lax.pmean(grads, MODEL_AXIS)
-            if zero_layer:
-                _, new_tr_sh, opt_state = zero_layer_update(state, grads)
+        with jax.named_scope("moco.optimizer"):
+            if gathered is not None:
+                if shard_queue_over_model:
+                    grads = lax.pmean(grads, MODEL_AXIS)
+                if zero_layer:
+                    _, new_tr_sh, opt_state = zero_layer_update(state, grads)
+                else:
+                    _, new_tr_sh, opt_state = zero23_update(state, grads)
+                drift = lambda: obs_health.ema_drift_sharded(
+                    new_tr_sh["enc"], squeeze_opt_state(gathered.shards_k), DATA_AXIS
+                )
+                out_params = dict(
+                    params_q=expand_opt_state(new_tr_sh["enc"]),
+                    params_pred=expand_opt_state(new_tr_sh["pred"]),
+                    params_k=gathered.shards_k,
+                )
+            elif zero:
+                if shard_queue_over_model:
+                    grads = lax.pmean(grads, MODEL_AXIS)
+                new_trainable, opt_state = sharded_update(
+                    tx, grads, state.opt_state, trainable
+                )
+                params_q = new_trainable["enc"]
+                drift = lambda: obs_health.ema_drift(params_q, params_k)
+                out_params = dict(params_q=params_q, params_k=params_k)
             else:
-                _, new_tr_sh, opt_state = zero23_update(state, grads)
-            drift = lambda: obs_health.ema_drift_sharded(
-                new_tr_sh["enc"], squeeze_opt_state(gathered.shards_k), DATA_AXIS
-            )
-            out_params = dict(
-                params_q=expand_opt_state(new_tr_sh["enc"]),
-                params_pred=expand_opt_state(new_tr_sh["pred"]),
-                params_k=gathered.shards_k,
-            )
-        elif zero:
-            if shard_queue_over_model:
-                grads = lax.pmean(grads, MODEL_AXIS)
-            new_trainable, opt_state = sharded_update(
-                tx, grads, state.opt_state, trainable
-            )
-            params_q = new_trainable["enc"]
-            drift = lambda: obs_health.ema_drift(params_q, params_k)
-            out_params = dict(params_q=params_q, params_k=params_k)
-        else:
-            grad_axes = (DATA_AXIS, MODEL_AXIS) if shard_queue_over_model else DATA_AXIS
-            grad_world = n_data * (n_model if shard_queue_over_model else 1)
-            with comms.tag("grad.psum", "psum", grads, grad_world):
-                grads = lax.pmean(grads, grad_axes)
-            updates, opt_state = tx.update(grads, state.opt_state, trainable)
-            params_q = optax.apply_updates(trainable, updates)["enc"]
-            drift = lambda: obs_health.ema_drift(params_q, params_k)
-            out_params = dict(params_q=params_q, params_k=params_k)
+                grad_axes = (DATA_AXIS, MODEL_AXIS) if shard_queue_over_model else DATA_AXIS
+                grad_world = n_data * (n_model if shard_queue_over_model else 1)
+                with comms.tag("grad.psum", "psum", grads, grad_world):
+                    grads = lax.pmean(grads, grad_axes)
+                updates, opt_state = tx.update(grads, state.opt_state, trainable)
+                params_q = optax.apply_updates(trainable, updates)["enc"]
+                drift = lambda: obs_health.ema_drift(params_q, params_k)
+                out_params = dict(params_q=params_q, params_k=params_k)
 
         # (6) FIFO enqueue of the global key batch
         # (moco/builder.py:~L62-77); with a model-sharded queue each shard
         # writes only the rows that fall inside it.
-        if cfg.num_negatives:
-            if shard_queue_over_model:
-                shard_rows = cfg.num_negatives // n_model
-                m_rank = lax.axis_index(MODEL_AXIS)
-                offset = m_rank * shard_rows
-                local_ptr = state.queue_ptr - offset
-                in_range = (local_ptr >= 0) & (local_ptr + global_batch <= shard_rows)
-                safe_ptr = jnp.clip(local_ptr, 0, shard_rows - global_batch)
-                written, _ = enqueue(state.queue, safe_ptr, k_global)
-                queue = jnp.where(in_range, written, state.queue)
-                queue_ptr = (state.queue_ptr + global_batch) % cfg.num_negatives
+        with jax.named_scope("moco.enqueue"):
+            if cfg.num_negatives:
+                if shard_queue_over_model:
+                    shard_rows = cfg.num_negatives // n_model
+                    m_rank = lax.axis_index(MODEL_AXIS)
+                    offset = m_rank * shard_rows
+                    local_ptr = state.queue_ptr - offset
+                    in_range = (local_ptr >= 0) & (local_ptr + global_batch <= shard_rows)
+                    safe_ptr = jnp.clip(local_ptr, 0, shard_rows - global_batch)
+                    written, _ = enqueue(state.queue, safe_ptr, k_global)
+                    queue = jnp.where(in_range, written, state.queue)
+                    queue_ptr = (state.queue_ptr + global_batch) % cfg.num_negatives
+                else:
+                    queue, queue_ptr = enqueue(state.queue, state.queue_ptr, k_global)
             else:
-                queue, queue_ptr = enqueue(state.queue, state.queue_ptr, k_global)
-        else:
-            queue, queue_ptr = state.queue, state.queue_ptr
+                queue, queue_ptr = state.queue, state.queue_ptr
 
         # (7) Training-health gauges (obs/health.py), identical math on
         # the fused and dense paths: positives recomputed from the
         # (q, k) diagonal; negatives from a bounded queue sample (the
         # full K-row pass is exactly what the fused kernel avoids
         # materializing), in post-temperature units.
-        if health_on:
-            q_h = lax.stop_gradient(q_feats)
-            pos_l = jnp.sum(q_h * k_local, axis=-1) / cfg.temperature
-            if cfg.num_negatives:
-                rows = min(1024, state.queue.shape[0])
-                neg_ref = lax.stop_gradient(state.queue[:rows])
-            else:
-                # queue-free: the gathered key batch is the negative set
-                # (contains each row's own positive — 1/B_global of the
-                # sample, negligible contamination for a gauge)
-                neg_ref = k_global
-            neg_l = (q_h @ neg_ref.T) / cfg.temperature
-            hlocal = {
-                **obs_health.logit_stats(pos_l, neg_l),
-                **obs_health.feature_stats(q_h),
-            }
-            metrics.update(lax.pmean(hlocal, DATA_AXIS))
-            metrics.update(drift())
-            if cfg.num_negatives:
-                metrics.update(
-                    obs_health.queue_age(state.step, cfg.num_negatives, global_batch)
-                )
+        with jax.named_scope("moco.health"):
+            if health_on:
+                q_h = lax.stop_gradient(q_feats)
+                pos_l = jnp.sum(q_h * k_local, axis=-1) / cfg.temperature
+                if cfg.num_negatives:
+                    rows = min(1024, state.queue.shape[0])
+                    neg_ref = lax.stop_gradient(state.queue[:rows])
+                else:
+                    # queue-free: the gathered key batch is the negative set
+                    # (contains each row's own positive — 1/B_global of the
+                    # sample, negligible contamination for a gauge)
+                    neg_ref = k_global
+                neg_l = (q_h @ neg_ref.T) / cfg.temperature
+                hlocal = {
+                    **obs_health.logit_stats(pos_l, neg_l),
+                    **obs_health.feature_stats(q_h),
+                }
+                metrics.update(lax.pmean(hlocal, DATA_AXIS))
+                metrics.update(drift())
+                if cfg.num_negatives:
+                    metrics.update(
+                        obs_health.queue_age(state.step, cfg.num_negatives, global_batch)
+                    )
 
         new_state = state.replace(
             step=state.step + 1,

@@ -412,22 +412,27 @@ def apply_recipe(
     k_crop, k_jit, k_gray, k_blur, k_flip = jax.random.split(rng, 5)
     x = images
     if recipe.crop:
-        x = random_resized_crop(k_crop, x, out_size, scale=recipe.crop_scale)
+        with jax.named_scope("moco.augment.crop"):
+            x = random_resized_crop(k_crop, x, out_size, scale=recipe.crop_scale)
     if recipe.name == "v1":
         # v1 order: crop, grayscale, jitter, flip (main_moco.py:~L245-255)
-        x = random_grayscale(k_gray, x, recipe.grayscale_prob)
-        x = color_jitter(k_jit, x, *recipe.jitter, apply_prob=recipe.jitter_prob)
+        with jax.named_scope("moco.augment.colour"):
+            x = random_grayscale(k_gray, x, recipe.grayscale_prob)
+            x = color_jitter(k_jit, x, *recipe.jitter, apply_prob=recipe.jitter_prob)
     elif recipe.name == "probe":
         pass  # crop + flip + normalize only
     else:
         # v2 order: crop, jitter(p=0.8), grayscale, blur, flip (~L228-240)
-        x = colour_stage(
-            k_jit, k_gray, x, recipe.jitter, recipe.jitter_prob, recipe.grayscale_prob, mesh
-        )
+        with jax.named_scope("moco.augment.colour"):
+            x = colour_stage(
+                k_jit, k_gray, x, recipe.jitter, recipe.jitter_prob, recipe.grayscale_prob, mesh
+            )
         if recipe.blur_prob > 0:
-            x = gaussian_blur(k_blur, x, apply_prob=recipe.blur_prob)
-    x = random_horizontal_flip(k_flip, x)
-    return normalize(x, recipe.mean, recipe.std)
+            with jax.named_scope("moco.augment.blur"):
+                x = gaussian_blur(k_blur, x, apply_prob=recipe.blur_prob)
+    with jax.named_scope("moco.augment.flip_normalize"):
+        x = random_horizontal_flip(k_flip, x)
+        return normalize(x, recipe.mean, recipe.std)
 
 
 def two_crop_augment(

@@ -415,7 +415,8 @@ class TwoCropPipeline(_HostPipeline):
         recipe, out_size = self.recipe, config.image_size
 
         def _augment(rng, raw_uint8):
-            images = raw_uint8.astype(jnp.float32) / 255.0
+            with jax.named_scope("moco.augment.crop"):  # the crop reads what this converts
+                images = raw_uint8.astype(jnp.float32) / 255.0
             return two_crop_augment(recipe, rng, images, out_size, mesh)
 
         self._augment, self._augment_donated = _jit_pair(_augment, (1,))

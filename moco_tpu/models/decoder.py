@@ -175,22 +175,23 @@ class ExpertDispatch(nn.Module):
 
         # assignments on held experts first, in expert order; the rest
         # (absent experts, padding) share one key that sorts behind them
-        local = (chosen - self.first_expert) % e
-        mine = valid[:, None] & (local < held)
-        key = jnp.where(mine, local, held).reshape(-1)
-        order = jnp.argsort(key, stable=True)
-        sizes = jnp.bincount(key, length=held + 1)[:held]
-        ladder = rung_ladder(t * k, e, held)
-        operands = (x.astype(dt), w_in.astype(dt), w_out.astype(dt), weights, order, sizes)
-        if len(ladder) == 1:  # the uncut layer: one program, nothing to choose
-            y = _rung(t * k, k, activation, *operands)
-        else:
-            y = _laddered(ladder, k, activation, *operands)
-        if self.train and not self.is_initializing():
-            load.value = sizes.astype(jnp.float32)
-            taken = rung_taken(ladder, sizes)
-            buffer_rows.value = jnp.asarray(ladder, jnp.float32)[taken]
-            bounded.value = (taken < len(ladder) - 1).astype(jnp.float32)
+        with jax.named_scope("moco.moe_dispatch"):
+            local = (chosen - self.first_expert) % e
+            mine = valid[:, None] & (local < held)
+            key = jnp.where(mine, local, held).reshape(-1)
+            order = jnp.argsort(key, stable=True)
+            sizes = jnp.bincount(key, length=held + 1)[:held]
+            ladder = rung_ladder(t * k, e, held)
+            operands = (x.astype(dt), w_in.astype(dt), w_out.astype(dt), weights, order, sizes)
+            if len(ladder) == 1:  # the uncut layer: one program, nothing to choose
+                y = _rung(t * k, k, activation, *operands)
+            else:
+                y = _laddered(ladder, k, activation, *operands)
+            if self.train and not self.is_initializing():
+                load.value = sizes.astype(jnp.float32)
+                taken = rung_taken(ladder, sizes)
+                buffer_rows.value = jnp.asarray(ladder, jnp.float32)[taken]
+                bounded.value = (taken < len(ladder) - 1).astype(jnp.float32)
         return y
 
 

@@ -184,19 +184,21 @@ class ExpertLayer(ExpertDispatch):
             "router", nn.initializers.lecun_normal(), (x.shape[-1], e), jnp.float32
         )
         bias = self.variable("batch_stats", "bias", jnp.zeros, (e,), jnp.float32)
-        scores = nn.sigmoid(
-            jnp.matmul(x.astype(jnp.float32), router, precision=lax.Precision.HIGHEST)
-        )
-        chosen, weights = route(scores, bias.value, k, self.routed_scale)
+        with jax.named_scope("moco.moe_dispatch"):
+            scores = nn.sigmoid(
+                jnp.matmul(x.astype(jnp.float32), router, precision=lax.Precision.HIGHEST)
+            )
+            chosen, weights = route(scores, bias.value, k, self.routed_scale)
         y = self.routed(x, valid, chosen, weights, nn.silu)
         for i in range(self.shared_experts):
             y = y + SwiGLU(self.expert_mlp, self.dtype, name=f"shared_{i}")(x)
 
         if self.train and not self.is_initializing():
-            counts = jnp.zeros((e,), jnp.float32).at[chosen.reshape(-1)].add(
-                jnp.repeat(valid, k).astype(jnp.float32)
-            )
-            bias.value = bias.value + BIAS_UPDATE_RATE * jnp.sign(jnp.mean(counts) - counts)
+            with jax.named_scope("moco.moe_dispatch"):
+                counts = jnp.zeros((e,), jnp.float32).at[chosen.reshape(-1)].add(
+                    jnp.repeat(valid, k).astype(jnp.float32)
+                )
+                bias.value = bias.value + BIAS_UPDATE_RATE * jnp.sign(jnp.mean(counts) - counts)
         return y
 
 

@@ -141,7 +141,8 @@ class ExpertLayer(ExpertDispatch):
     @nn.compact
     def __call__(self, x, valid, logits):
         """x (T, d) tokens; valid (T,) bool, False on padding; logits (T, E)."""
-        chosen, weights = route(logits, self.top_k)
+        with jax.named_scope("moco.moe_dispatch"):
+            chosen, weights = route(logits, self.top_k)
         return self.routed(x, valid, chosen, weights, nn.relu)
 
 
@@ -159,9 +160,10 @@ class Block(nn.Module):
         b, s, d = x.shape
         h = RMSNorm(dt, name="attn_norm")(x)
         router = self.param("router", nn.initializers.lecun_normal(), (d, c.experts), jnp.float32)
-        logits = jnp.matmul(
-            h.astype(jnp.float32).reshape(b * s, d), router, precision=lax.Precision.HIGHEST
-        )
+        with jax.named_scope("moco.moe_dispatch"):
+            logits = jnp.matmul(
+                h.astype(jnp.float32).reshape(b * s, d), router, precision=lax.Precision.HIGHEST
+            )
         attn = GroupedAttention(
             heads=c.heads, kv_heads=c.kv_heads, head_dim=c.head_dim, window=self.window,
             rope_theta=c.rope_theta, dtype=dt, name="attn",

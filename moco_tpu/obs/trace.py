@@ -47,6 +47,28 @@ from typing import Optional
 
 from moco_tpu.analysis import tsan
 
+# The device side's names: each part of the train step, of the expert
+# dispatch and of the on-device augmentation program runs under a
+# `jax.named_scope` of this list. A scope is HLO metadata only: it changes
+# no program, and in a device trace every op carries its scopes in its
+# `op_name` path, where `benchmarks/readers/scope.py` gives the op's time to
+# the innermost of them. No other scope in the tree starts with `moco.`.
+STEP_SCOPES = (
+    "moco.ema",  # the key encoder's parameter EMA; the key BN statistics' EMA
+    "moco.key_encoder",  # Shuffle-BN and the no-grad key forward
+    "moco.query_encoder",  # query forward (and predictor), its backward
+    "moco.contrastive_loss",  # normalise, InfoNCE or the v3 logits, forward and backward
+    "moco.optimizer",  # gradient mean and the weight update
+    "moco.enqueue",  # the queue's FIFO write
+    "moco.health",  # the training-health gauges
+    "moco.moe_dispatch",  # router, top-k, sort, gather and scatter of an expert layer
+    "moco.expert_ffn",  # the grouped products of the experts
+    "moco.augment.crop",
+    "moco.augment.colour",
+    "moco.augment.blur",
+    "moco.augment.flip_normalize",
+)
+
 
 class _NullSpan:
     """Reusable no-op context manager — the zero-cost path when no

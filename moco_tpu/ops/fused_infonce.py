@@ -119,6 +119,7 @@ def _forward(q, k, queue, temperature, block_k, interpret):
             pltpu.VMEM((b,), jnp.int32),
         ],
         interpret=interpret,
+        name="infonce_fwd",
     )(q.astype(jnp.float32), k.astype(jnp.float32), queue.astype(jnp.float32))
 
 
@@ -193,6 +194,7 @@ def _vjp_bwd(temperature, block_k, interpret, res, cots):
         out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
         scratch_shapes=[pltpu.VMEM((b, c), jnp.float32)],
         interpret=interpret,
+        name="infonce_bwd",
     )(q.astype(jnp.float32), queue.astype(jnp.float32), lse, g_lse)
     # pos-logit path: through both the pos output and the lse
     pos = jnp.sum(q * k, axis=-1) * inv_t

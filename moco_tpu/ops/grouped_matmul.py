@@ -42,14 +42,16 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> ja
     m, k = lhs.shape
     n = rhs.shape[-1]
     live = jnp.arange(m)[:, None] < jnp.sum(group_sizes)
-    if jax.default_backend() == "tpu":
-        from jax.experimental.pallas.ops.tpu.megablox.ops import gmm
+    # the scope holds the product and, through autodiff, its backward (`tgmm`)
+    with jax.named_scope("moco.expert_ffn"):
+        if jax.default_backend() == "tpu":
+            from jax.experimental.pallas.ops.tpu.megablox.ops import gmm
 
-        # the kernel leaves the rows it never visits unwritten
-        tiling = (TILE_ROWS if m % TILE_ROWS == 0 else m, _tile(k), _tile(n))
-        out = gmm(lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype, tiling)
-    else:
-        out = jax.lax.ragged_dot(
-            lhs, rhs, group_sizes.astype(jnp.int32), preferred_element_type=jnp.float32
-        ).astype(lhs.dtype)
+            # the kernel leaves the rows it never visits unwritten
+            tiling = (TILE_ROWS if m % TILE_ROWS == 0 else m, _tile(k), _tile(n))
+            out = gmm(lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype, tiling)
+        else:
+            out = jax.lax.ragged_dot(
+                lhs, rhs, group_sizes.astype(jnp.int32), preferred_element_type=jnp.float32
+            ).astype(lhs.dtype)
     return jnp.where(live, out, 0)

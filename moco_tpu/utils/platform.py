@@ -61,23 +61,32 @@ def enable_persistent_compilation_cache() -> str | None:
     run, a serving replica booting its buckets — loads what the first
     one compiled.
 
-    - `JAX_COMPILATION_CACHE_DIR` set: jax reads it itself; nothing is
-      set in code, whatever the platform.
+    - `JAX_COMPILATION_CACHE_DIR` set: jax reads it itself; no other
+      directory is set in code, whatever the platform.
     - unset and the run is pinned to the CPU: no cache (compiles are
       not the bottleneck there, and XLA:CPU's AOT cache loader warns
       about machine-feature mismatches between writer and reader).
     - unset otherwise: `DEFAULT_CACHE_DIR`.
 
+    Wherever a cache is used, its key keeps the programs' metadata. A
+    device trace names each op by the `moco.` scopes it ran under
+    (`obs.trace.STEP_SCOPES`), and those come from the executable. Jax's
+    default key leaves them out, so a checkout whose scopes differ from
+    another's would load the other's program and its trace would name the
+    other's parts. The key then also holds source locations: an edit to a
+    traced line, or a second checkout, compiles again.
+
     Decided from the environment alone — never initialises a backend
     (multi-host runs must rendezvous first, moco_tpu/train.py).
     """
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env_dir:
-        return env_dir
-    if cpu_pinned():
+    if not env_dir and cpu_pinned():
         return None
     import jax
 
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    if env_dir:
+        return env_dir
     jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     return DEFAULT_CACHE_DIR
 
