@@ -6,9 +6,11 @@ The reference decodes and augments per-image in 32 DataLoader worker
 processes (PIL C code). On TPU the elementwise augmentation work
 (jitter/grayscale/blur/flip/normalize) fuses into one XLA program and runs
 on-device on the whole batch, leaving the host only JPEG decode + crop.
-Where the images fill a lane tile (224 px), the v2 recipe's colour stage,
-jitter then grayscale, is one Pallas kernel in that program
-(`colour_stage`, `ops/colour_jitter.py`) with the batched ops' draws.
+Where the images fill a lane tile (224 px), everything the v2 recipe does
+after its crop, jitter, grayscale, blur, flip and normalise, is one Pallas
+kernel in that program (`colour_stage`, `ops/colour_jitter.py`) with the
+batched ops' draws; the batched ops run everywhere else and are its
+reference.
 Every op takes images in [0, 1] float, NHWC, and a per-call PRNG key; all
 randomness is per-example (`jax.vmap` over split keys) except where noted.
 
@@ -45,7 +47,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
 
-from moco_tpu.ops.colour_jitter import blend, hue_planes, luma
+from moco_tpu.ops.colour_jitter import TAPS, Finish, blend, hue_planes, luma
 from moco_tpu.ops.colour_jitter import colour_jitter as colour_kernel
 from moco_tpu.ops.colour_jitter import fits as colour_kernel_fits
 from moco_tpu.utils.platform import pallas_interpret
@@ -297,23 +299,25 @@ def colour_stage(
     jitter: tuple[float, float, float, float],
     jitter_prob: float,
     grayscale_prob: float,
+    finish: Finish,
     mesh: Mesh | None = None,
 ) -> jax.Array:
-    """`color_jitter(k_jit, ...)` then `random_grayscale(k_gray, ...)`, the
-    v2 recipe's colour stage, with the same draws. Images whose planes fill
-    a lane tile take one kernel that holds each image in VMEM
-    (`ops/colour_jitter.py`), run on each device's own images where `mesh`
-    shards them; smaller ones compose the two batched ops."""
-    b, h, w, _ = images.shape
-    if not colour_kernel_fits(h, w):
-        x = color_jitter(k_jit, images, *jitter, apply_prob=jitter_prob)
-        return random_grayscale(k_gray, x, grayscale_prob)
+    """The v2 recipe after its crop as one kernel that holds each image in
+    VMEM through all of it (`ops/colour_jitter.py`), for images whose
+    planes fill a lane tile (`ops/colour_jitter.fits`): `color_jitter(k_jit,
+    ...)`, `random_grayscale(k_gray, ...)`, then `finish`, the blur and flip
+    draws of `blur_draws` and `flip_draws` and the normalise's constants,
+    as `gaussian_blur`, `random_horizontal_flip` and `normalize` make
+    them, with the same draws. It runs on each device's own images where
+    `mesh` shards them. Other sizes, and the v1 and probe recipes, compose
+    the batched ops (`apply_recipe`)."""
+    b = images.shape[0]
     order, factors, keep = _jitter_draws(k_jit, b, *jitter, jitter_prob)
     factor = jnp.take_along_axis(jnp.concatenate(factors, axis=1)[:, :, 0, 0], order, axis=1)
     gray = jax.random.bernoulli(k_gray, grayscale_prob, (b, 1, 1, 1))
     planes = colour_kernel(
         jnp.moveaxis(images, -1, 0), order, factor, keep.reshape(b), gray.reshape(b),
-        hue=jitter[3] > 0, mesh=mesh, interpret=pallas_interpret(),
+        hue=jitter[3] > 0, finish=finish, mesh=mesh, interpret=pallas_interpret(),
     )
     return jnp.moveaxis(planes, 0, -1)
 
@@ -328,18 +332,26 @@ def _gaussian_kernels(sigma: jax.Array, taps: int) -> jax.Array:
     return k / jnp.sum(k, axis=1, keepdims=True)
 
 
+def blur_draws(
+    rng: jax.Array, b: int, sigma_range: tuple[float, float] = (0.1, 2.0), apply_prob: float = 0.5
+) -> tuple[jax.Array, jax.Array]:
+    """`gaussian_blur`'s per-image draws from its key: the (B,) sigmas and
+    the (B,) RandomApply flags."""
+    k_sigma, k_apply = jax.random.split(rng)
+    sigma = jax.random.uniform(k_sigma, (b,), minval=sigma_range[0], maxval=sigma_range[1])
+    return sigma, jax.random.bernoulli(k_apply, apply_prob, (b,))
+
+
 def gaussian_blur(
     rng: jax.Array,
     images: jax.Array,
     sigma_range: tuple[float, float] = (0.1, 2.0),
     apply_prob: float = 0.5,
-    taps: int = 23,
+    taps: int = TAPS,
 ) -> jax.Array:
     """RandomApply(GaussianBlur(sigma∈U[range]), p) — SimCLR/MoCo-v2 blur
     (`moco/loader.py:~L23-35`), as a separable depthwise conv."""
-    b, h, w, c = images.shape
-    k_sigma, k_apply = jax.random.split(rng)
-    sigma = jax.random.uniform(k_sigma, (b,), minval=sigma_range[0], maxval=sigma_range[1])
+    sigma, keep = blur_draws(rng, images.shape[0], sigma_range, apply_prob)
     kernels = _gaussian_kernels(sigma, taps)  # (B, taps)
 
     def blur_one(img, k1d):  # img (H, W, C)
@@ -355,17 +367,23 @@ def gaussian_blur(
         return x[:, 0].transpose(1, 2, 0)
 
     blurred = jax.vmap(blur_one)(images, kernels)
-    keep = jax.random.bernoulli(k_apply, apply_prob, (b, 1, 1, 1))
-    return jnp.where(keep, blurred, images)
+    return jnp.where(keep[:, None, None, None], blurred, images)
 
 
 # ------------------------------------------------------------- flip/norm
 
 
+def flip_draws(rng: jax.Array, b: int, prob: float = 0.5) -> jax.Array:
+    """`random_horizontal_flip`'s (B,) flags from its key."""
+    return jax.random.bernoulli(rng, prob, (b,))
+
+
+def _flip(images: jax.Array, flip: jax.Array) -> jax.Array:
+    return jnp.where(flip[:, None, None, None], images[:, :, ::-1, :], images)
+
+
 def random_horizontal_flip(rng: jax.Array, images: jax.Array, prob: float = 0.5) -> jax.Array:
-    b = images.shape[0]
-    flip = jax.random.bernoulli(rng, prob, (b, 1, 1, 1))
-    return jnp.where(flip, images[:, :, ::-1, :], images)
+    return _flip(images, flip_draws(rng, images.shape[0], prob))
 
 
 def normalize(images: jax.Array, mean=IMAGENET_MEAN, std=IMAGENET_STD) -> jax.Array:
@@ -408,12 +426,20 @@ def apply_recipe(
     mesh: Mesh | None = None,
 ) -> jax.Array:
     """One view. `images` float [0,1] NHWC, any (H, W) ≥ out_size, their
-    rows sharded over `mesh`'s data axis where one is given."""
+    rows sharded over `mesh`'s data axis where one is given. In the v2
+    order, views whose planes fill a lane tile (`ops/colour_jitter.fits`)
+    take one kernel from the crop to the normalised view: jitter,
+    grayscale, blur, flip and normalise, with the batched ops' draws, which
+    stay in their stages' scopes; other views, and the v1 and probe
+    orders, run the batched ops."""
     k_crop, k_jit, k_gray, k_blur, k_flip = jax.random.split(rng, 5)
     x = images
     if recipe.crop:
         with jax.named_scope("moco.augment.crop"):
             x = random_resized_crop(k_crop, x, out_size, scale=recipe.crop_scale)
+    b, h, w, _ = x.shape
+    with jax.named_scope("moco.augment.flip_normalize"):
+        flip = flip_draws(k_flip, b)
     if recipe.name == "v1":
         # v1 order: crop, grayscale, jitter, flip (main_moco.py:~L245-255)
         with jax.named_scope("moco.augment.colour"):
@@ -421,18 +447,27 @@ def apply_recipe(
             x = color_jitter(k_jit, x, *recipe.jitter, apply_prob=recipe.jitter_prob)
     elif recipe.name == "probe":
         pass  # crop + flip + normalize only
-    else:
-        # v2 order: crop, jitter(p=0.8), grayscale, blur, flip (~L228-240)
+    elif colour_kernel_fits(h, w):
+        # v2 order: crop, jitter(p=0.8), grayscale, blur, flip (~L228-240), in one kernel
+        sigma = blurred = None
+        if recipe.blur_prob > 0:
+            with jax.named_scope("moco.augment.blur"):
+                sigma, blurred = blur_draws(k_blur, b, apply_prob=recipe.blur_prob)
         with jax.named_scope("moco.augment.colour"):
-            x = colour_stage(
-                k_jit, k_gray, x, recipe.jitter, recipe.jitter_prob, recipe.grayscale_prob, mesh
+            return colour_stage(
+                k_jit, k_gray, x, recipe.jitter, recipe.jitter_prob, recipe.grayscale_prob,
+                Finish(sigma, blurred, flip, recipe.mean, recipe.std), mesh,
             )
+    else:
+        # the same order on the batched ops
+        with jax.named_scope("moco.augment.colour"):
+            x = color_jitter(k_jit, x, *recipe.jitter, apply_prob=recipe.jitter_prob)
+            x = random_grayscale(k_gray, x, recipe.grayscale_prob)
         if recipe.blur_prob > 0:
             with jax.named_scope("moco.augment.blur"):
                 x = gaussian_blur(k_blur, x, apply_prob=recipe.blur_prob)
     with jax.named_scope("moco.augment.flip_normalize"):
-        x = random_horizontal_flip(k_flip, x)
-        return normalize(x, recipe.mean, recipe.std)
+        return normalize(_flip(x, flip), recipe.mean, recipe.std)
 
 
 def two_crop_augment(

@@ -1,25 +1,38 @@
-"""The colour stage of the v2 augmentation recipe, RandomApply(ColorJitter)
-then RandomGrayscale, as one Pallas TPU kernel, and the colour formulas it
-shares with the batched jnp path (`data/augment.py::color_jitter`).
+"""The v2 augmentation recipe after its crop, RandomApply(ColorJitter),
+RandomGrayscale, RandomApply(GaussianBlur), the horizontal flip and the
+normalise, as one Pallas TPU kernel, and the colour formulas it shares with
+the batched jnp path (`data/augment.py`).
 
 XLA runs the batched path as passes over the whole batch in HBM: a blend
 slot is a mean pass and a blend pass over the three channel planes, and
-contrast's mean keeps one slot from fusing into the next, so a view takes
-about thirteen passes. Here the grid steps over the images, one image's
-three planes sit in VMEM (224 x 224 float32: 688 KB with the lanes padded
-to 256), and the image's own ops run there in its drawn order: the planes
-are read from HBM once and written once.
+contrast's mean keeps one slot from fusing into the next, so the colour
+ops take about thirteen passes a view, and the blur (two convolutions,
+edge pads, the RandomApply select), the flip's reverse and the normalise
+each read and write the batch again. Here the grid steps over the images,
+one image's three planes sit in VMEM (224 x 224 float32: 688 KB with the
+lanes padded to 256), and the image's own ops run there in its drawn
+order: the planes are read from HBM once and written once.
 
 Each image's draws arrive in scalar memory: its four ops in drawn order
 (0 brightness, 1 contrast, 2 saturation, 3 hue), their factors (hue's is
-its delta), the RandomApply flag and the grayscale flag. An op is a scalar
-branch around one sweep over the image in VMEM, in place in the output
-block, so an image runs its own three blends and the hue round trip, not
-six blend slots of which three are the identity; contrast sums the luma
-of the image as it stands before its blend. A sweep goes over strips of
-`ROWS` rows: a strip is eight vregs a plane, enough independent work
-to hide most of the latency of the HSV round trip's dependency chain
-without unrolling the loop over strips, which multiplies compile time.
+its delta), the RandomApply flag, the grayscale flag, the blur and flip
+flags, and the blur's 1 / sigma and the reciprocal of its taps' sum. An
+op is a scalar branch around one sweep over the image in VMEM, in place
+in the output block, so an image runs its own three blends and the hue
+round trip, not six blend slots of which three are the identity; contrast
+sums the luma of the image as it stands before its blend. A sweep goes
+over strips of `ROWS` rows: a strip is eight vregs a plane, enough
+independent work to hide most of the latency of the HSV round trip's
+dependency chain without unrolling the loop over strips, which multiplies
+compile time.
+
+The blur is two products on the matrix unit at `Precision.HIGHEST` (no
+operand rounded below float32), `down @ plane @ across`, each band built
+from the image's sigma (`_blur_band`) with the replicated border folded
+into its edge entries; the Gaussian and the clamp are symmetric, so the
+flip of a blurred image is the horizontal band mirrored, and an unblurred
+flipped image takes the mirror alone. The normalise rides the last write.
+Whether the blur is compiled in is static, as hue is (`Finish.sigma`).
 
 Over a mesh of more than one device the kernel runs under `shard_map`,
 each device on its own images: a Mosaic call is not partitioned by XLA.
@@ -28,6 +41,8 @@ each device on its own images: a Mosaic call is not partitioned by XLA.
 from __future__ import annotations
 
 import functools
+import struct
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +59,7 @@ MIN_WIDTH = 128  # planes narrower than one lane tile stay on the jnp path
 # rows x lane-padded columns: an image's planes in and out, double-buffered,
 # take 3 MiB of VMEM at 256 x 256
 MAX_PIXELS = 256 * 256
+TAPS = 23  # the blur's window, as the batched blur's (`data/augment.py::gaussian_blur`)
 
 
 def fits(height: int, width: int) -> bool:
@@ -117,9 +133,92 @@ def hue_planes(r, g, b, d, mod=jnp.remainder):
     return by_sextant(v, q, p, p, t, v), by_sextant(t, v, v, q, p, p), by_sextant(p, p, t, v, v, q)
 
 
-def _kernel(kind_ref, factor_ref, flag_ref, x_ref, o_ref, *, hue: bool):
+class Finish(NamedTuple):
+    """What the v2 recipe does after its grayscale, for the kernel to run
+    before it writes an image: RandomApply(GaussianBlur) on `sigma` and
+    `blurred` (both None where the recipe has no blur), the horizontal flip
+    and the per-channel normalise."""
+
+    sigma: jax.Array | None  # (B,) float32: each image's blur sigma
+    blurred: jax.Array | None  # (B,) bool: RandomApply kept the blur
+    flipped: jax.Array  # (B,) bool
+    mean: tuple[float, float, float]
+    std: tuple[float, float, float]
+
+
+def _blur_band(
+    n: int, out_axis: int, inv_sigma, inv_z, flip=0,
+    out_span: tuple[int, int] | None = None, in_span: tuple[int, int] | None = None,
+) -> jax.Array:
+    """The weights of the separable blur along an axis of n pixels, with
+    the border replicated: entry (a, b) weighs the input pixel at the index
+    that is not `out_axis` into the output pixel at the one that is, so the
+    output is `band @ x` for `out_axis` 0 and `x @ band` for 1. The window
+    takes `TAPS` taps exp(-(d * inv_sigma)^2 / 2) * inv_z; what it loses
+    past the border goes to the border pixel (edge padding, as the batched
+    blur pads). `flip` 1 mirrors the output axis, so that the flip of a
+    blurred image rides its horizontal pass. `out_span` and `in_span`
+    (first index, count) cut the band to those outputs and inputs; the
+    inputs hold every output's window."""
+    out0, outs = out_span or (0, n)
+    in0, ins = in_span or (0, n)
+    shape = (outs, ins) if out_axis == 0 else (ins, outs)
+    src = in0 + lax.broadcasted_iota(jnp.int32, shape, 1 - out_axis)
+    dst = out0 + lax.broadcasted_iota(jnp.int32, shape, out_axis)
+    centre = dst + flip * (n - 1 - 2 * dst)
+    d = src - centre
+    x = d.astype(jnp.float32) * inv_sigma
+    w = jnp.where(jnp.abs(d) <= TAPS // 2, jnp.exp(-0.5 * x * x) * inv_z, 0.0)
+    lost = 1.0 - jnp.sum(w, axis=1 - out_axis, keepdims=True)
+    edge = jnp.where(centre < n // 2, 0, n - 1)
+    return w + jnp.where((src == edge) & (jnp.abs(centre - edge) < TAPS // 2), lost, 0.0)
+
+
+def _halves(n: int) -> tuple[tuple[int, int, int], ...]:
+    """(first output, first input, inputs) of each half of n rows: the
+    rows of input that hold the half's windows, whole sublane tiles, so
+    that a 224-row plane's vertical pass contracts 128 rows a half, one
+    pass of the matrix unit where the whole plane takes two."""
+    half, reach = n // 2, TAPS // 2
+    spans = []
+    for a in (0, half):
+        k0 = max(0, (a - reach) // 8 * 8)
+        k1 = min(n, -(-(a + half + reach) // 8) * 8)
+        spans.append((a, k0, k1 - k0))
+    return tuple(spans)
+
+
+def _float32(x: float) -> float:
+    return struct.unpack("f", struct.pack("f", x))[0]
+
+
+def _reciprocals(values: tuple[float, ...]) -> tuple[float, ...]:
+    """Each value's float32 reciprocal (a float64 quotient of float32
+    operands rounds to the float32 one): dividing by a constant is
+    multiplying by it, as XLA compiles the batched `normalize`."""
+    return tuple(_float32(1.0 / _float32(v)) for v in values)
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, precision=lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+
+
+def _mirrored(plane, mirror):
+    """`plane @ mirror` for a (W, W) bfloat16 matrix of zeros and ones,
+    exactly, in three bfloat16 passes: the plane is the sum of three
+    bfloat16 parts, each product picks one element, and the parts add back
+    to the plane in float32 without rounding."""
+    hi = plane.astype(jnp.bfloat16)
+    rest = plane - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    pick = lambda part: jnp.dot(part, mirror, preferred_element_type=jnp.float32)
+    return (pick(hi) + pick(mid)) + pick(lo)
+
+
+def _kernel(kind_ref, factor_ref, flag_ref, blur_ref, x_ref, o_ref, *, hue, blur, normalise):
     n = pl.program_id(0)
-    keep = flag_ref[2 * n] != 0
+    keep = flag_ref[4 * n] != 0
     _, height, width = x_ref.shape
 
     def sweep(src, fn):
@@ -167,9 +266,47 @@ def _kernel(kind_ref, factor_ref, flag_ref, x_ref, o_ref, *, hue: bool):
             def _():
                 sweep(o_ref, lambda c: hue_planes(*c, f, remainder_in_range))
 
-    @pl.when(flag_ref[2 * n + 1] != 0)
+    @pl.when(flag_ref[4 * n + 1] != 0)
     def _():
         sweep(o_ref, lambda c: (luma(*c),) * 3)
+
+    mean, scale = normalise
+    blurred = flag_ref[4 * n + 2] != 0 if blur else False
+    flip = flag_ref[4 * n + 3]
+
+    def normalised(c, plane):
+        return (plane - mean[c]) * scale[c]
+
+    if blur:
+
+        @pl.when(blurred)
+        def _():
+            # the vertical pass by halves, then the horizontal one with the flip in it
+            inv_sigma, inv_z = blur_ref[2 * n], blur_ref[2 * n + 1]
+            halves = _halves(height)
+            down = [_blur_band(height, 0, inv_sigma, inv_z, 0, (a, height // 2), (k0, k))
+                    for a, k0, k in halves]
+            across = _blur_band(width, 1, inv_sigma, inv_z, flip)
+            # every plane's vertical pass reads the planes before any is written;
+            # the horizontal one then takes the three planes as one operand
+            vertical = jnp.concatenate(
+                [_dot(band, o_ref[c, k0:k0 + k, :]) for c in range(3) for band, (_, k0, k) in zip(down, halves)]
+            )
+            out = _dot(vertical, across)
+            for c in range(3):
+                o_ref[c] = normalised(c, out[c * height:(c + 1) * height])
+
+    @pl.when(jnp.logical_not(blurred) & (flip != 0))
+    def _():
+        src = lax.broadcasted_iota(jnp.int32, (width, width), 0)
+        dst = lax.broadcasted_iota(jnp.int32, (width, width), 1)
+        mirror = (src + dst == width - 1).astype(jnp.bfloat16)
+        for c in range(3):
+            o_ref[c] = normalised(c, _mirrored(o_ref[c], mirror))
+
+    @pl.when(jnp.logical_not(blurred) & (flip == 0))
+    def _():
+        sweep(o_ref, lambda c: tuple((x - mean[i]) * scale[i] for i, x in enumerate(c)))
 
 
 def colour_jitter(
@@ -180,25 +317,38 @@ def colour_jitter(
     gray: jax.Array,  # (B,) bool: RandomGrayscale took the image
     *,
     hue: bool,  # False: the hue range is 0 and hue is the identity
+    finish: Finish,  # blur, flip and normalise after the grayscale
     mesh: Mesh | None = None,  # the images are sharded over its data axis
     interpret: bool = False,
 ) -> jax.Array:
-    """The (3, B, H, W) planes after each image's jitter (where kept) and
-    then its grayscale (where drawn); an image with neither comes out bit
-    for bit. The planes lead, so that NHWC images whose layout puts the
-    channel outermost (what XLA gives the crop's resample here) reach the
-    kernel and leave it through bitcasts."""
-    _, _, height, width = planes.shape
+    """The (3, B, H, W) planes after each image's jitter (where kept), its
+    grayscale (where drawn), its blur (where drawn) and its flip (where
+    drawn), normalised; an image with none of these comes out as its
+    normalised input bit for bit. A mean of 0 and a std of 1 make the
+    normalise the identity, bit for bit. The planes lead, so that NHWC
+    images whose layout puts the channel outermost (what XLA gives the
+    crop's resample here) reach the kernel and leave it through bitcasts."""
+    _, b, height, width = planes.shape
     if not fits(height, width):
         raise ValueError(f"{height} x {width} planes do not take the colour kernel")
+    blur = finish.sigma is not None
+    normalise = (finish.mean, _reciprocals(finish.std))
+    blurred = finish.blurred if blur else jnp.zeros((b,), bool)
+    if blur:
+        inv_sigma = 1.0 / finish.sigma.astype(jnp.float32)
+        d = jnp.arange(-(TAPS // 2), TAPS // 2 + 1, dtype=jnp.float32)
+        x = d[None, :] * inv_sigma[:, None]
+        inv_z = 1.0 / jnp.sum(jnp.exp(-0.5 * x * x), axis=1)
+        blur_params = jnp.stack([inv_sigma, inv_z], axis=1)
+    else:
+        blur_params = jnp.zeros((b, 2), jnp.float32)
 
-    def call(planes, kinds, factors, keep, gray):
+    def call(planes, kinds, factors, flags, blur_params):
         block = pl.BlockSpec((3, None, height, width), lambda i, *_: (0, i, 0, 0))
-        flags = jnp.stack([keep, gray], axis=1).astype(jnp.int32).reshape(-1)
         return pl.pallas_call(
-            functools.partial(_kernel, hue=hue),
+            functools.partial(_kernel, hue=hue, blur=blur, normalise=normalise),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=3, grid=(planes.shape[1],), in_specs=[block], out_specs=block
+                num_scalar_prefetch=4, grid=(planes.shape[1],), in_specs=[block], out_specs=block
             ),
             out_shape=jax.ShapeDtypeStruct(planes.shape, jnp.float32),
             compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
@@ -207,14 +357,16 @@ def colour_jitter(
         )(
             kinds.reshape(-1).astype(jnp.int32),
             factors.reshape(-1).astype(jnp.float32),
-            flags,
+            flags.reshape(-1),
+            blur_params.reshape(-1),
             planes.astype(jnp.float32),
         )
 
+    flags = jnp.stack([keep, gray, blurred, finish.flipped], axis=1).astype(jnp.int32)
     if mesh is not None and mesh.size > 1:
         rows = P(DATA_AXIS)
         call = shard_map(
             call, mesh=mesh, in_specs=(P(None, DATA_AXIS), rows, rows, rows, rows),
             out_specs=P(None, DATA_AXIS), check_vma=False,
         )
-    return call(planes, kinds, factors, keep, gray)
+    return call(planes, kinds, factors, flags, blur_params)

@@ -205,19 +205,43 @@ def test_the_selective_scan_compiles_at_the_published_width(one_chip):
 def test_the_colour_kernel_compiles_at_an_r50_view(one_chip, hue):
     """The v2 colour stage's kernel on one view of the R50 cell, 256 images'
     (3, 256, 224, 224) float32 planes, with the hue round trip and without
-    it: its blocks fit the default scoped VMEM (no limit is asked for), and
-    it is in the program under its name."""
-    from moco_tpu.ops.colour_jitter import colour_jitter
+    it, and the flip and normalise after them: its blocks fit the default
+    scoped VMEM (no limit is asked for), and it is in the program under its
+    name."""
+    from moco_tpu.ops.colour_jitter import Finish, colour_jitter
 
     planes = _shape(one_chip, (3, 256, 224, 224), jnp.float32)
     kinds = _shape(one_chip, (256, 4), jnp.int32)
     factors = _shape(one_chip, (256, 4), jnp.float32)
     flag = _shape(one_chip, (256,), jnp.bool_)
 
-    def f(planes, kinds, factors, keep, gray):
-        return colour_jitter(planes, kinds, factors, keep, gray, hue=hue)
+    def f(planes, kinds, factors, keep, gray, flipped):
+        finish = Finish(None, None, flipped, (0.485, 0.456, 0.406), (0.229, 0.224, 0.225))
+        return colour_jitter(planes, kinds, factors, keep, gray, hue=hue, finish=finish)
 
-    text = jax.jit(f).lower(planes, kinds, factors, flag, flag).compile().as_text()
+    text = jax.jit(f).lower(planes, kinds, factors, flag, flag, flag).compile().as_text()
+    assert len(re.findall(r"%colour_jitter[.\d]* = .*custom-call\(", text)) == 1
+
+
+def test_the_colour_kernel_with_the_rest_of_the_view_compiles_at_an_r50_view(one_chip):
+    """The kernel with the v2 recipe's blur, flip and normalise after its
+    colour ops, on one view of the R50 cell, 256 images' (3, 256, 224, 224)
+    float32 planes: its blocks, the blur's two bands and their products fit
+    the default scoped VMEM, and it is one custom call under its name."""
+    from moco_tpu.ops.colour_jitter import Finish, colour_jitter
+
+    planes = _shape(one_chip, (3, 256, 224, 224), jnp.float32)
+    kinds = _shape(one_chip, (256, 4), jnp.int32)
+    factors = _shape(one_chip, (256, 4), jnp.float32)
+    flag = _shape(one_chip, (256,), jnp.bool_)
+    sigma = _shape(one_chip, (256,), jnp.float32)
+
+    def f(planes, kinds, factors, keep, gray, sigma, blurred, flipped):
+        finish = Finish(sigma, blurred, flipped, (0.485, 0.456, 0.406), (0.229, 0.224, 0.225))
+        return colour_jitter(planes, kinds, factors, keep, gray, hue=True, finish=finish)
+
+    lowered = jax.jit(f).lower(planes, kinds, factors, flag, flag, sigma, flag, flag)
+    text = lowered.compile().as_text()
     assert len(re.findall(r"%colour_jitter[.\d]* = .*custom-call\(", text)) == 1
 
 
@@ -226,7 +250,9 @@ def test_the_two_view_program_compiles_over_four_chips(four_chips, monkeypatch, 
     """`TwoCropPipeline`'s two-view program, on the canvas and on host
     crops, for the R50 cell's 256 images over a 2x2 mesh: XLA refuses to
     partition a Mosaic call, so the colour kernel has to arrive under
-    `shard_map`, once a view, on each chip's 64 images."""
+    `shard_map`, once a view, on each chip's 64 images; it holds the
+    blur and the flip, so no reverse is left and no convolution but the
+    crop's resample."""
     from moco_tpu.data import augment
     from moco_tpu.data.pipeline import TwoCropPipeline
     from moco_tpu.parallel import create_mesh
@@ -244,5 +270,12 @@ def test_the_two_view_program_compiles_over_four_chips(four_chips, monkeypatch, 
     else:
         canvas = pipe.dataset.load(0)[0].shape
         lowered = pipe._augment.lower(rng, jax.ShapeDtypeStruct((256, *canvas), jnp.uint8, sharding=rows))
-    calls = re.findall(r"%colour_jitter[.\d]* = (.*) custom-call\(", lowered.compile().as_text())
+    text = lowered.compile().as_text()
+    calls = re.findall(r"%colour_jitter[.\d]* = (.*) custom-call\(", text)
     assert len(calls) == 2 and all("f32[3,64,224,224]" in c for c in calls), calls
+    # the blur and the flip run inside the kernel: no reverse is left, and
+    # a convolution only in the crop's resample
+    assert not re.findall(r"= \S+ reverse\(", text)
+    convolutions = re.findall(r"= \S+ convolution\(.*", text)
+    assert all("moco.augment.crop" in c for c in convolutions), convolutions
+    assert bool(convolutions) is not host_crops

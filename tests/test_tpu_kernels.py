@@ -312,14 +312,44 @@ class TestColourJitter:
 
     @pytest.mark.parametrize("hue,apply_prob", [(0.1, 0.8), (0.4, 1.0), (0.0, 0.8)])
     def test_stage_matches_jitter_then_grayscale(self, hue, apply_prob):
-        from moco_tpu.data.augment import color_jitter, colour_stage, random_grayscale
+        from moco_tpu.data.augment import Finish, color_jitter, colour_stage, random_grayscale
 
         k_jit, k_gray = jax.random.split(jax.random.PRNGKey(2147483659))
         x = jax.random.uniform(jax.random.PRNGKey(70), (256, 224, 224, 3))
         jitter = (0.4, 0.4, 0.4, hue)
-        got = jax.jit(lambda x: colour_stage(k_jit, k_gray, x, jitter, apply_prob, 0.2))(x)
+        # no blur, no flip, and a normalise by mean 0 and std 1: the colour ops alone
+        colour_only = Finish(None, None, jnp.zeros((256,), bool), (0.0, 0.0, 0.0), (1.0, 1.0, 1.0))
+        got = jax.jit(lambda x: colour_stage(k_jit, k_gray, x, jitter, apply_prob, 0.2, colour_only))(x)
         want = jax.jit(
             # the kernel's own keys on purpose: the same draws, the batched ops' arithmetic
             lambda x: random_grayscale(k_gray, color_jitter(k_jit, x, *jitter, apply_prob=apply_prob), 0.2)  # mocolint: disable=JX003
         )(x)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=0)
+
+    @pytest.mark.parametrize("blur_prob,flip_prob", [(0.5, 0.5), (1.0, 1.0), (0.0, 1.0)])
+    def test_post_crop_chain_matches_the_batched_path(self, blur_prob, flip_prob):
+        """The whole chain after the crop in the kernel (jitter, grayscale,
+        blur, flip, normalise) against the batched ops on the same keys,
+        their convolutions at the highest precision, within 1e-5."""
+        from moco_tpu.data.augment import (
+            IMAGENET_MEAN, IMAGENET_STD, Finish, blur_draws, color_jitter, colour_stage,
+            flip_draws, gaussian_blur, normalize, random_grayscale, random_horizontal_flip,
+        )
+
+        k_jit, k_gray, k_blur, k_flip = jax.random.split(jax.random.PRNGKey(2147483671), 4)
+        x = jax.random.uniform(jax.random.PRNGKey(71), (256, 224, 224, 3))
+        jitter = (0.4, 0.4, 0.4, 0.1)
+        sigma, blurred = blur_draws(k_blur, 256, apply_prob=blur_prob) if blur_prob else (None, None)
+        finish = Finish(sigma, blurred, flip_draws(k_flip, 256, flip_prob), IMAGENET_MEAN, IMAGENET_STD)
+        got = jax.jit(lambda x: colour_stage(k_jit, k_gray, x, jitter, 0.8, 0.2, finish))(x)
+
+        def chain(x):
+            # the kernel's own keys on purpose: the same draws, the batched ops' arithmetic
+            x = random_grayscale(k_gray, color_jitter(k_jit, x, *jitter, apply_prob=0.8), 0.2)  # mocolint: disable=JX003
+            if blur_prob:
+                x = gaussian_blur(k_blur, x, apply_prob=blur_prob)  # mocolint: disable=JX003
+            return normalize(random_horizontal_flip(k_flip, x, flip_prob))  # mocolint: disable=JX003
+
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(chain)(x)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=0)
